@@ -6,7 +6,7 @@ registered ops on synthetic inputs and prints a table + JSON. The
 reference runs each op through its imperative path with the profiler;
 here each op runs through the same `mx.np`/`npx` dispatch the user calls,
 timed with the two-loop difference method (see bench.py) so the numbers
-hold on lazy/tunnelled runtimes too.
+hold on runtimes that return before execution ends too.
 
 Usage::
 

@@ -47,7 +47,8 @@ def main():
 
     mx.random.seed(0)
     net = get_llama(args.config)
-    net.initialize()
+    # the chip when there is one; the Generator serves where the model is
+    net.initialize(ctx=mx.tpu() if mx.num_tpus() else mx.cpu())
     gen = Generator(net, max_seq=64, batch_buckets=(1, 4),
                     prompt_buckets=(16,))
 

@@ -171,10 +171,7 @@ class CachedOp:
         self.block = block
         self.static_alloc = static_alloc
         self.static_shape = static_shape
-        # per-executable XLA overrides (jax.jit compiler_options). The
-        # serving engine pins the deterministic legacy CPU runtime here:
-        # the thunk runtime's codegen partitioning varies with graph
-        # shape, which breaks the decode-vs-prefill bitwise contract
+        # per-executable XLA overrides (jax.jit compiler_options)
         self._compiler_options = dict(compiler_options) \
             if compiler_options else None
         self._cache = {}
@@ -294,7 +291,7 @@ class CachedOp:
 
     def _build_with_retry(self, key, grad_mode, args_tracked, static_args):
         """Trace/compile under the resilience retry policy: a transient
-        XLA compile failure (tunnel drop, RESOURCE_EXHAUSTED from a
+        XLA compile failure (dropped connection, RESOURCE_EXHAUSTED from a
         concurrent compile) backs off and retries instead of failing the
         training step; real trace errors re-raise on the first attempt."""
         from .resilience import retry as _retry
@@ -314,7 +311,7 @@ class CachedOp:
         """Write back mutated state (BatchNorm running stats etc.)."""
         for p, ns in zip(state_params, new_states):
             arr = p.data()
-            if arr._data is not ns:
+            if ns is not None and arr._data is not ns:
                 arr._set_data_internal(ns)
 
     def _split_params(self):
@@ -347,6 +344,7 @@ class CachedOp:
         state_arrays = [p.data() for p in state_params]
         block = self.block
         is_training = autograd.is_training()
+        donate_states = self.static_alloc and not grad_mode
         out_tree_box = {}
 
         def replay(tp_datas, st_datas, rng_key, arg_datas):
@@ -367,7 +365,15 @@ class CachedOp:
                     autograd.set_training(prev_train)
                     autograd.set_recording(prev_rec)
                     _rng.pop_trace_rng()
-                new_states = [a._data for a in state_arrays]
+                # only a state the forward rebound (BatchNorm running
+                # stats) is an output. An untouched one would come back
+                # as its own input tracer, and XLA copies a passed-through
+                # input: every frozen weight, on every call. Donated
+                # state buffers (static_alloc) alias their outputs, and
+                # must all come back or the call would consume them.
+                new_states = [
+                    a._data if donate_states or a._data is not t else None
+                    for a, t in zip(state_arrays, st_datas)]
             flat_outs, tree = jax.tree_util.tree_flatten(
                 outs, is_leaf=lambda x: isinstance(x, NDArray))
             out_tree_box["tree"] = tree
@@ -402,14 +408,15 @@ class CachedOp:
                                                list(arg_datas))
                 return out_datas, new_states, None
 
-            donate = (1,) if self.static_alloc else ()
+            donate = (1,) if donate_states else ()
             fwd_jit = jax.jit(fwd, donate_argnums=donate,
                               compiler_options=self._compiler_options)
 
         def bwd(vjp, out_cts, state_shapes_dtypes):
             import jax.numpy as jnp
 
-            zero_states = [jnp.zeros(s, d) for s, d in state_shapes_dtypes]
+            zero_states = [None if sd is None else jnp.zeros(*sd)
+                           for sd in state_shapes_dtypes]
             grads = vjp((list(out_cts), zero_states))
             return grads  # (param_grads_tuple, *diff_arg_grads)
 
@@ -498,7 +505,9 @@ class CachedOp:
         wrapped = [NDArray(d) for d in out_datas]
 
         if grad_mode and vjp is not None:
-            state_sd = tuple((tuple(s.shape), str(s.dtype)) for s in new_states)
+            state_sd = tuple(
+                None if s is None else (tuple(s.shape), str(s.dtype))
+                for s in new_states)
             bwd_jit = entry["bwd"]
             diff_arg_idx = entry["diff_arg_idx"]
 

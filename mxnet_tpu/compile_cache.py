@@ -14,10 +14,14 @@ How it composes with the in-memory signature cache:
 * ``CachedOp._cache`` stays the first-level cache (exact signature key →
   live executable; zero-cost hits).
 * A signature **miss** still traces and calls ``jax.jit``, but XLA's
-  lowering → executable step now consults ``MXNET_COMPILE_CACHE_DIR``:
-  a disk hit deserializes the executable instead of compiling
-  (``disk_hits``); a miss compiles once and writes through
-  (``disk_misses``).
+  lowering → executable step now consults the cache directory: a disk
+  hit deserializes the executable instead of compiling (``disk_hits``);
+  a miss compiles once and writes through (``disk_misses``).
+* Where the directory comes from: ``JAX_COMPILATION_CACHE_DIR``, when
+  the environment sets it, places the cache from outside — JAX reads it
+  itself and this module never re-points it (the repo's own flag and an
+  explicit ``path`` then have no say). Otherwise an explicit
+  ``enable(path)`` or ``MXNET_COMPILE_CACHE_DIR``.
 * Disk keys are **content** keys (JAX fingerprints the lowered HLO +
   compile options + backend), so they are process-independent exactly
   when the traced computation is — which is what
@@ -57,16 +61,28 @@ def _on_event(name, **_kw):
         _misses += 1
 
 
+def _placed_from_outside():
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or None
+
+
 def enable(path=None):
     """Point the JAX persistent compilation cache at ``path`` (default:
     ``MXNET_COMPILE_CACHE_DIR``). Returns True when active. No-op
     (False) when both are empty — the knob is opt-in. Idempotent;
     re-enabling with a different explicit ``path`` re-points the cache.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` in the environment the cache
+    stays where that says: the directory is recorded for :func:`stats`
+    and the write-through thresholds are lowered, but
+    ``jax_compilation_cache_dir`` is never written here.
     """
     global _dir, _listener_on
     from . import config
 
-    if path is None:
+    outside = _placed_from_outside()
+    if outside:
+        path = outside
+    elif path is None:
         path = config.get("MXNET_COMPILE_CACHE_DIR") or None
     if not path:
         return _dir is not None
@@ -77,8 +93,9 @@ def enable(path=None):
         import jax
         from jax._src import monitoring
 
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
+        if not outside:
+            os.makedirs(path, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", path)
         # serve executables are small and compile fast on CPU CI; cache
         # everything so the second process compiles literally nothing
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
@@ -100,9 +117,10 @@ def disable():
     with _lock:
         if _dir is None:
             return
-        import jax
+        if not _placed_from_outside():
+            import jax
 
-        jax.config.update("jax_compilation_cache_dir", None)
+            jax.config.update("jax_compilation_cache_dir", None)
         _dir = None
 
 

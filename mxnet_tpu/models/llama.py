@@ -55,17 +55,16 @@ def apply_rope(x, cos, sin):
     return stacked.reshape(*x.shape)
 
 
-def _serving_dense(x, proj, cache):
-    """Projection on the serving fast rungs: the int8 rung looks the
-    parameter up in the cache's pre-quantized side table
-    (``ops.nn.quantized_dense``); otherwise the plain gemm Dense — never
-    ``stable_dense``, whose mul+reduce formulation is the baseline rung's
-    bitwise-parity tax."""
+def _serving_dense(x, weight, cache):
+    """Projection by the parameter ``weight`` on the serving fast rungs:
+    the int8 rung looks it up in the cache's pre-quantized side table
+    (``ops.nn.quantized_dense``); otherwise the gemm at the precision the
+    weight is stored at (``ops.nn.serving_dense``)."""
     qw = getattr(cache, "quant_weights", None)
-    entry = qw.get(id(proj.weight)) if qw else None
+    entry = qw.get(id(weight)) if qw else None
     if entry is not None:
         return _ops.quantized_dense(x, entry[0], entry[1])
-    return proj(x)
+    return _ops.serving_dense(x, weight.data())
 
 
 class LlamaAttention(HybridBlock):
@@ -173,11 +172,11 @@ class LlamaAttention(HybridBlock):
         from .. import numpy as mnp
 
         b, t, _ = x.shape
-        q = self._heads_split(_serving_dense(x, self.q_proj, cache),
+        q = self._heads_split(_serving_dense(x, self.q_proj.weight, cache),
                               self._heads)
-        k = self._heads_split(_serving_dense(x, self.k_proj, cache),
+        k = self._heads_split(_serving_dense(x, self.k_proj.weight, cache),
                               self._kv_heads)
-        v = self._heads_split(_serving_dense(x, self.v_proj, cache),
+        v = self._heads_split(_serving_dense(x, self.v_proj.weight, cache),
                               self._kv_heads)
         cos_t, sin_t = _rope_tables(cache.max_seq, self._head_dim,
                                     self._theta)
@@ -201,7 +200,7 @@ class LlamaAttention(HybridBlock):
             out = _ops.cached_attention(q, k_all, v_all, start_pos,
                                         path=path)
         out = out.transpose(0, 2, 1, 3).reshape(b, t, self._units)
-        return _serving_dense(out, self.o_proj, cache)
+        return _serving_dense(out, self.o_proj.weight, cache)
 
 
 class LlamaFFN(HybridBlock):
@@ -228,11 +227,10 @@ class LlamaFFN(HybridBlock):
         if cache is not None:
             # serving fast rungs: gemm / int8 projections via the cache's
             # quant side table
-            g = _ops.activation(_serving_dense(x, self.gate_proj, cache),
-                                "silu")
-            return _serving_dense(g * _serving_dense(x, self.up_proj,
-                                                     cache),
-                                  self.down_proj, cache)
+            g = _ops.activation(
+                _serving_dense(x, self.gate_proj.weight, cache), "silu")
+            up = _serving_dense(x, self.up_proj.weight, cache)
+            return _serving_dense(g * up, self.down_proj.weight, cache)
         g = _ops.activation(self.gate_proj(x), "silu")
         return self.down_proj(g * self.up_proj(x))
 
@@ -312,10 +310,10 @@ class LlamaModel(HybridBlock):
         if cache is not None:
             # serving decode path: per-layer KV rings, no remat (inference
             # saves no activations, so recompute would be pure waste).
-            # Every matmul on this path is ops.nn.stable_dense — with the
-            # serving engine's pinned CPU runtime that makes the T=1
-            # decode executable bitwise equal, per position, to the
-            # T=bucket prefill executable (the serve parity contract);
+            # Every matmul on this path is ops.nn.stable_dense — that
+            # makes the T=1 decode executable bitwise equal, per
+            # position, to the T=bucket prefill executable (the serve
+            # parity contract);
             # the fusion_fence additionally pins each layer boundary so
             # the contract can't regress via cross-layer fusion choices
             fast = getattr(cache, "path", "baseline") != "baseline"
@@ -329,14 +327,7 @@ class LlamaModel(HybridBlock):
             w_param = (self.embed.weight if self._tie
                        else self.lm_head.weight)
             if fast:
-                qw = getattr(cache, "quant_weights", None)
-                entry = qw.get(id(w_param)) if qw else None
-                if entry is not None:
-                    return _ops.quantized_dense(x, entry[0], entry[1])
-                w = w_param.data()
-                return _ops.fully_connected(x, w, None,
-                                            num_hidden=w.shape[0],
-                                            no_bias=True, flatten=False)
+                return _serving_dense(x, w_param, cache)
             return _ops.stable_dense(x, w_param.data())
         if self._remat and in_trace():
             # only under a functionalized trace (ShardedTrainer/CachedOp):

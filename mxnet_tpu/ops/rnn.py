@@ -91,8 +91,9 @@ def rnn_fused(data, h0, c0, weights, mode, num_layers, bidirectional,
             if rng_key is None:
                 raise MXNetError("dropout inside fused rnn needs an rng key")
             keep = 1.0 - dropout
-            mask = jax.random.bernoulli(
-                jax.random.fold_in(rng_key, layer), keep, x.shape)
+            mask = jax.random.bernoulli(  # f32: see ops.nn.dropout
+                jax.random.fold_in(rng_key, layer), jnp.float32(keep),
+                x.shape)
             x = jnp.where(mask, x / keep, 0.0)
     h_stack = jnp.stack(h_outs)
     c_stack = jnp.stack(c_outs) if c_outs else None

@@ -627,7 +627,7 @@ class ShardedTrainer:
         n_train = len(self._train_keys)
         lrs = tuple(self.optimizer._get_lr(i) for i in range(n_train))
         wds = tuple(self.optimizer._get_wd(i) for i in range(n_train))
-        key_struct = jax.ShapeDtypeStruct((2,), jnp.uint32)
+        key_struct = jax.ShapeDtypeStruct(self._key.shape, self._key.dtype)
         train = {n: self.params[n] for n in self._train_keys}
         state = {n: self.params[n] for n in self._state_names}
         args = (train, state, self._opt_states, batch_struct, labels_struct,

@@ -19,20 +19,12 @@ _state = threading.local()
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: the public API renamed the
-    replication-check kwarg (check_vma) and older versions only ship
-    ``jax.experimental.shard_map`` (check_rep). One shim, shared by the
-    pipeline and ring-attention modules."""
-    try:
-        from jax import shard_map
+    """``jax.shard_map`` without the replication check — one spelling,
+    shared by the trainer, pipeline and ring-attention modules."""
+    import jax
 
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-    except (ImportError, TypeError):
-        from jax.experimental.shard_map import shard_map as sm
-
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
 
 
 def make_mesh(shape: Dict[str, int] = None, devices=None):

@@ -25,6 +25,7 @@ Resilience wiring (all existing subsystems, reused):
 """
 from __future__ import annotations
 
+import functools
 import threading
 import time
 
@@ -32,6 +33,7 @@ import numpy as _onp
 
 from ..base import MXNetError
 from ..cachedop import CachedOpThreadSafe
+from ..device import current_context
 from ..profiler import core as _prof
 from ..profiler import export as _export
 from ..profiler import trace as _trace
@@ -86,20 +88,25 @@ class DeadlineExceeded(ServeError):
     status = 504
 
 
-def _deterministic_compiler_options():
-    """XLA overrides for serving executables. On the CPU backend the
-    default thunk runtime partitions fused loops differently per graph
-    shape — even the shape-stable mul+reduce ops (``ops.nn.stable_dense``,
-    ``cached_attention``) drift a few ulps between the T=1 and T=bucket
-    executables under it; pin the legacy runtime, whose codegen is
-    shape-stable for those formulations (both pieces are needed: with
-    gemm-based Dense the legacy runtime drifts too). Other backends
-    compile with their defaults."""
-    import jax
+def block_context(block):
+    """The context a block's parameters live on — where a server built
+    over it puts its rings, page pools and inputs. Serving takes the
+    device from the model it is given rather than from the caller's
+    (thread-local) default context, which its worker threads never see."""
+    try:
+        return next(iter(block.collect_params().values())).list_ctx()[0]
+    except (StopIteration, MXNetError):  # no parameters / not initialized
+        return current_context()
 
-    if jax.default_backend() == "cpu":
-        return {"xla_cpu_use_thunk_runtime": False}
-    return None
+
+def on_block_context(method):
+    """Run a serving entry point under ``self.ctx`` (see
+    :func:`block_context`), so every array it creates lands there."""
+    @functools.wraps(method)
+    def wrapped(self, *args, **kwargs):
+        with self.ctx:
+            return method(self, *args, **kwargs)
+    return wrapped
 
 
 def pick_bucket(n, buckets):
@@ -130,28 +137,20 @@ class InferenceSession:
         (token arrays). ``None`` disables seq padding.
     pad_value : scalar
         Fill for padded sequence positions (token id 0 by default).
-    deterministic : bool
-        Compile with the pinned shape-stable runtime options (the PR-5
-        bitwise contract; default). ``False`` compiles with the backend's
-        default options — the serving fast rungs select this per-CachedOp
-        because the pinned CPU legacy runtime is itself a large decode-
-        throughput tax.
     """
 
     def __init__(self, block, batch_buckets=(1, 2, 4, 8), seq_buckets=None,
-                 pad_value=0, name=None, deterministic=True):
+                 pad_value=0, name=None):
         from .. import config
 
         self.block = block
+        self.ctx = block_context(block)
         self.batch_buckets = tuple(sorted(int(b) for b in batch_buckets))
         self.seq_buckets = (tuple(sorted(int(s) for s in seq_buckets))
                             if seq_buckets else None)
         self.pad_value = pad_value
         self.name = name or type(block).__name__
-        self.deterministic = bool(deterministic)
-        self._op = CachedOpThreadSafe(
-            block, compiler_options=(_deterministic_compiler_options()
-                                     if self.deterministic else None))
+        self._op = CachedOpThreadSafe(block)
         self.metrics = ServeMetrics(self.name)
         self.breaker = CircuitBreaker(
             failure_threshold=config.get("MXNET_SERVE_BREAKER_THRESHOLD"),
@@ -177,6 +176,7 @@ class InferenceSession:
 
         return config.get("MXNET_SERVE_TIMEOUT_MS") / 1e3
 
+    @on_block_context
     def run(self, *args):
         """Execute one already-bucketed call under the full protection
         stack (breaker -> fault site -> watchdog -> cachedop). Raises
@@ -224,7 +224,9 @@ class InferenceSession:
                     # timeout
                     _faults.fault_point("serve:execute",
                                         {"session": self.name})
-                    with autograd.predict_mode():
+                    # the watchdog runs this on its own thread: re-enter
+                    # the context for what the trace creates
+                    with self.ctx, autograd.predict_mode():
                         return self._op(*args)
 
                 # ambient-trace span: when the batcher activated a
@@ -287,7 +289,7 @@ class InferenceSession:
                 seq_w = [(0, 0), (0, st - t)] + [(0, 0)] * (data.ndim - 2)
                 padded = _onp.pad(padded, seq_w, mode="constant",
                                   constant_values=self.pad_value)
-        return mnp.array(padded), b, t
+        return mnp.array(padded, ctx=self.ctx), b, t
 
     def predict(self, data):
         """Serve one request batch: pad onto the bucket lattice, execute,
@@ -458,7 +460,8 @@ class InferenceSession:
                 if any(getattr(p, "_deferred_init", None) is not None
                        and p._data is None for p in params):
                     with autograd.predict_mode():
-                        new_block(mnp.array(_onp.asarray(example)))
+                        new_block(mnp.array(_onp.asarray(example),
+                                            ctx=block_context(new_block)))
             if self._signature_matches(new_block):
                 mode = "warm"
                 olds = list(self.block.collect_params().values())
@@ -468,10 +471,8 @@ class InferenceSession:
             else:
                 mode = "cold"
                 self.block = new_block
-                self._op = CachedOpThreadSafe(
-                    new_block,
-                    compiler_options=(_deterministic_compiler_options()
-                                      if self.deterministic else None))
+                self.ctx = block_context(new_block)
+                self._op = CachedOpThreadSafe(new_block)
                 self._warm_signatures = None
                 self._shapes_ready = False
                 if example is not None:

@@ -55,12 +55,13 @@ override (CPU tests run 16-wide pages).
 """
 from __future__ import annotations
 
+import functools
 import threading
 
 import numpy as _onp
 
 from ..base import MXNetError
-from .engine import PoolExhausted
+from .engine import PoolExhausted, block_context
 
 
 def resolve_page_size(page_size, max_seq):
@@ -136,6 +137,8 @@ class PagedKVPool:
         # (P, KV, page) f32 scale pools — interleaved in flat() exactly
         # like KVCache.flat() so _CacheForward's calling convention is
         # shared between ring and paged steps
+        self.ctx = block_context(model)
+        zeros = functools.partial(mnp.zeros, ctx=self.ctx)
         self._arrays = []
         for blk in model._blocks:
             attn = blk.attention
@@ -143,13 +146,13 @@ class PagedKVPool:
                      attn._head_dim)
             if quant == "int8":
                 self._arrays.extend((
-                    mnp.zeros(shape, dtype="int8"),
-                    mnp.zeros(shape[:3], dtype="float32"),
-                    mnp.zeros(shape, dtype="int8"),
-                    mnp.zeros(shape[:3], dtype="float32")))
+                    zeros(shape, dtype="int8"),
+                    zeros(shape[:3], dtype="float32"),
+                    zeros(shape, dtype="int8"),
+                    zeros(shape[:3], dtype="float32")))
             else:
-                self._arrays.extend((mnp.zeros(shape, dtype="float32"),
-                                     mnp.zeros(shape, dtype="float32")))
+                self._arrays.extend((zeros(shape, dtype="float32"),
+                                     zeros(shape, dtype="float32")))
         # host allocator state: LIFO free list (hot pages recycle first),
         # per-slot owned pages, the canonical page-table matrix
         self._lock = threading.Lock()
@@ -195,7 +198,7 @@ class PagedKVPool:
 
         with self._lock:
             if self._table_nd is None:
-                self._table_nd = mnp.array(self._table)
+                self._table_nd = mnp.array(self._table, ctx=self.ctx)
             return self._table_nd
 
     # -- allocator -----------------------------------------------------------

@@ -56,7 +56,7 @@ from ..profiler import trace as _trace
 from ..resilience import faults as _faults
 from .batcher import DynamicBatcher
 from .engine import DeadlineExceeded, InferenceSession, PoolExhausted, \
-    ServeError, ServiceUnavailable
+    ServeError, ServiceUnavailable, on_block_context
 from .generate import _CacheForward, _MultiStepForward, _STOP_WIDTH, \
     _fresh_key_bits, _int8_weights_enabled, _quantize_serving_weights, \
     _stop_matrix, resolve_decode_path, sample_tokens
@@ -186,8 +186,8 @@ class ContinuousEngine:
             self._step_block,
             batch_buckets=tuple(sorted({1, self.num_slots})),
             seq_buckets=tuple(sorted({1, self.prefill_chunk})),
-            pad_value=self.pad_id, name=name,
-            deterministic=(self.decode_path == "baseline"))
+            pad_value=self.pad_id, name=name)
+        self.ctx = self.session.ctx  # the model's device: inputs go there
         self.metrics = self.session.metrics
         self.metrics.set_decode_path(self.decode_path)
         self.metrics.set_kv_cache_bytes(self.pool.nbytes())
@@ -230,8 +230,7 @@ class ContinuousEngine:
             self._msession = InferenceSession(
                 self._mstep, batch_buckets=(self.num_slots,),
                 seq_buckets=(1,), pad_value=self.pad_id,
-                name=f"{name}_multi",
-                deterministic=(self.decode_path == "baseline"))
+                name=f"{name}_multi")
             # one key per engine; per-request streams come from folding
             # each slot's admission seed (and position) into it in-trace
             self._key_bits = _fresh_key_bits()
@@ -711,6 +710,7 @@ class ContinuousEngine:
         for tr in targets:
             tr.span_at(name, t0_ns, t1_ns, args)
 
+    @on_block_context
     def step(self):
         """One scheduler iteration: retire -> admit -> one prefill chunk
         -> one decode step -> gauges. Execution failures (an injected
@@ -764,6 +764,7 @@ class ContinuousEngine:
             self.step()
 
     # -- lifecycle -----------------------------------------------------------
+    @on_block_context
     def warmup(self):
         """Compile BOTH live signatures and freeze the set: one
         (1, chunk) prefill chunk plus — classic mode — one

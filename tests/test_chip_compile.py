@@ -1,0 +1,130 @@
+"""The main path's Pallas kernels, compiled for a described TPU v5e.
+
+Nothing runs: the chip's own compiler (Mosaic + XLA:TPU, installed with
+libtpu) lowers each kernel at the widths ``chip_smoke.py`` serves and
+trains, for a device that is described rather than attached. What it
+refuses here it would refuse on the chip — interpret-mode tests cannot
+see f64/i64 leaks from ``jax_enable_x64``, tiling or VMEM limits.
+
+The topology is described inside a module-scoped fixture (never at
+import: one process may hold libtpu, and every xdist worker imports every
+test file), and all such compiles live in this one file so one worker
+owns the library. What crossed four chips and broke goes here too.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu.models.llama import _LLAMA_CONFIGS
+from mxnet_tpu.ops.pallas import decode_attention as da
+from mxnet_tpu.ops.pallas import flash_attention as fa
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # pylint: disable=broad-except
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device executable can be written to the persistent cache
+    # but never read back without a chip; keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+_8B = _LLAMA_CONFIGS["llama3_8b"]
+_12L = _LLAMA_CONFIGS["llama_serve_12l_test"]
+
+
+@pytest.mark.parametrize("cfg,b,s,ring", [
+    (_8B, 8, 2048, jnp.float32),
+    (_8B, 8, 2048, jnp.bfloat16),
+    (_8B, 8, 2048, jnp.int8),
+    (_12L, 2, 64, jnp.float32),
+], ids=["8b-f32", "8b-bf16", "8b-int8", "serve12l-f32"])
+def test_decode_attention_compiles_for_v5e(one_chip, cfg, b, s, ring):
+    h, kv = cfg["num_heads"], cfg["num_kv_heads"]
+    d = cfg["units"] // h
+    int8 = ring == jnp.int8
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q = sds((b, h, 1, d), jnp.float32 if int8 else ring)
+    k = sds((b, kv, s, d), ring)
+    scales = [sds((b, kv, s), jnp.float32)] * 2 if int8 else []
+
+    def fn(q, k, v, sp, *scales):
+        return da._pallas_decode(q, k, v, sp, d ** -0.5,
+                                 *(scales or (None, None)))
+
+    text = _compile(fn, q, k, k, sds((b,), jnp.int32), *scales)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_flash_attention_compiles_for_v5e(one_chip, grad):
+    qkv = jax.ShapeDtypeStruct((2, 32, 2048, 128), jnp.bfloat16,
+                               sharding=one_chip)
+    vl = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one_chip)
+
+    def fwd(q, k, v, vl):
+        return fa._flash_core(q, k, v, vl, True, 128 ** -0.5)
+
+    def loss(q, k, v, vl):
+        return fwd(q, k, v, vl).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    assert "tpu_custom_call" in _compile(fn, qkv, qkv, qkv, vl)
+
+
+def test_dropout_partitions_over_four_chips(topo):
+    """A dp-sharded dropout mask under the default ``rbg`` generator. With
+    a bare Python probability ``jax_enable_x64`` made it a float64 draw
+    from 64-bit random bits, and the v5e compiler aborted on the
+    partitioned 64-bit RngBitGenerator (the first four-chip BERT step)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from mxnet_tpu import autograd
+    from mxnet_tpu import random as mx_random
+    from mxnet_tpu.ndarray.ndarray import NDArray
+    from mxnet_tpu.ops import nn as ops
+
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("dp",))
+    x = jax.ShapeDtypeStruct((64, 128, 768), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("dp")))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    key = jax.ShapeDtypeStruct(key.shape, key.dtype,
+                               sharding=NamedSharding(mesh, P()))
+
+    def fn(key, x):
+        mx_random.push_trace_rng(key)
+        try:
+            with autograd.train_mode():
+                return ops.dropout(NDArray(x), p=0.1)._data
+        finally:
+            mx_random.pop_trace_rng()
+
+    assert "rng-bit-generator" in _compile(fn, key, x)

@@ -42,8 +42,11 @@ print("CC_CHILD=" + json.dumps({
 """
 
 
-def _spawn(cache_dir):
+def _spawn(cache_dir, outside=None):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if outside is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(outside)
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
         + env.get("PYTHONPATH", "").split(os.pathsep))
@@ -98,7 +101,38 @@ class TestStableKeys:
 
 
 class TestEnableDisable:
-    def test_opt_in_and_repoint(self, tmp_path):
+    def test_placed_from_outside(self, tmp_path, monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR places the cache: an explicit path
+        (and the repo's flag) have no say, jax_compilation_cache_dir is
+        never written, and the executables land in the outside dir."""
+        import jax
+
+        outside, inside = tmp_path / "outside", tmp_path / "inside"
+        prev = compile_cache.cache_dir()
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(outside))
+        try:
+            assert compile_cache.enable(str(inside))
+            assert compile_cache.stats()["dir"] == str(outside)
+            assert jax.config.jax_compilation_cache_dir == before
+        finally:
+            compile_cache.disable()  # env still set: forgets, writes nothing
+            assert jax.config.jax_compilation_cache_dir == before
+            monkeypatch.undo()
+            if prev is not None:
+                compile_cache.enable(prev)
+        p1 = _spawn(inside, outside=outside)
+        p2 = _spawn(inside, outside=outside)
+        assert p1["disk_misses"] > 0
+        assert p2["disk_hits"] > 0 and p2["disk_misses"] == 0
+        assert any(f.endswith("-cache") for f in os.listdir(outside))
+        assert not inside.exists()
+
+    def test_opt_in_and_repoint(self, tmp_path, monkeypatch):
+        import jax
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        before = jax.config.jax_compilation_cache_dir
         prev = compile_cache.cache_dir()
         try:
             assert compile_cache.enable(str(tmp_path / "a"))
@@ -119,6 +153,8 @@ class TestEnableDisable:
                 assert compile_cache.enable() is False
         finally:
             compile_cache.disable()
+            jax.config.update("jax_compilation_cache_dir", before)
+            monkeypatch.undo()
             if prev is not None:
                 compile_cache.enable(prev)
 

@@ -235,3 +235,31 @@ def test_gluon_contrib_nn_layers():
     with autograd.record():
         emb(np.array(onp.array([1, 2], "int64"))).sum().backward()
     assert isinstance(emb.weight.grad(), RowSparseNDArray)
+
+
+def test_hybridized_call_hands_back_only_rebound_state():
+    """A frozen parameter (grad_req='null') is an input of the compiled
+    forward, not an output: a call must not copy it (at Llama-8B widths
+    that copy is the whole model, per call). State the forward does
+    rebind — BatchNorm running statistics in train mode — still comes
+    back and is written through."""
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(4, in_units=3), gluon.nn.BatchNorm(in_channels=4))
+    net.initialize()
+    net[0].collect_params().setattr("grad_req", "null")
+    net.hybridize()
+    x = mx.np.array(onp.arange(6, dtype="float32").reshape(2, 3))
+
+    def buffers():
+        return [p.data()._data.unsafe_buffer_pointer()
+                for p in (net[0].weight, net[0].bias, net[1].running_mean)]
+
+    before = buffers()
+    with autograd.predict_mode():
+        net(x).wait_to_read()
+    assert buffers() == before
+    mean = net[1].running_mean.data().asnumpy().copy()
+    with autograd.record():
+        net(x).wait_to_read()
+    assert buffers()[:2] == before[:2]
+    assert not onp.allclose(net[1].running_mean.data().asnumpy(), mean)

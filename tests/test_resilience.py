@@ -200,7 +200,7 @@ def test_call_with_retry_fatal_not_retried():
 
 def test_is_transient_classification():
     assert retry.is_transient(faults.TransientFaultError("x"))
-    assert retry.is_transient(RuntimeError("UNAVAILABLE: tunnel dropped"))
+    assert retry.is_transient(RuntimeError("UNAVAILABLE: connection dropped"))
     assert retry.is_transient(RuntimeError("RESOURCE_EXHAUSTED: compiling"))
     assert not retry.is_transient(faults.InjectedFaultError("x"))
     assert not retry.is_transient(ValueError("bad shape"))

@@ -573,3 +573,84 @@ class TestServeTimeout:
         with pytest.raises(ServiceUnavailable, match="MXNET_SERVE_TIMEOUT"):
             sess.predict(x)
         assert time.monotonic() - t0 < 0.9  # fast 503, not the full hang
+
+
+# ---------------------------------------------------------------------------
+# Device placement: serving computes where the model's parameters live
+# ---------------------------------------------------------------------------
+
+
+def _device_of(nd):
+    (dev,) = nd._data.devices()
+    return dev
+
+
+class TestServePlacement:
+    """The device is observed from the block (``Parameter.list_ctx``),
+    not from the caller's thread-local default context — so a model on
+    device 1 is served on device 1, worker threads included, with no
+    ``with ctx:`` around the caller."""
+
+    CTX = mx.cpu(1)
+
+    def _net(self):
+        net = get_llama("llama_tiny_test")
+        net.initialize(ctx=self.CTX)
+        return net
+
+    @pytest.mark.parametrize("kw", [
+        dict(decode_path="baseline"),
+        dict(decode_path="pallas"),
+        dict(decode_path="int8"),
+        dict(decode_path="baseline", paged=True),
+        dict(decode_path="pallas", multistep=True),
+    ], ids=["baseline", "pallas", "int8", "paged", "multistep"])
+    def test_generator_rings_and_logits_follow_the_block(self, kw):
+        want = self.CTX.jax_device()
+        gen = Generator(self._net(), max_seq=32, batch_buckets=(2,),
+                        prompt_buckets=(8,), name="place_gen", **kw)
+        assert gen.ctx == self.CTX
+        cache = gen._fresh_cache(2)
+        assert {_device_of(a) for a in cache.flat()} == {want}
+        toks = np.zeros((2, 8), np.int32)
+        logits, cache = gen.prefill(toks, np.array([3, 3], np.int32), cache)
+        assert _device_of(logits) == want
+        assert {_device_of(a) for a in cache.flat()} == {want}
+        out, _ = gen.generate([[1, 2, 3], [4, 5]], max_new_tokens=4)
+        assert [len(o) for o in out] == [4, 4]
+
+    def test_session_and_batcher_thread_follow_the_block(self):
+        want = self.CTX.jax_device()
+        net = gluon.nn.HybridSequential()
+        net.add(gluon.nn.Dense(16, activation="relu"), gluon.nn.Dense(4))
+        net.initialize(ctx=self.CTX)
+        sess = InferenceSession(net, batch_buckets=(1, 4), name="place_cls")
+        assert sess.ctx == self.CTX
+        seen = []
+
+        def runner(payloads):
+            out = sess.predict(np.stack(payloads))
+            seen.append(_device_of(out))
+            return list(out.asnumpy())
+
+        with DynamicBatcher(runner, max_batch_size=4, timeout_ms=5,
+                            name="place_b") as b:
+            b.submit(np.ones(16, np.float32)).result(10)
+        assert seen == [want]
+
+    def test_continuous_engine_thread_follows_the_block(self):
+        from mxnet_tpu.serve import ContinuousEngine
+
+        want = self.CTX.jax_device()
+        net = self._net()
+        ref, _ = Generator(net, max_seq=32, batch_buckets=(1,),
+                           prompt_buckets=(8,), decode_path="baseline",
+                           name="place_ref").generate([[1, 2, 3]],
+                                                      max_new_tokens=4)
+        with ContinuousEngine(net, max_seq=32, num_slots=2, page_size=8,
+                              prefill_chunk=8, decode_path="baseline",
+                              name="place_cb") as eng:
+            assert eng.ctx == self.CTX
+            got = eng.submit([1, 2, 3], max_new_tokens=4).result(30)
+            assert {_device_of(a) for a in eng.pool.flat()} == {want}
+        assert got["tokens"] == ref[0]

@@ -78,6 +78,14 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
+def _device():
+    """Where the smokes put their models: the chip when JAX sees one,
+    else the host — observed, not a flag. Serving follows the model."""
+    import mxnet_tpu as mx
+
+    return mx.tpu() if mx.num_tpus() else mx.cpu()
+
+
 def _trace_epilogue(sess, batcher_cls, runner, x, trace_out):
     """Injected-fault forensics + trace dump (the --trace-out half)."""
     import json
@@ -158,7 +166,7 @@ def _run_prefix_child(cache_dir):
     compile_cache.enable(cache_dir)
     mx.random.seed(0)
     model = get_llama("llama_tiny_test")
-    model.initialize()
+    model.initialize(ctx=_device())
     eng = ContinuousEngine(model, max_seq=64, num_slots=4, page_size=8,
                            prefill_chunk=8, decode_path="baseline",
                            name="smoke_prefix_child")
@@ -187,7 +195,7 @@ def _run_prefix():
 
     mx.random.seed(0)
     model = get_llama("llama_tiny_test")
-    model.initialize()
+    model.initialize(ctx=_device())
 
     system = list(range(3, 23))  # 20-token shared system prompt
     prompts = [system + [30 + i, 40 + i, 50 + i] for i in range(8)]
@@ -231,6 +239,10 @@ def _run_prefix():
     # stable signature keys and identical greedy output
     child = [sys.executable, os.path.abspath(__file__)]
     env = dict(os.environ, JAX_PLATFORMS="cpu")
+    # a cold/warm measurement over its own fresh directory: a cache placed
+    # from outside would take the explicit path's say away
+    # (compile_cache.enable) and hand the "cold" child a warm cache
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     docs = []
     with tempfile.TemporaryDirectory() as d:
         for i in (1, 2):
@@ -281,7 +293,7 @@ def _run_multistep():
 
     mx.random.seed(0)
     model = get_llama("llama_tiny_test")
-    model.initialize()
+    model.initialize(ctx=_device())
     prompts = [[5 + i, 9, 2, (3 * i) % 11 + 1] for i in range(8)]
 
     # reference: classic one-visit-per-token engine, sequential requests
@@ -394,10 +406,10 @@ def _run_decode(path):
 
     mx.random.seed(0)
     model = get_llama("llama_tiny_test")
-    model.initialize()
+    model.initialize(ctx=_device())
     if path == "spec":
         draft = get_llama("llama_tiny_test", num_layers=1)
-        draft.initialize()
+        draft.initialize(ctx=_device())
         gen = SpeculativeGenerator(model, draft, k=2, max_seq=48,
                                    batch_buckets=(2,), prompt_buckets=(8,),
                                    name="smoke_spec")
@@ -505,7 +517,7 @@ def _run(trace_out=None, slo=False):
     net = gluon.nn.HybridSequential()
     net.add(gluon.nn.Dense(32, activation="relu"))
     net.add(gluon.nn.Dense(8))
-    net.initialize()
+    net.initialize(ctx=_device())
 
     sess = InferenceSession(net, batch_buckets=(1, 2, 4, 8), name="smoke")
     monitor = None
