@@ -22,6 +22,7 @@ from collections import OrderedDict
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from ..base import MXNetError
+from ..profiler.core import host_span
 
 
 def _jax():
@@ -1129,12 +1130,38 @@ class ShardedTrainer:
         wds = tuple(self.optimizer._get_wd(i) for i in range(n_train))
         return lrs, wds, t_first
 
+    def _advance_and_run(self, jit_fn, sig_head, d, l, n):
+        """What ``step`` and ``step_n`` share once the batch is placed:
+        the optimizer's scalars, the RNG split, the argument tuple with
+        its signature, and the compiled call."""
+        import jax
+
+        with host_span("mxnet_tpu.trainer.optimizer_scalars",
+                       scalars=2 * len(self._train_keys)):
+            lrs, wds, t = self._advance_optimizer(n)
+        with host_span("mxnet_tpu.trainer.rng_split"):
+            self._key, sub = jax.random.split(self._key)
+        with host_span("mxnet_tpu.trainer.gather_args") as span:
+            train = {k: self.params[k] for k in self._train_keys}
+            state = {k: self.params[k] for k in self._state_names}
+            args = (train, state, self._opt_states, d, l, sub, lrs, wds, t)
+            sig = sig_head + tuple(
+                (x.shape, str(x.dtype))
+                for x in jax.tree_util.tree_leaves((d, l)))
+            if span.is_enabled():   # a profiler session: count for it
+                span.set_metadata(
+                    leaves=len(jax.tree_util.tree_leaves(args)))
+        return self._run_compiled(sig, jit_fn, args)
+
     def _run_compiled(self, sig, jit_fn, args):
         """AOT-compile once per signature (a partial final batch gets its
         own executable): the compiled callable skips per-call signature
         matching and exposes XLA's cost analysis — the exact per-step
-        FLOPs source for MFU reporting. Returns the executable's outputs;
-        updates params/opt state from the first three."""
+        FLOPs source for MFU reporting. Updates params/opt state from
+        the executable's first three outputs; returns the fourth (the
+        loss or losses) as an NDArray."""
+        from ..ndarray.ndarray import NDArray
+
         if self._abstract:
             raise MXNetError(
                 "this ShardedTrainer was built with abstract=True "
@@ -1143,9 +1170,10 @@ class ShardedTrainer:
                 "abstract to train")
         hit = self._compiled.get(sig)
         if hit is None:
-            compiled = jit_fn.lower(*args).compile()
-            flops = _cost_analysis_of(compiled).get("flops")
-            self._compiled[sig] = (compiled, flops)
+            with host_span("mxnet_tpu.trainer.compile"):
+                compiled = jit_fn.lower(*args).compile()
+                flops = _cost_analysis_of(compiled).get("flops")
+                self._compiled[sig] = (compiled, flops)
         else:
             compiled, flops = hit
         # refresh per call so the property tracks the LAST executed
@@ -1153,11 +1181,13 @@ class ShardedTrainer:
         # per-step figure even for step_n windows)
         self._step_flops = flops
         self._last_compiled = compiled
-        new_train, new_state, new_opt, out = compiled(*args)
-        self.params.update(new_train)
-        self.params.update(new_state)
-        self._opt_states = new_opt
-        return out
+        with host_span("mxnet_tpu.trainer.call"):
+            new_train, new_state, new_opt, out = compiled(*args)
+        with host_span("mxnet_tpu.trainer.commit"):
+            self.params.update(new_train)
+            self.params.update(new_state)
+            self._opt_states = new_opt
+            return NDArray(out)
 
     def step(self, data, labels):
         """Run one SPMD training step; returns the scalar loss as an
@@ -1165,9 +1195,6 @@ class ShardedTrainer:
 
         ``data`` may be a single array or a tuple of arrays (multi-input
         models, e.g. (tokens, segments) for BERT)."""
-        import jax
-
-        from ..ndarray.ndarray import NDArray
         from ..resilience import faults as _faults
 
         # chip-loss injection surface for composed-mesh elasticity: a
@@ -1176,19 +1203,13 @@ class ShardedTrainer:
         # ICI/chip failure would surface as a poisoned dispatch
         _faults.fault_point("trainer:sharded_step",
                             {"step": self._step_count})
-        if self._step_jit is None:
-            self._build_step()
-        d, l = self._unwrap_batch(data, labels)
-        lrs, wds, t = self._advance_optimizer(1)
-        self._key, sub = jax.random.split(self._key)
-        train = {n: self.params[n] for n in self._train_keys}
-        state = {n: self.params[n] for n in self._state_names}
-        args = (train, state, self._opt_states, d, l, sub, lrs, wds, t)
-        sig = tuple(
-            (x.shape, str(x.dtype))
-            for x in jax.tree_util.tree_leaves((d, l)))
-        loss = self._run_compiled(sig, self._step_jit, args)
-        return NDArray(loss)
+        with host_span("mxnet_tpu.trainer.step",
+                       step=self._step_count + 1, n=1):
+            if self._step_jit is None:
+                self._build_step()
+            with host_span("mxnet_tpu.trainer.unwrap"):
+                d, l = self._unwrap_batch(data, labels)
+            return self._advance_and_run(self._step_jit, (), d, l, 1)
 
     def step_n(self, data, labels, num_steps=None):
         """Run MANY SPMD training steps in ONE compiled dispatch.
@@ -1202,33 +1223,28 @@ class ShardedTrainer:
         """
         import jax
 
-        from ..ndarray.ndarray import NDArray
-
-        if self._step_jit is None:
-            self._build_step()
-        d, l = self._unwrap_batch(data, labels,
-                                  spec=_P()(None, *self.batch_spec))
-        avail = jax.tree_util.tree_leaves(d)[0].shape[0]
-        n = avail if num_steps is None else int(num_steps)
-        if n < 1 or n > avail:
-            raise MXNetError(
-                f"step_n: num_steps={num_steps} but the stacked leading "
-                f"axis holds {avail} step batches")
-        if avail != n:
-            # scan runs the whole leading axis: slice so bookkeeping
-            # (update counts, lr schedule, FLOPs) matches execution
-            d = jax.tree_util.tree_map(lambda x: x[:n], d)
-            l = jax.tree_util.tree_map(lambda x: x[:n], l)
-        lrs, wds, t0 = self._advance_optimizer(n)
-        self._key, sub = jax.random.split(self._key)
-        train = {k: self.params[k] for k in self._train_keys}
-        state = {k: self.params[k] for k in self._state_names}
-        args = (train, state, self._opt_states, d, l, sub, lrs, wds, t0)
-        sig = ("step_n", n, tuple(
-            (x.shape, str(x.dtype))
-            for x in jax.tree_util.tree_leaves((d, l))))
-        losses = self._run_compiled(sig, self._stepn_jit, args)
-        return NDArray(losses)
+        with host_span("mxnet_tpu.trainer.step",
+                       step=self._step_count + 1) as span:
+            if self._step_jit is None:
+                self._build_step()
+            with host_span("mxnet_tpu.trainer.unwrap"):
+                d, l = self._unwrap_batch(
+                    data, labels, spec=_P()(None, *self.batch_spec))
+                avail = jax.tree_util.tree_leaves(d)[0].shape[0]
+                n = avail if num_steps is None else int(num_steps)
+                if n < 1 or n > avail:
+                    raise MXNetError(
+                        f"step_n: num_steps={num_steps} but the stacked "
+                        f"leading axis holds {avail} step batches")
+                if avail != n:
+                    # scan runs the whole leading axis: slice so
+                    # bookkeeping (update counts, lr schedule, FLOPs)
+                    # matches execution
+                    d = jax.tree_util.tree_map(lambda x: x[:n], d)
+                    l = jax.tree_util.tree_map(lambda x: x[:n], l)
+            span.set_metadata(n=n)
+            return self._advance_and_run(self._stepn_jit, ("step_n", n),
+                                         d, l, n)
 
     def save_checkpoint(self, path):
         """Checkpoint the FULL training state — params, optimizer state,

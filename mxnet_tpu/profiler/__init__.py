@@ -178,10 +178,8 @@ def get_summary(reset=False):  # pylint: disable=redefined-outer-name
 def scope(name="<unk>:", cat="scope"):
     """Named range: lands in the aggregate table always, in the chrome
     trace when running, and in the XLA device trace when one is active."""
-    import jax
-
     t0 = time.perf_counter_ns()
-    with jax.profiler.TraceAnnotation(name):
+    with core.host_span(name):
         yield
     core.record_duration(name, cat, t0)
 
@@ -196,10 +194,8 @@ class Task:
         self._ann = None
 
     def start(self):
-        import jax
-
         self._t0 = time.perf_counter_ns()
-        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann = core.host_span(self.name)
         self._ann.__enter__()
 
     def stop(self):

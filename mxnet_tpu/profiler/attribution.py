@@ -13,9 +13,10 @@ exclusive phases:
 * **dispatch** — issuing the step executable (async: the call returns
   before the device finishes);
 * **device**   — the delta around the blocking fetch of the step's
-  logits (argmax/sample + device->host copy). Cross-checkable against
-  ``profiler.xla.device_op_stats`` when an XLA capture is live
-  (:func:`device_cross_check`);
+  logits (argmax/sample + device->host copy): a host-clock estimate.
+  The device's own time is in a ``jax.profiler`` capture, where the
+  engine's host spans (``core.host_span``) lie on the same clock
+  (OBSERVABILITY.md, "Host spans on the device trace's clock");
 * **wait**     — ``engine:wait`` stalls *outside* the sanctioned
   blocking fetch, fed by the (now phase-tagged) wait hooks in
   ``engine.py``.
@@ -329,24 +330,3 @@ def report(trace_id):
                              if ledger_steps else 0.0),
         "coverage": accounted / total if total > 0 else 0.0,
     }
-
-
-def device_cross_check(ledger_device_ms, trace_dir):
-    """Cross-check the ledger's blocking-fetch device estimate against
-    an XLA capture's per-op device rows (``xla.device_op_stats``).
-    Returns ``{"ledger_device_ms", "xla_device_ms", "ratio"}``, or
-    ``None`` when the capture has no device rows (pure-CPU run) or can't
-    be parsed — the ledger stands alone there."""
-    from ..base import MXNetError
-    from . import xla as _xla
-
-    try:
-        rows = _xla.device_op_stats(trace_dir)
-    except (MXNetError, OSError, ValueError):
-        return None
-    xla_ms = sum(float(r.get("total_us", 0.0)) for r in rows) / 1e3
-    if xla_ms <= 0.0:
-        return None
-    led = float(ledger_device_ms)
-    return {"ledger_device_ms": led, "xla_device_ms": xla_ms,
-            "ratio": led / xla_ms}

@@ -24,6 +24,8 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 # -- hot flags (read by instrumented modules; written by the facade) --------
 ENABLED = False      # event bus recording is on (profiler.set_state('run'))
 IMPERATIVE = False   # per-op dispatch counters (set_config(profile_imperative=True))
@@ -38,6 +40,19 @@ _agg = collections.defaultdict(lambda: [0, 0.0])  # name -> [calls, total_s]
 _op_counts: collections.Counter = collections.Counter()  # imperative op calls
 _counters: dict = {}                 # counter name -> last value
 _thread_names: dict = {}             # tid -> human name ('M' metadata events)
+
+
+def host_span(name, **stats):
+    """A host span on the device trace's clock: the one primitive the
+    hot paths are instrumented with (``ContinuousEngine.step``,
+    ``ShardedTrainer.step``). With a ``jax.profiler`` session running
+    the span and its ``stats`` land in the ``.xplane.pb`` on the calling
+    thread's line, beside the device planes; with none it costs the
+    construction of one object and TraceMe's own inactive check. Nothing
+    is kept here: no event, no aggregate row. Names are fixed strings,
+    ``mxnet_tpu.<layer>.<phase>``; an engine's or a block's name goes in
+    ``stats``."""
+    return _TraceAnnotation(name, **stats)
 
 
 def begin() -> int:

@@ -343,6 +343,12 @@ class ServeMetrics:
         with self._lock:
             return list(zip(self._itl_ms, self._itl_live))
 
+    def queue_samples(self):
+        """Windowed queue-wait milliseconds, one a settled request
+        (what :meth:`observe_request` was given), oldest first."""
+        with self._lock:
+            return list(self._queue_ms)
+
     def latency_percentiles(self):
         with self._lock:
             lat = list(self._latency_ms)

@@ -53,6 +53,7 @@ import numpy as _onp
 from ..base import MXNetError
 from ..profiler import attribution as _attr
 from ..profiler import trace as _trace
+from ..profiler.core import host_span
 from ..resilience import faults as _faults
 from .batcher import DynamicBatcher
 from .engine import DeadlineExceeded, InferenceSession, PoolExhausted, \
@@ -75,7 +76,7 @@ class _Slot:
     __slots__ = ("p", "prompt", "consumed", "pos", "decoding", "pending",
                  "tokens", "max_new", "temperature", "top_k", "stop",
                  "finished", "expired", "t_admit", "admit_wait_steps",
-                 "ttft_ms", "decode_steps", "seed")
+                 "ttft_ms", "decode_steps", "seed", "token_ms")
 
     def __init__(self, p, steps_now, seed=0):
         payload = p.payload
@@ -86,6 +87,7 @@ class _Slot:
         self.decoding = False      # prefill complete, pending token live
         self.pending = 0           # next token id to feed the decode step
         self.tokens = []           # emitted output ids
+        self.token_ms = []         # each kept token's ms since enqueue
         self.max_new = payload["max_new"]
         self.temperature = payload["temperature"]
         self.top_k = payload["top_k"]
@@ -101,12 +103,15 @@ class _Slot:
         # requests reusing one slot never share a draw stream
         self.seed = int(seed)
 
-    def emit(self, tid):
-        """Account one sampled token; flips ``finished`` on stop/budget."""
+    def emit(self, tid, now):
+        """Account one sampled token; flips ``finished`` on stop/budget.
+        ``now`` (``time.monotonic()`` of the visit that surfaced it)
+        stamps a kept token on the clock and origin of ``ttft_ms``."""
         if tid in self.stop:
             self.finished = True
             return
         self.tokens.append(tid)
+        self.token_ms.append((now - self.p.t_enq) * 1e3)
         if len(self.tokens) >= self.max_new:
             self.finished = True
         else:
@@ -240,8 +245,13 @@ class ContinuousEngine:
                top_k=None, stop_ids=(), priority="interactive",
                deadline_ms=None, key=None):
         """Admit one generation request; returns a Future resolving to
-        ``{"tokens": [...], "ttft_ms": ..., "admit_wait_steps": ...,
-        "decode_steps": ...}``. The full PR-6 admission surface applies
+        ``{"tokens": [...], "ttft_ms": ..., "token_ms": [...],
+        "admit_wait_steps": ..., "decode_steps": ...}``. ``token_ms`` has
+        one entry a token: milliseconds from the request's enqueue (the
+        clock and origin of ``ttft_ms``) to the host visit that surfaced
+        it, non-decreasing, ``token_ms[0] == ttft_ms``; under
+        ``multistep`` the tokens of one super-step share their visit's
+        stamp. The full PR-6 admission surface applies
         (priority classes, deadlines -> 504, queue caps/sheds -> 503,
         idempotency keys); a deadline that expires mid-decode settles
         with :class:`DeadlineExceeded` whose ``.partial`` carries the
@@ -296,6 +306,7 @@ class ContinuousEngine:
         self._batcher.settle_one(s.p, result={
             "tokens": list(s.tokens),
             "ttft_ms": s.ttft_ms,
+            "token_ms": list(s.token_ms),
             "admit_wait_steps": s.admit_wait_steps,
             "decode_steps": s.decode_steps,
         })
@@ -369,24 +380,28 @@ class ContinuousEngine:
     def _run_step(self, tokens, start_pos, last_idx, table):
         from .. import numpy as mnp
 
-        toks = mnp.array(_onp.asarray(tokens, _onp.int32))
-        sp = mnp.array(_onp.asarray(start_pos, _onp.int32))
-        li = mnp.array(_onp.asarray(last_idx, _onp.int32))
-        tab = mnp.array(_onp.asarray(table, _onp.int32))
-        if not self._fused_paged:
-            # strict rung: paging brackets as standalone exact-copy ops
-            # around the unchanged ring executable (bitwise contract)
-            rings = [_ops.paged_kv_gather(p, tab)
-                     for p in self.pool.flat()]
-            out = self.session.run(toks, sp, li, *rings, *self._qflat)
-            t_len = _onp.asarray(tokens).shape[1]
-            self.pool.update_from_flat([
-                _ops.paged_kv_scatter(p, tab, r, sp, t_len)
-                for p, r in zip(self.pool.flat(), out[1:])])
-            return out[0]
-        out = self.session.run(toks, sp, li, tab,
-                               *self.pool.flat(), *self._qflat)
-        self.pool.update_from_flat(out[1:])
+        with host_span("mxnet_tpu.serve.to_device"):
+            toks = mnp.array(_onp.asarray(tokens, _onp.int32))
+            sp = mnp.array(_onp.asarray(start_pos, _onp.int32))
+            li = mnp.array(_onp.asarray(last_idx, _onp.int32))
+            tab = mnp.array(_onp.asarray(table, _onp.int32))
+        with host_span("mxnet_tpu.serve.dispatch"):
+            if self._fused_paged:
+                out = self.session.run(toks, sp, li, tab,
+                                       *self.pool.flat(), *self._qflat)
+                flat = out[1:]
+            else:
+                # strict rung: paging brackets as standalone exact-copy
+                # ops around the unchanged ring executable (bitwise
+                # contract)
+                rings = [_ops.paged_kv_gather(p, tab)
+                         for p in self.pool.flat()]
+                out = self.session.run(toks, sp, li, *rings, *self._qflat)
+                t_len = _onp.asarray(tokens).shape[1]
+                flat = [_ops.paged_kv_scatter(p, tab, r, sp, t_len)
+                        for p, r in zip(self.pool.flat(), out[1:])]
+        with host_span("mxnet_tpu.serve.pool_update"):
+            self.pool.update_from_flat(flat)
         return out[0]
 
     def _prefill_once(self):
@@ -400,13 +415,18 @@ class ContinuousEngine:
         i = min(waiting, key=lambda j: (j - self._pf_next) % self.num_slots)
         self._pf_next = (i + 1) % self.num_slots
         s = self._slots[i]
-        chunk = self.prefill_chunk
-        piece = s.prompt[s.consumed:s.consumed + chunk]
-        n = len(piece)
-        toks = _onp.full((1, chunk), self.pad_id, _onp.int32)
-        toks[0, :n] = piece
-        table = _onp.zeros((1, self.pool.pages_per_slot), _onp.int32)
-        table[0] = self.pool.table()[i]
+        n = min(self.prefill_chunk, len(s.prompt) - s.consumed)
+        with host_span("mxnet_tpu.serve.prefill", slot=i, n=n):
+            self._prefill_chunk(i, s, n)
+
+    def _prefill_chunk(self, i, s, n):
+        """The next ``n`` prompt tokens of slot ``i`` through the step."""
+        with host_span("mxnet_tpu.serve.build_inputs"):
+            chunk = self.prefill_chunk
+            toks = _onp.full((1, chunk), self.pad_id, _onp.int32)
+            toks[0, :n] = s.prompt[s.consumed:s.consumed + n]
+            table = _onp.zeros((1, self.pool.pages_per_slot), _onp.int32)
+            table[0] = self.pool.table()[i]
         try:
             pf_args = {"slot": i, "n": n}
             with _attr.phase_scope("prefill"):
@@ -432,11 +452,14 @@ class ContinuousEngine:
         # position's logits (exactly Generator._generate's step-0 sample)
         s.decoding = True
         s.pos = len(s.prompt)
-        tid = int(sample_tokens(logits, temperature=s.temperature,
-                                top_k=s.top_k)[0])
-        s.ttft_ms = (time.monotonic() - s.p.t_enq) * 1e3
-        self.metrics.observe_ttft(s.ttft_ms, s.p.priority)
-        s.emit(tid)
+        with host_span("mxnet_tpu.serve.sample"):
+            tid = int(sample_tokens(logits, temperature=s.temperature,
+                                    top_k=s.top_k)[0])
+        with host_span("mxnet_tpu.serve.settle", tokens=1):
+            now = time.monotonic()
+            s.ttft_ms = (now - s.p.t_enq) * 1e3
+            self.metrics.observe_ttft(s.ttft_ms, s.p.priority)
+            s.emit(tid, now)
 
     def _decode_once(self):
         """One fixed-width decode step over every decoding slot. Slots
@@ -451,20 +474,26 @@ class ContinuousEngine:
             # the next decode step's ITL restarts from its own window
             self._last_emit_t = None
             return
+        with host_span("mxnet_tpu.serve.decode", live=len(decoding)):
+            self._decode_step(decoding)
+
+    def _decode_step(self, decoding):
+        """The classic decode visit over the ``decoding`` slots."""
         _faults.fault_point("serve:decode",
                             {"session": self.session.name})
         t_build = time.perf_counter()
-        S = self.num_slots
-        toks = _onp.zeros((S, 1), _onp.int32)
-        pos = _onp.zeros(S, _onp.int32)
-        table = _onp.zeros((S, self.pool.pages_per_slot), _onp.int32)
-        live_table = self.pool.table()
-        for i in decoding:
-            s = self._slots[i]
-            toks[i, 0] = s.pending
-            pos[i] = s.pos
-            table[i] = live_table[i]
-        temps = [self._slots[i].temperature for i in decoding]
+        with host_span("mxnet_tpu.serve.build_inputs"):
+            S = self.num_slots
+            toks = _onp.zeros((S, 1), _onp.int32)
+            pos = _onp.zeros(S, _onp.int32)
+            table = _onp.zeros((S, self.pool.pages_per_slot), _onp.int32)
+            live_table = self.pool.table()
+            for i in decoding:
+                s = self._slots[i]
+                toks[i, 0] = s.pending
+                pos[i] = s.pos
+                table[i] = live_table[i]
+            temps = [self._slots[i].temperature for i in decoding]
         # the iteration's four-way attribution (host/dispatch/device/
         # wait partitions the span wall exactly; the pre-span input
         # assembly above lands in the ledger's schedule bucket): the
@@ -482,61 +511,67 @@ class ContinuousEngine:
                                         _onp.zeros(S, _onp.int32), table)
                 t2 = time.perf_counter()
                 w2 = _attr.thread_wait_ns() if attributing else 0
-                if all(t is None or t <= 0.0 for t in temps):
-                    # one greedy argmax for all rows; blocks on device
-                    ids = sample_tokens(logits)
-                    t3 = time.perf_counter()
-                    w3 = _attr.thread_wait_ns() if attributing else 0
-                    sampled = {i: int(ids[i]) for i in decoding}
-                else:
-                    arr = logits.asnumpy()  # blocking device fetch
-                    t3 = time.perf_counter()
-                    w3 = _attr.thread_wait_ns() if attributing else 0
-                    sampled = {}
+                with host_span("mxnet_tpu.serve.sample"):
+                    if all(t is None or t <= 0.0 for t in temps):
+                        # one greedy argmax for all rows; blocks on device
+                        ids = sample_tokens(logits)
+                        t3 = time.perf_counter()
+                        w3 = _attr.thread_wait_ns() if attributing else 0
+                        sampled = {i: int(ids[i]) for i in decoding}
+                    else:
+                        arr = logits.asnumpy()  # blocking device fetch
+                        t3 = time.perf_counter()
+                        w3 = _attr.thread_wait_ns() if attributing else 0
+                        sampled = {}
+                        for i in decoding:
+                            s = self._slots[i]
+                            sampled[i] = int(sample_tokens(
+                                arr[i:i + 1], temperature=s.temperature,
+                                top_k=s.top_k)[0])
+                with host_span("mxnet_tpu.serve.settle",
+                               tokens=len(decoding)):
+                    now = time.monotonic()
                     for i in decoding:
                         s = self._slots[i]
-                        sampled[i] = int(sample_tokens(
-                            arr[i:i + 1], temperature=s.temperature,
-                            top_k=s.top_k)[0])
-                for i in decoding:
-                    s = self._slots[i]
-                    s.pos += 1
-                    s.decode_steps += 1
-                    s.emit(sampled[i])
-                if attributing:
-                    t4 = time.perf_counter()
-                    w4 = _attr.thread_wait_ns()
-                    dispatch_ms = max(
-                        0.0, (t2 - t1) * 1e3 - (w2 - w1) / 1e6)
-                    device_ms = (t3 - t2) * 1e3
-                    host_ms = max(
-                        0.0, (t4 - t3) * 1e3 - (w4 - w3) / 1e6)
-                    wait_ms = max(0.0, ((w2 - w1) + (w4 - w3)) / 1e6)
-                    args.update(host_ms=round(host_ms, 4),
-                                dispatch_ms=round(dispatch_ms, 4),
-                                device_ms=round(device_ms, 4),
-                                wait_ms=round(wait_ms, 4))
-                    self.ledger.observe_step(host_ms, dispatch_ms,
-                                             device_ms, wait_ms,
+                        s.pos += 1
+                        s.decode_steps += 1
+                        s.emit(sampled[i], now)
+                    if attributing:
+                        t4 = time.perf_counter()
+                        w4 = _attr.thread_wait_ns()
+                        dispatch_ms = max(
+                            0.0, (t2 - t1) * 1e3 - (w2 - w1) / 1e6)
+                        device_ms = (t3 - t2) * 1e3
+                        host_ms = max(
+                            0.0, (t4 - t3) * 1e3 - (w4 - w3) / 1e6)
+                        wait_ms = max(0.0, ((w2 - w1) + (w4 - w3)) / 1e6)
+                        args.update(host_ms=round(host_ms, 4),
+                                    dispatch_ms=round(dispatch_ms, 4),
+                                    device_ms=round(device_ms, 4),
+                                    wait_ms=round(wait_ms, 4))
+                        self.ledger.observe_step(host_ms, dispatch_ms,
+                                                 device_ms, wait_ms,
+                                                 live=len(decoding))
+                        self.ledger.observe_schedule((t1 - t_build) * 1e3)
+                    # ITL is the token-to-token gap, not just the device
+                    # window: in steady state it runs from the PREVIOUS
+                    # step's emission, so scheduler stalls between steps
+                    # (admissions, prefill chunks, an injected
+                    # serve:decode delay) land in the stream-stall number
+                    # the SLO monitor judges. First step after idle has
+                    # no waiting stream; it falls back to its own decode
+                    # window.
+                    prev = self._last_emit_t
+                    self._last_emit_t = t3
+                    itl_start = prev if prev is not None else t1
+                    self.metrics.observe_itl((t3 - itl_start) * 1e3,
                                              live=len(decoding))
-                    self.ledger.observe_schedule((t1 - t_build) * 1e3)
             except Exception as e:
                 args["error"] = type(e).__name__
                 raise
             finally:
                 self._span_fanout("serve::decode_step", s0_ns,
                                   time.perf_counter_ns(), args, decoding)
-        # ITL is the token-to-token gap, not just the device window: in
-        # steady state it runs from the PREVIOUS step's emission, so
-        # scheduler stalls between steps (admissions, prefill chunks, an
-        # injected serve:decode delay) land in the stream-stall number
-        # the SLO monitor judges. First step after idle has no waiting
-        # stream; it falls back to its own decode window.
-        prev = self._last_emit_t
-        self._last_emit_t = t3
-        itl_start = prev if prev is not None else t1
-        self.metrics.observe_itl((t3 - itl_start) * 1e3,
-                                 live=len(decoding))
 
     def _run_multi(self, toks, pos, table, limit, remaining, seeds,
                    temps, top_ks, stops):
@@ -547,25 +582,30 @@ class ContinuousEngine:
         dispatch from device time like :meth:`_decode_once` does."""
         from .. import numpy as mnp
 
-        args = [
-            mnp.array(_onp.asarray(toks, _onp.int32)),
-            mnp.array(_onp.asarray(pos, _onp.int32)),
-            mnp.array(_onp.asarray([limit], _onp.int32)),
-            mnp.array(_onp.asarray(remaining, _onp.int32)),
-            mnp.array(_onp.asarray(seeds, _onp.int32)),
-            mnp.array(_onp.asarray(temps, _onp.float32)),
-            mnp.array(_onp.asarray(top_ks, _onp.int32)),
-            mnp.array(_onp.asarray(stops, _onp.int32)),
-            mnp.array(_onp.asarray(self._key_bits, _onp.uint32)),
-            mnp.array(_onp.asarray(table, _onp.int32)),
-        ]
-        out = self._msession.run(*args, *self.pool.flat(), *self._qflat)
+        with host_span("mxnet_tpu.serve.to_device"):
+            args = [
+                mnp.array(_onp.asarray(toks, _onp.int32)),
+                mnp.array(_onp.asarray(pos, _onp.int32)),
+                mnp.array(_onp.asarray([limit], _onp.int32)),
+                mnp.array(_onp.asarray(remaining, _onp.int32)),
+                mnp.array(_onp.asarray(seeds, _onp.int32)),
+                mnp.array(_onp.asarray(temps, _onp.float32)),
+                mnp.array(_onp.asarray(top_ks, _onp.int32)),
+                mnp.array(_onp.asarray(stops, _onp.int32)),
+                mnp.array(_onp.asarray(self._key_bits, _onp.uint32)),
+                mnp.array(_onp.asarray(table, _onp.int32)),
+            ]
+        with host_span("mxnet_tpu.serve.dispatch"):
+            out = self._msession.run(*args, *self.pool.flat(),
+                                     *self._qflat)
         t2 = time.perf_counter()
         w2 = _attr.thread_wait_ns()
-        self.pool.update_from_flat(out[3:])
-        block = _onp.asarray(out[0].asnumpy(), _onp.int32)
-        valid = _onp.asarray(out[1].asnumpy(), _onp.int32)
-        done = _onp.asarray(out[2].asnumpy(), _onp.int32)
+        with host_span("mxnet_tpu.serve.pool_update"):
+            self.pool.update_from_flat(out[3:])
+        with host_span("mxnet_tpu.serve.sample"):
+            block = _onp.asarray(out[0].asnumpy(), _onp.int32)
+            valid = _onp.asarray(out[1].asnumpy(), _onp.int32)
+            done = _onp.asarray(out[2].asnumpy(), _onp.int32)
         return block, valid, done, t2, w2
 
     def _steps_limit(self, decoding):
@@ -599,32 +639,38 @@ class ContinuousEngine:
         if not decoding:
             self._last_emit_t = None
             return
+        with host_span("mxnet_tpu.serve.decode", live=len(decoding)):
+            self._decode_superstep(decoding)
+
+    def _decode_superstep(self, decoding):
+        """The multi-step decode visit over the ``decoding`` slots."""
         _faults.fault_point("serve:decode",
                             {"session": self._msession.name})
         t_build = time.perf_counter()
-        S = self.num_slots
-        toks = _onp.zeros((S, 1), _onp.int32)
-        pos = _onp.zeros(S, _onp.int32)
-        remaining = _onp.zeros(S, _onp.int32)
-        seeds = _onp.zeros(S, _onp.int32)
-        temps = _onp.zeros(S, _onp.float32)
-        tks = _onp.zeros(S, _onp.int32)
-        table = _onp.zeros((S, self.pool.pages_per_slot), _onp.int32)
-        live_table = self.pool.table()
-        stop_sets = [frozenset()] * S
-        for i in decoding:
-            s = self._slots[i]
-            toks[i, 0] = s.pending
-            pos[i] = s.pos
-            remaining[i] = s.max_new - len(s.tokens)
-            seeds[i] = s.seed
-            temps[i] = (s.temperature if s.temperature is not None
-                        and s.temperature > 0.0 else 0.0)
-            tks[i] = int(s.top_k) if s.top_k else 0
-            table[i] = live_table[i]
-            stop_sets[i] = s.stop
-        stops = _stop_matrix(S, stop_sets)
-        limit = self._steps_limit(decoding)
+        with host_span("mxnet_tpu.serve.build_inputs"):
+            S = self.num_slots
+            toks = _onp.zeros((S, 1), _onp.int32)
+            pos = _onp.zeros(S, _onp.int32)
+            remaining = _onp.zeros(S, _onp.int32)
+            seeds = _onp.zeros(S, _onp.int32)
+            temps = _onp.zeros(S, _onp.float32)
+            tks = _onp.zeros(S, _onp.int32)
+            table = _onp.zeros((S, self.pool.pages_per_slot), _onp.int32)
+            live_table = self.pool.table()
+            stop_sets = [frozenset()] * S
+            for i in decoding:
+                s = self._slots[i]
+                toks[i, 0] = s.pending
+                pos[i] = s.pos
+                remaining[i] = s.max_new - len(s.tokens)
+                seeds[i] = s.seed
+                temps[i] = (s.temperature if s.temperature is not None
+                            and s.temperature > 0.0 else 0.0)
+                tks[i] = int(s.top_k) if s.top_k else 0
+                table[i] = live_table[i]
+                stop_sets[i] = s.stop
+            stops = _stop_matrix(S, stop_sets)
+            limit = self._steps_limit(decoding)
         attributing = _attr.ENABLED
         args = {"live": len(decoding), "steps": limit}
         with _attr.phase_scope("decode"):
@@ -640,57 +686,60 @@ class ContinuousEngine:
                 # host settle: replay emit over each lane's token run —
                 # the host stays the source of truth for stop/budget
                 # (device done only bounds the iteration count)
-                n_tok = 0
-                steps_run = 0
-                for i in decoding:
-                    s = self._slots[i]
-                    k = int(valid[i])
-                    n_tok += k
-                    if k > steps_run:
-                        steps_run = k
-                    s.pos += k
-                    s.decode_steps += k
-                    for j in range(k):
-                        s.emit(int(block[i, j]))
-                        if s.finished:
-                            break
-                if attributing:
-                    t4 = time.perf_counter()
-                    w4 = _attr.thread_wait_ns()
-                    dispatch_ms = max(
-                        0.0, (t2 - t1) * 1e3 - (w2 - w1) / 1e6)
-                    device_ms = (t3 - t2) * 1e3
-                    host_ms = max(
-                        0.0, (t4 - t3) * 1e3 - (w4 - w3) / 1e6)
-                    wait_ms = max(0.0, ((w2 - w1) + (w4 - w3)) / 1e6)
-                    args.update(host_ms=round(host_ms, 4),
-                                dispatch_ms=round(dispatch_ms, 4),
-                                device_ms=round(device_ms, 4),
-                                wait_ms=round(wait_ms, 4),
-                                tokens=n_tok)
-                    self.ledger.observe_step(host_ms, dispatch_ms,
-                                             device_ms, wait_ms,
-                                             live=len(decoding),
-                                             tokens=n_tok)
-                    self.ledger.observe_schedule((t1 - t_build) * 1e3)
+                n_tok = int(sum(valid[i] for i in decoding))
+                with host_span("mxnet_tpu.serve.settle", tokens=n_tok):
+                    now = time.monotonic()
+                    steps_run = 0
+                    for i in decoding:
+                        s = self._slots[i]
+                        k = int(valid[i])
+                        if k > steps_run:
+                            steps_run = k
+                        s.pos += k
+                        s.decode_steps += k
+                        for j in range(k):
+                            s.emit(int(block[i, j]), now)
+                            if s.finished:
+                                break
+                    if attributing:
+                        t4 = time.perf_counter()
+                        w4 = _attr.thread_wait_ns()
+                        dispatch_ms = max(
+                            0.0, (t2 - t1) * 1e3 - (w2 - w1) / 1e6)
+                        device_ms = (t3 - t2) * 1e3
+                        host_ms = max(
+                            0.0, (t4 - t3) * 1e3 - (w4 - w3) / 1e6)
+                        wait_ms = max(0.0, ((w2 - w1) + (w4 - w3)) / 1e6)
+                        args.update(host_ms=round(host_ms, 4),
+                                    dispatch_ms=round(dispatch_ms, 4),
+                                    device_ms=round(device_ms, 4),
+                                    wait_ms=round(wait_ms, 4),
+                                    tokens=n_tok)
+                        self.ledger.observe_step(host_ms, dispatch_ms,
+                                                 device_ms, wait_ms,
+                                                 live=len(decoding),
+                                                 tokens=n_tok)
+                        self.ledger.observe_schedule((t1 - t_build) * 1e3)
+                    prev = self._last_emit_t
+                    self._last_emit_t = t3
+                    itl_start = prev if prev is not None else t1
+                    if steps_run > 0:
+                        # the visit's wall amortizes over the iterations
+                        # it ran — k tokens means k consumer-visible
+                        # gaps, not one giant one
+                        self.metrics.observe_itl((t3 - itl_start) * 1e3,
+                                                 live=len(decoding),
+                                                 tokens=steps_run)
+                        est = (t3 - t1) / steps_run
+                        self._itl_est = (est if self._itl_est is None
+                                         else 0.5 * self._itl_est
+                                         + 0.5 * est)
             except Exception as e:
                 args["error"] = type(e).__name__
                 raise
             finally:
                 self._span_fanout("serve::decode_step", s0_ns,
                                   time.perf_counter_ns(), args, decoding)
-        prev = self._last_emit_t
-        self._last_emit_t = t3
-        itl_start = prev if prev is not None else t1
-        if steps_run > 0:
-            # the visit's wall amortizes over the iterations it ran —
-            # k tokens means k consumer-visible gaps, not one giant one
-            self.metrics.observe_itl((t3 - itl_start) * 1e3,
-                                     live=len(decoding),
-                                     tokens=steps_run)
-            est = (t3 - t1) / steps_run
-            self._itl_est = (est if self._itl_est is None
-                             else 0.5 * self._itl_est + 0.5 * est)
 
     def _span_fanout(self, name, t0_ns, t1_ns, args, slot_idx):
         """Record one span into every listed slot's request trace — an
@@ -718,35 +767,41 @@ class ContinuousEngine:
         fail the requests that were inside the failing call — the
         scheduler itself keeps serving, exactly like the batcher's
         batch-failure isolation."""
-        t0 = time.perf_counter()
-        self._retire()
-        self._admit()
-        if _attr.ENABLED:
-            # host-schedule: the admit/retire bookkeeping between
-            # device calls — ROADMAP item 3's kill target
-            self.ledger.observe_schedule((time.perf_counter() - t0) * 1e3)
-        self._prefill_once()
-        try:
-            if self._multistep:
-                self._decode_multi()
-            else:
-                self._decode_once()
-        except Exception as exc:  # pylint: disable=broad-except
-            for i, s in enumerate(self._slots):
-                if s is not None and s.decoding:
-                    self._settle_slot(i, error=exc)
-        self._steps += 1
-        self.metrics.set_kv_pages(self.pool.pages_used,
-                                  self.pool.pages_free)
-        self.metrics.set_slot_occupancy(len(self._live()), self.num_slots)
-        if _attr.ENABLED:
-            self.metrics.set_attribution(
-                self.ledger.host_overhead_fraction(),
-                self.ledger.device_ms_per_token())
-        if self.prefix is not None:
-            self.metrics.set_prefix_gauges(self.pool.pages_shared,
-                                           self.prefix.pages_held,
-                                           self.prefix.evictions)
+        with host_span("mxnet_tpu.serve.step", engine=self.session.name,
+                       step=self._steps):
+            t0 = time.perf_counter()
+            with host_span("mxnet_tpu.serve.retire"):
+                self._retire()
+            with host_span("mxnet_tpu.serve.admit"):
+                self._admit()
+            if _attr.ENABLED:
+                # host-schedule: the admit/retire bookkeeping between
+                # device calls — ROADMAP item 3's kill target
+                self.ledger.observe_schedule((time.perf_counter() - t0) * 1e3)
+            self._prefill_once()
+            try:
+                if self._multistep:
+                    self._decode_multi()
+                else:
+                    self._decode_once()
+            except Exception as exc:  # pylint: disable=broad-except
+                for i, s in enumerate(self._slots):
+                    if s is not None and s.decoding:
+                        self._settle_slot(i, error=exc)
+            self._steps += 1
+            with host_span("mxnet_tpu.serve.gauges"):
+                self.metrics.set_kv_pages(self.pool.pages_used,
+                                          self.pool.pages_free)
+                self.metrics.set_slot_occupancy(len(self._live()),
+                                                self.num_slots)
+                if _attr.ENABLED:
+                    self.metrics.set_attribution(
+                        self.ledger.host_overhead_fraction(),
+                        self.ledger.device_ms_per_token())
+                if self.prefix is not None:
+                    self.metrics.set_prefix_gauges(self.pool.pages_shared,
+                                                   self.prefix.pages_held,
+                                                   self.prefix.evictions)
 
     def _idle(self):
         return not self._live() and self._batcher.queue_depth() == 0
@@ -757,7 +812,8 @@ class ContinuousEngine:
         _prof.register_thread_name()
         while not self._stop.is_set():
             if self._idle():
-                with self._batcher._cond:
+                with host_span("mxnet_tpu.serve.idle_wait"), \
+                        self._batcher._cond:
                     if not self._batcher._queue and not self._stop.is_set():
                         self._batcher._cond.wait(0.05)
                 continue

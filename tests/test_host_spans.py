@@ -1,0 +1,334 @@
+"""Host spans on the device trace's clock (``profiler.core.host_span``):
+``ContinuousEngine.step`` and ``ShardedTrainer.step`` write them into the
+``.xplane.pb`` of a ``jax.profiler`` session, nested as OBSERVABILITY.md
+tabulates them; with no session they cost next to nothing and leave
+nothing behind. Also the engine's per-token times and
+``ServeMetrics.queue_samples``. Every test runs under its own alarm.
+"""
+import glob
+import signal
+import threading
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from mxnet_tpu import gluon
+from mxnet_tpu.models.llama import get_llama
+from mxnet_tpu.parallel import ShardedTrainer, ShardingRules, make_mesh
+from mxnet_tpu.profiler import core
+from mxnet_tpu.serve import ContinuousEngine
+from mxnet_tpu.serve.metrics import ServeMetrics
+
+STEP_CHILDREN = ("retire", "admit", "prefill", "decode", "gauges")
+VISIT_CHILDREN = ("build_inputs", "to_device", "dispatch", "pool_update",
+                  "sample", "settle")
+TRAINER_CHILDREN = ("unwrap", "optimizer_scalars", "rng_split",
+                    "gather_args", "compile", "call", "commit")
+
+
+@pytest.fixture(autouse=True)
+def short_timeout(request):
+    """Fail, not hang: every test here ends well inside a minute, and
+    an alarm cuts one that does not (on the main thread, which is where
+    pytest and its xdist workers run tests)."""
+    if threading.current_thread() is not threading.main_thread():
+        yield
+        return
+
+    def late(signum, frame):
+        raise TimeoutError(f"{request.node.name} ran over its 120 s")
+
+    old = signal.signal(signal.SIGALRM, late)
+    signal.alarm(120)
+    try:
+        yield
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, old)
+
+
+@pytest.fixture(scope="module")
+def net():
+    net = get_llama("llama_tiny_test")
+    net.initialize()
+    return net
+
+
+def traced(tmp_path, work):
+    """Run ``work()`` under a profiler session (Python tracer off: the
+    spans are TraceMe events) and return ``(what work returned, spans)``,
+    ``spans`` being every ``mxnet_tpu.`` event as a dict with its thread's
+    line, start, end and stats, in start order."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = work()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    spans = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for k, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith("mxnet_tpu."):
+                    spans.append({
+                        "name": ev.name[len("mxnet_tpu."):],
+                        "line": (plane.name, k), "start": ev.start_ns,
+                        "end": ev.start_ns + ev.duration_ns,
+                        "stats": dict(ev.stats)})
+    return out, sorted(spans, key=lambda s: (s["start"], -s["end"]))
+
+
+def children(spans, parent):
+    """The spans directly inside ``parent``: on its line, within its
+    time, and inside no other span that is inside ``parent``."""
+    inside = [s for s in spans if s is not parent
+              and s["line"] == parent["line"]
+              and s["start"] >= parent["start"] and s["end"] <= parent["end"]]
+    return [s for s in inside
+            if not any(o is not s and o["start"] <= s["start"]
+                       and s["end"] <= o["end"] for o in inside)]
+
+
+def short_names(spans):
+    return [s["name"].split(".", 1)[1] for s in spans]
+
+
+def drive(eng, requests):
+    """Submit, then step the engine on this thread until every future
+    has its answer; returns the results and, a step, the lanes that
+    decoded (``live``) before it ran."""
+    futs = [eng.submit(p, max_new_tokens=n) for p, n in requests]
+    live = []
+    while not all(f.done() for f in futs):
+        live.append(sum(1 for s in eng._slots if s is not None
+                        and s.decoding and not s.finished))
+        eng.step()
+    return [f.result(timeout=1) for f in futs], live
+
+
+# -- (a) the engine ----------------------------------------------------------
+
+@pytest.mark.parametrize("multistep", [False, True],
+                         ids=["decode_once", "decode_multi"])
+def test_engine_spans_nest_cover_and_count(tmp_path, net, multistep):
+    eng = ContinuousEngine(
+        net, max_seq=64, num_slots=4, page_size=16, prefill_chunk=16,
+        decode_path="baseline", name=f"spans_{int(multistep)}",
+        multistep=multistep, decode_steps=4)
+    eng.warmup()
+    requests = [([5, 6, 7], 6), ([3] * 20, 4), ([9, 8], 2)]
+    (results, _), spans = traced(tmp_path, lambda: drive(eng, requests))
+    for (_, n), r in zip(requests, results):
+        assert len(r["tokens"]) == n
+
+    steps = [s for s in spans if s["name"] == "serve.step"]
+    assert len(steps) >= 3
+    assert [s["stats"]["step"] for s in steps] == list(range(len(steps)))
+    assert {s["stats"]["engine"] for s in steps} == {eng.session.name}
+    seen = set()
+    for step in steps:
+        kids = children(spans, step)
+        assert set(short_names(kids)) <= set(STEP_CHILDREN)
+        assert short_names(kids)[:2] == ["retire", "admit"]
+        assert short_names(kids)[-1] == "gauges"
+        if {"prefill", "decode"} & set(short_names(kids)):
+            # (a step that visits no executable is tens of microseconds
+            # of list comprehensions: nothing to cover)
+            covered = sum(k["end"] - k["start"] for k in kids)
+            assert covered >= 0.9 * (step["end"] - step["start"])
+        for visit in kids:
+            kind = visit["name"].split(".", 1)[1]
+            if kind not in ("prefill", "decode"):
+                assert not children(spans, visit)
+                continue
+            inner = short_names(children(spans, visit))
+            # a prompt's middle chunks stop after the pool's update
+            assert inner in (list(VISIT_CHILDREN), list(VISIT_CHILDREN[:4]))
+            seen.update(inner)
+        seen.update(short_names(kids))
+    assert seen == set(STEP_CHILDREN) | set(VISIT_CHILDREN)
+    # every span lies inside a step: nothing leaks out of the loop
+    for s in spans:
+        assert any(st["start"] <= s["start"] and s["end"] <= st["end"]
+                   for st in steps), s
+
+    # the stats say what the slots did
+    sampled = sum(s["stats"]["tokens"] for s in spans
+                  if s["name"] == "serve.settle")
+    assert sampled == sum(n for _, n in requests)
+    prefills = [s for s in spans if s["name"] == "serve.prefill"]
+    assert sum(s["stats"]["n"] for s in prefills) == sum(
+        len(p) for p, _ in requests)
+    decodes = [s for s in spans if s["name"] == "serve.decode"]
+    assert decodes and all(1 <= s["stats"]["live"] <= 3 for s in decodes)
+    if not multistep:
+        # one token a live lane a visit
+        for d in decodes:
+            settle, = [c for c in children(spans, d)
+                       if c["name"] == "serve.settle"]
+            assert settle["stats"]["tokens"] == d["stats"]["live"]
+    else:
+        assert len(decodes) < sum(n - 1 for _, n in requests)
+
+
+def test_engine_decode_live_is_the_lanes_that_decoded(tmp_path, net):
+    eng = ContinuousEngine(net, max_seq=64, num_slots=4, page_size=16,
+                           prefill_chunk=16, decode_path="baseline",
+                           name="spans_live")
+    eng.warmup()
+    (_, live), spans = traced(
+        tmp_path, lambda: drive(eng, [([5, 6, 7], 5), ([4] * 18, 3)]))
+    by_step = {s["stats"]["step"]: s for s in spans
+               if s["name"] == "serve.step"}
+    for k, step in by_step.items():
+        decode = [c for c in children(spans, step)
+                  if c["name"] == "serve.decode"]
+        # a lane whose prompt ends in this step's prefill decodes in it
+        if decode:
+            assert live[k] <= decode[0]["stats"]["live"] <= live[k] + 1
+        else:
+            assert live[k] == 0
+
+
+def test_idle_wait_span_on_the_engine_thread(tmp_path, net):
+    eng = ContinuousEngine(net, max_seq=64, num_slots=2, page_size=16,
+                           prefill_chunk=16, decode_path="baseline",
+                           name="spans_idle")
+    eng.warmup()
+
+    def work():
+        with eng:
+            time.sleep(0.12)
+            return eng.submit([5, 6], max_new_tokens=2).result(timeout=60)
+
+    res, spans = traced(tmp_path, work)
+    assert len(res["tokens"]) == 2
+    waits = [s for s in spans if s["name"] == "serve.idle_wait"]
+    steps = [s for s in spans if s["name"] == "serve.step"]
+    assert waits and steps
+    assert {w["line"] for w in waits} == {s["line"] for s in steps}
+    assert not any(w["start"] < s["end"] and s["start"] < w["end"]
+                   for w in waits for s in steps)
+
+
+# -- (b) the trainer ---------------------------------------------------------
+
+def _trainer():
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(32, activation="relu", in_units=20),
+            gluon.nn.Dense(10, in_units=32))
+    net.initialize()
+    return ShardedTrainer(net, gluon.loss.SoftmaxCrossEntropyLoss(), "adam",
+                          {"learning_rate": 1e-2},
+                          mesh=make_mesh({"dp": 2}),
+                          rules=ShardingRules(default_axis=None))
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["step", "step_n"])
+def test_trainer_spans_nest_and_count(tmp_path, fused):
+    tr = _trainer()
+    X = np.random.randn(3, 8, 20).astype("float32")
+    Y = np.random.randint(0, 10, (3, 8))
+
+    def work():
+        if fused:
+            return [tr.step_n(X, Y), tr.step_n(X, Y)]
+        return [tr.step(X[i], Y[i]) for i in range(3)]
+
+    losses, spans = traced(tmp_path, work)
+    assert all(np.isfinite(l.asnumpy()).all() for l in losses)
+    steps = [s for s in spans if s["name"] == "trainer.step"]
+    assert [s["stats"]["step"] for s in steps] == ([1, 4] if fused
+                                                   else [1, 2, 3])
+    assert {s["stats"]["n"] for s in steps} == {3 if fused else 1}
+    for k, step in enumerate(steps):
+        kids = children(spans, step)
+        want = [c for c in TRAINER_CHILDREN if c != "compile" or k == 0]
+        assert short_names(kids) == want
+        by = dict(zip(short_names(kids), kids))
+        assert by["optimizer_scalars"]["stats"]["scalars"] \
+            == 2 * len(tr._train_keys)
+        # parameters and Adam's two moments a parameter, the batch, the
+        # key, the scalars and the step count
+        n_par = len(tr._train_keys)
+        assert by["gather_args"]["stats"]["leaves"] \
+            == len(tr.params) + 2 * n_par + 2 + 1 + 2 * n_par + 1
+    assert all(any(st["start"] <= s["start"] and s["end"] <= st["end"]
+                   for st in steps) for s in spans)
+
+
+# -- (c) what a span costs with no session -----------------------------------
+
+def test_host_span_without_a_session_is_cheap_and_keeps_nothing():
+    events, agg = len(core.snapshot_events()), dict(core.aggregate_stats())
+    counters = core.counters_snapshot()
+    for _ in range(200):      # warm the call path
+        with core.host_span("mxnet_tpu.test.warm", k=1):
+            pass
+    t0 = time.perf_counter()
+    for i in range(10_000):
+        with core.host_span("mxnet_tpu.test.cheap", step=i):
+            pass
+    took = time.perf_counter() - t0
+    assert took < 0.050, f"10,000 host_span enters and exits took {took}s"
+    assert len(core.snapshot_events()) == events
+    assert core.aggregate_stats() == agg
+    assert core.counters_snapshot() == counters
+
+
+def test_profiler_scope_still_feeds_the_aggregate_table():
+    from mxnet_tpu import profiler
+
+    before = core.aggregate_stats().get("spans::scope", {"calls": 0})
+    with profiler.scope("spans::scope"):
+        pass
+    assert core.aggregate_stats()["spans::scope"]["calls"] \
+        == before["calls"] + 1
+
+
+# -- (d) per-token times on the engine's answer ------------------------------
+
+@pytest.mark.parametrize("multistep", [False, True],
+                         ids=["decode_once", "decode_multi"])
+def test_token_ms_on_the_settled_result(net, multistep):
+    with ContinuousEngine(
+            net, max_seq=64, num_slots=2, page_size=16, prefill_chunk=16,
+            decode_path="baseline", name=f"tokms_{int(multistep)}",
+            multistep=multistep, decode_steps=4) as eng:
+        futs = [eng.submit([5, 6, 7], max_new_tokens=9),
+                eng.submit([3] * 20, max_new_tokens=5)]
+        t_end = time.monotonic()
+        results = [f.result(timeout=60) for f in futs]
+        t_end = (time.monotonic() - t_end) * 1e3
+    for r in results:
+        ms = r["token_ms"]
+        assert len(ms) == len(r["tokens"])
+        assert ms[0] == r["ttft_ms"]
+        assert all(b >= a for a, b in zip(ms, ms[1:]))
+        assert 0 < ms[0] and ms[-1] <= t_end + 1e3
+        if multistep:
+            # the tokens of one super-step share their visit's stamp:
+            # 8 decoded tokens at up to 4 a visit
+            assert len(set(ms[1:])) < len(ms[1:])
+            assert len(set(ms)) >= 1 + -(-(len(ms) - 1) // 4)
+        else:
+            assert len(set(ms)) == len(ms)
+
+
+# -- (e) the public accessor -------------------------------------------------
+
+def test_queue_samples_returns_what_observe_request_was_given():
+    m = ServeMetrics("spans_q")
+    assert m.queue_samples() == []
+    given = [0.0, 3.5, 1.25, 40.0]
+    for q in given:
+        m.observe_request(queue_ms=q, exec_ms=2.0)
+    assert m.queue_samples() == given
+    assert m.queue_samples() == list(m._queue_ms)   # the old way in
+    m.queue_samples().append(1.0)                   # a copy, not the ring
+    assert m.queue_samples() == given
