@@ -161,6 +161,16 @@ def _cost_analysis_of(compiled):
     return ca or {}
 
 
+def _scalar_like(x, leaf):
+    """An element of the step's float32 ``lrs`` / ``wds`` arrays as a
+    scalar of ``leaf``'s dtype, for ``opt._update_raw``. A Python float in
+    its place arrived as a weak float32 that took the leaf's dtype
+    (float64 -> float32 -> leaf dtype); an element of a float32 array is
+    strong and would promote a bf16- or f16-stored parameter and its
+    moments to float32, so it takes that rounding here."""
+    return x.astype(leaf.dtype)
+
+
 def _collect_aux_losses(block):
     """Sum `aux_loss` values the forward just set on any sub-block (MoE
     router load-balance terms). Values are tracers from THIS trace — read
@@ -625,9 +635,7 @@ class ShardedTrainer:
 
         if self._step_jit is None:
             self._build_step()
-        n_train = len(self._train_keys)
-        lrs = tuple(self.optimizer._get_lr(i) for i in range(n_train))
-        wds = tuple(self.optimizer._get_wd(i) for i in range(n_train))
+        lrs, wds = self._optimizer_scalars()
         key_struct = jax.ShapeDtypeStruct(self._key.shape, self._key.dtype)
         train = {n: self.params[n] for n in self._train_keys}
         state = {n: self.params[n] for n in self._state_names}
@@ -812,7 +820,9 @@ class ShardedTrainer:
                 g = g / float(mesh_n)
                 g = opt._prep_grad(g)
                 p_new, s_new = opt._update_raw(
-                    train_params[n], g, opt_states[n], lrs[i], wds[i], t)
+                    train_params[n], g, opt_states[n],
+                    _scalar_like(lrs[i], train_params[n]),
+                    _scalar_like(wds[i], train_params[n]), t)
                 new_train[n] = p_new
                 new_opt[n] = tuple(s_new) \
                     if isinstance(s_new, (list, tuple)) else (s_new,)
@@ -1008,9 +1018,10 @@ class ShardedTrainer:
                 # the weight at its use sites) / scatter-for-update.
                 g = jax.lax.with_sharding_constraint(g, train_shard[n])
                 g = opt._prep_grad(g)
-                p_new, s_new = opt._update_raw(train_params[n], g,
-                                               opt_states[n], lrs[i], wds[i],
-                                               t)
+                p_new, s_new = opt._update_raw(
+                    train_params[n], g, opt_states[n],
+                    _scalar_like(lrs[i], train_params[n]),
+                    _scalar_like(wds[i], train_params[n]), t)
                 new_train[n] = p_new
                 new_opt[n] = tuple(s_new) if isinstance(s_new, (list, tuple)) \
                     else (s_new,)
@@ -1119,15 +1130,29 @@ class ShardedTrainer:
                                    is_leaf=lambda x: isinstance(x, NDArray))
         return d, l
 
+    def _optimizer_scalars(self):
+        """Every train key's learning rate and weight decay, asked of the
+        optimizer now (schedulers, ``set_learning_rate``, ``lr_mult`` /
+        ``wd_mult``), as two float32 host arrays of shape
+        ``(len(self._train_keys),)``: the compiled step takes two small
+        transfers a call where a tuple of Python floats cost one a
+        scalar, on every chip of the mesh."""
+        import numpy as onp
+
+        n_train = len(self._train_keys)
+        opt = self.optimizer
+        return (onp.fromiter((opt._get_lr(i) for i in range(n_train)),
+                             onp.float32, n_train),
+                onp.fromiter((opt._get_wd(i) for i in range(n_train)),
+                             onp.float32, n_train))
+
     def _advance_optimizer(self, n):
         """Advance step/update counts by n; return (lrs, wds, t_first)."""
         t_first = self._step_count + 1
         self._step_count += n
-        n_train = len(self._train_keys)
-        for i in range(n_train):
+        for i in range(len(self._train_keys)):
             self.optimizer._index_update_count[i] = self._step_count
-        lrs = tuple(self.optimizer._get_lr(i) for i in range(n_train))
-        wds = tuple(self.optimizer._get_wd(i) for i in range(n_train))
+        lrs, wds = self._optimizer_scalars()
         return lrs, wds, t_first
 
     def _advance_and_run(self, jit_fn, sig_head, d, l, n):
@@ -1149,8 +1174,11 @@ class ShardedTrainer:
                 (x.shape, str(x.dtype))
                 for x in jax.tree_util.tree_leaves((d, l)))
             if span.is_enabled():   # a profiler session: count for it
+                leaves = jax.tree_util.tree_leaves(args)
                 span.set_metadata(
-                    leaves=len(jax.tree_util.tree_leaves(args)))
+                    leaves=len(leaves),
+                    host_leaves=sum(not isinstance(x, jax.Array)
+                                    for x in leaves))
         return self._run_compiled(sig, jit_fn, args)
 
     def _run_compiled(self, sig, jit_fn, args):

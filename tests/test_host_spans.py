@@ -232,8 +232,10 @@ def _trainer():
 @pytest.mark.parametrize("fused", [False, True], ids=["step", "step_n"])
 def test_trainer_spans_nest_and_count(tmp_path, fused):
     tr = _trainer()
-    X = np.random.randn(3, 8, 20).astype("float32")
-    Y = np.random.randint(0, 10, (3, 8))
+    # the batch is on the device before the step, as a training loop's
+    # feed leaves it: a NumPy batch would count among the host leaves
+    X = jax.numpy.asarray(np.random.randn(3, 8, 20).astype("float32"))
+    Y = jax.numpy.asarray(np.random.randint(0, 10, (3, 8)))
 
     def work():
         if fused:
@@ -254,10 +256,12 @@ def test_trainer_spans_nest_and_count(tmp_path, fused):
         assert by["optimizer_scalars"]["stats"]["scalars"] \
             == 2 * len(tr._train_keys)
         # parameters and Adam's two moments a parameter, the batch, the
-        # key, the scalars and the step count
+        # key, the two arrays of scalars and the step count; those last
+        # three are all the call has to move to the chips
         n_par = len(tr._train_keys)
         assert by["gather_args"]["stats"]["leaves"] \
-            == len(tr.params) + 2 * n_par + 2 + 1 + 2 * n_par + 1
+            == len(tr.params) + 2 * n_par + 2 + 1 + 2 + 1
+        assert by["gather_args"]["stats"]["host_leaves"] == 3
     assert all(any(st["start"] <= s["start"] and s["end"] <= st["end"]
                    for st in steps) for s in spans)
 

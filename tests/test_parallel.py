@@ -357,3 +357,227 @@ def test_checkpoint_restores_rng_stream(tmp_path):
     resumed = [float(tr2.step(X, Y).asnumpy().reshape(-1)[0])
                for _ in range(3)]
     np.testing.assert_allclose(resumed, cont, rtol=1e-6)
+
+
+# -- the optimizer's scalars reach the compiled step as two float32 arrays --
+
+class _RecordingAdam(mx.optimizer.Adam):
+    """Adam whose state also keeps the gradient its last update saw."""
+
+    def create_state(self, index, weight):
+        return super().create_state(index, weight) + (
+            mx.nd.zeros(weight.shape, dtype=weight.dtype),)
+
+    def _update_raw(self, p, g, states, lr, wd, t):
+        p_new, (m, v) = super()._update_raw(p, g, states[:2], lr, wd, t)
+        return p_new, (m, v, g)
+
+
+class _HalvedFrom:
+    """A scheduler: the optimizer's rate (it sets ``base_lr``) before
+    update ``at``, half of it from there."""
+
+    def __init__(self, at):
+        self.base_lr, self.at = None, at
+
+    def __call__(self, num_update):
+        return self.base_lr if num_update < self.at else self.base_lr / 2
+
+
+def _sq_loss(out, label):
+    d = out - label
+    return d * d
+
+
+def _scalars_trainer(builder, optimizer, optimizer_params=None, seed=3,
+                     dtype=None, mults=(), abstract=False):
+    """Two Dense layers from ``seed`` under the pjit step (dp=4) or the
+    shard_map step (dp=2 x tp=2, the first weight split over tp)."""
+    from mxnet_tpu.parallel import ParallelConfig
+
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(16, activation="relu", in_units=20),
+            gluon.nn.Dense(8, in_units=16))
+    net.initialize()
+    rng = np.random.RandomState(seed)
+    for p in net.collect_params().values():
+        p.set_data(mx.nd.array(
+            (0.3 * rng.randn(*p.shape)).astype("float32")))
+    if dtype is not None:
+        net.cast(dtype)
+    for name, (lr_mult, wd_mult) in dict(mults).items():
+        net.collect_params()[name].lr_mult = lr_mult
+        net.collect_params()[name].wd_mult = wd_mult
+    if builder == "shard_map":
+        how = dict(parallel=ParallelConfig(dp=2, tp=2),
+                   rules=ShardingRules([(r"0\.weight", P("tp", None))],
+                                       default_axis=None))
+    else:
+        how = dict(mesh=make_mesh({"dp": 4}),
+                   rules=ShardingRules(default_axis=None))
+    tr = ShardedTrainer(net, _sq_loss, optimizer, optimizer_params,
+                        abstract=abstract, **how)
+    assert tr._use_shard_map == (builder == "shard_map")
+    return tr
+
+
+def _scalars_batches(n, dtype="float32"):
+    rng = np.random.RandomState(11)
+    return (rng.randn(n, 8, 20).astype(dtype),
+            rng.randn(n, 8, 8).astype(dtype))
+
+
+def _one(tr, X, Y, i, fused):
+    """Step ``i`` of the batches: ``step``, or a ``step_n`` window of one."""
+    if fused:
+        return tr.step_n(X[i:i + 1], Y[i:i + 1])
+    return tr.step(X[i], Y[i])
+
+
+def _host(tree):
+    return jax.tree_util.tree_map(np.asarray, dict(tree))
+
+
+def _case_mults(builder, fused):
+    """lr_mult 0 holds a parameter still, lr_mult 2 moves it twice as far
+    (SGD: the first step's gradient is the same on both trainers)."""
+    X, Y = _scalars_batches(1)
+    plain = _scalars_trainer(builder, "sgd", {"learning_rate": 0.1})
+    mult = _scalars_trainer(builder, "sgd", {"learning_rate": 0.1},
+                            mults={"0.weight": (0.0, 1.0),
+                                   "1.weight": (2.0, 1.0)})
+    start = _host(plain.params)
+    _one(plain, X, Y, 0, fused)
+    _one(mult, X, Y, 0, fused)
+    a, b = _host(plain.params), _host(mult.params)
+    np.testing.assert_array_equal(b["0.weight"], start["0.weight"])
+    assert np.abs(a["0.weight"] - start["0.weight"]).max() > 1e-4
+    np.testing.assert_allclose(b["1.weight"] - start["1.weight"],
+                               2 * (a["1.weight"] - start["1.weight"]),
+                               rtol=1e-4, atol=1e-7)
+    np.testing.assert_array_equal(b["1.bias"], a["1.bias"])
+
+
+def _case_new_rate(builder, fused, through):
+    """A rate that changes between steps (a scheduler's, or
+    ``set_learning_rate``) is the rate of that very step, on the one
+    executable: the second step moves half as far as at the old rate."""
+    X, Y = _scalars_batches(2)
+    steady = _scalars_trainer(builder, "sgd", {"learning_rate": 0.1})
+    if through == "scheduler":
+        moved = _scalars_trainer(
+            builder, "sgd", {"learning_rate": 0.1,
+                             "lr_scheduler": _HalvedFrom(at=2)})
+    else:
+        moved = _scalars_trainer(builder, "sgd", {"learning_rate": 0.1})
+    _one(steady, X, Y, 0, fused)
+    _one(moved, X, Y, 0, fused)
+    mid = _host(steady.params)
+    for n, a in _host(moved.params).items():
+        np.testing.assert_array_equal(a, mid[n])
+    if through == "set_learning_rate":
+        moved.optimizer.set_learning_rate(0.05)
+    _one(steady, X, Y, 1, fused)
+    _one(moved, X, Y, 1, fused)
+    assert len(moved._compiled) == 1 and len(steady._compiled) == 1
+    a, b = _host(steady.params), _host(moved.params)
+    for n in a:
+        assert np.abs(a[n] - mid[n]).max() > 1e-5
+        np.testing.assert_allclose(2 * (b[n] - mid[n]), a[n] - mid[n],
+                                   rtol=1e-4, atol=1e-7)
+
+
+def _case_stored_dtype(builder, fused, dtype):
+    """Parameters stored in bf16 or f16, and Adam's moments beside them,
+    keep that dtype through a step (a float32 rate must not promote
+    them), and move."""
+    X, Y = _scalars_batches(2, dtype)
+    tr = _scalars_trainer(builder, "adam",
+                          {"learning_rate": 1e-2, "wd": 1e-2, "epsilon": 1e-3},
+                          dtype=dtype)
+    start = _host(tr.params)
+    for i in range(2):
+        loss = _one(tr, X, Y, i, fused)
+    assert np.isfinite(loss.asnumpy().astype("float32")).all()
+    for n, a in tr.params.items():
+        assert a.dtype == jnp.dtype(dtype), (n, a.dtype)
+        assert (np.asarray(a) != start[n]).any(), n
+    for n, st in tr._opt_states.items():
+        assert len(st) == 2
+        assert all(s.dtype == jnp.dtype(dtype) for s in st), n
+
+
+def _case_adam_parity(builder, fused):
+    """Three Adam steps on float32 parameters against the same three done
+    leaf by leaf with ``Adam._update_raw`` and Python scalars (how the
+    step took its rates before they were arrays) on the gradients the
+    trainer saw, each from the state the trainer had: bit for bit under
+    the pjit step on the CPU backend. Under the shard_map step XLA folds
+    the step's own ``g / mesh.size`` into the update, so the parameters
+    agree to 1 ulp and the moments to 8 (5 read); with Python scalars in
+    the step it reads the same ulps, leaf for leaf."""
+    X, Y = _scalars_batches(3)
+    opt = _RecordingAdam(learning_rate=3e-3, wd=1e-2)
+    tr = _scalars_trainer(builder, opt,
+                          mults={"0.bias": (0.5, 0.0), "1.weight": (2.0, 3.0)})
+    rule = jax.jit(lambda p, g, m, v, lr, wd, t: mx.optimizer.Adam._update_raw(
+        opt, p, g, (m, v), lr, wd, t))
+    ulps = (0, 0, 0) if builder == "pjit" else (1, 8, 8)
+    for step in range(3):
+        before_p = _host(tr.params)
+        before_s = {n: tuple(np.asarray(s) for s in st[:2])
+                    for n, st in tr._opt_states.items()}
+        _one(tr, X, Y, step, fused)
+        for i, n in enumerate(tr._train_keys):
+            m, v, g = (np.asarray(s) for s in tr._opt_states[n])
+            assert np.abs(g).max() > 0
+            want = rule(before_p[n], g, *before_s[n], opt._get_lr(i),
+                        opt._get_wd(i), step + 1)
+            for got, w, ulp in zip((np.asarray(tr.params[n]), m, v),
+                                   jax.tree_util.tree_leaves(want), ulps):
+                np.testing.assert_array_max_ulp(got, np.asarray(w),
+                                                maxulp=ulp)
+    assert len(tr._compiled) == 1
+
+
+_SCALAR_CASES = {
+    "lr_mult": _case_mults,
+    "scheduler": lambda b, f: _case_new_rate(b, f, "scheduler"),
+    "set_learning_rate":
+        lambda b, f: _case_new_rate(b, f, "set_learning_rate"),
+    "bfloat16": lambda b, f: _case_stored_dtype(b, f, "bfloat16"),
+    "float16": lambda b, f: _case_stored_dtype(b, f, "float16"),
+    "adam_parity": _case_adam_parity,
+}
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["step", "step_n"])
+@pytest.mark.parametrize("builder", ["pjit", "shard_map"])
+@pytest.mark.parametrize("case", list(_SCALAR_CASES))
+def test_optimizer_scalars_reach_the_step_as_arrays(case, builder, fused):
+    _SCALAR_CASES[case](builder, fused)
+
+
+@pytest.mark.parametrize("builder", ["pjit", "shard_map"])
+def test_abstract_trainer_lowers_the_arguments_the_step_takes(builder):
+    """``aot_lowered`` of an ``abstract=True`` trainer takes what the
+    running step is called with: the two float32 arrays, the Python step
+    count, and no other host value."""
+    X, Y = _scalars_batches(1)
+    live = _scalars_trainer(builder, "adam", {"learning_rate": 1e-2})
+    live.step(X[0], Y[0])
+    (compiled, _), = live._compiled.values()
+    dry = _scalars_trainer(builder, "adam", {"learning_rate": 1e-2},
+                           abstract=True)
+    lowered = dry.aot_lowered(jax.ShapeDtypeStruct(X[0].shape, X.dtype),
+                              jax.ShapeDtypeStruct(Y[0].shape, Y.dtype))
+    assert lowered.in_tree == compiled.in_tree
+
+    def flat(avals):
+        return [(a.shape, str(a.dtype))
+                for a in jax.tree_util.tree_leaves(avals)]
+
+    assert flat(lowered.in_avals) == flat(compiled.in_avals)
+    lrs, wds = dry._optimizer_scalars()
+    assert lrs.shape == wds.shape == (len(dry._train_keys),)
+    assert lrs.dtype == wds.dtype == np.float32
