@@ -17,8 +17,8 @@ ShardedTrainer shards the batch over ``dp``; long sequences shard over
 """
 from __future__ import annotations
 
+import collections
 import functools
-import math
 
 from ..base import MXNetError
 from ..gluon import nn
@@ -55,6 +55,16 @@ def apply_rope(x, cos, sin):
     return stacked.reshape(*x.shape)
 
 
+# What one layer keeps between serving steps, as the model's
+# ``cache_spec()`` lists it a layer: the geometry of its K/V rows
+# (``kv_heads`` x ``head_dim`` a position, paged or in rings) and the shapes
+# of its recurrent state, one row a sequence (``state``: a tuple of
+# per-sequence shapes, empty for a layer that keeps none). ``serve.KVCache``
+# and ``serve.PagedKVPool`` size themselves from this and nothing else.
+LayerCache = collections.namedtuple("LayerCache",
+                                    ["kv_heads", "head_dim", "state"])
+
+
 def _serving_dense(x, weight, cache):
     """Projection by the parameter ``weight`` on the serving fast rungs:
     the int8 rung looks it up in the cache's pre-quantized side table
@@ -67,34 +77,63 @@ def _serving_dense(x, weight, cache):
     return _ops.serving_dense(x, weight.data())
 
 
+def _dense_on(cache):
+    """How a block's projections are computed on the path ``cache``
+    stands for: ``dense(h, layer)``. No cache is the normal path (the
+    layer itself); the baseline rung's cache the shape-stable
+    ``ops.nn.stable_dense``, which keeps T=1 decode bitwise equal to
+    T=bucket prefill; a fast rung's the gemm, or int8 through the cache's
+    quant side table (:func:`_serving_dense`)."""
+    if cache is None:
+        return lambda h, layer: layer(h)
+    if getattr(cache, "path", "baseline") == "baseline":
+        return lambda h, layer: _ops.stable_dense(h, layer.weight.data())
+    return lambda h, layer: _serving_dense(h, layer.weight, cache)
+
+
 class LlamaAttention(HybridBlock):
     """Causal GQA attention with RoPE."""
 
     def __init__(self, units, num_heads, num_kv_heads=None, theta=10000.0,
-                 **kwargs):
+                 head_dim=None, key_multiplier=None, **kwargs):
         super().__init__(**kwargs)
         num_kv_heads = num_kv_heads or num_heads
-        if units % num_heads or num_heads % num_kv_heads:
+        if (head_dim is None and units % num_heads) \
+                or num_heads % num_kv_heads:
             raise MXNetError(
                 f"units {units} / heads {num_heads} / kv {num_kv_heads} "
                 "must divide")
-        self._units = units
         self._heads = num_heads
         self._kv_heads = num_kv_heads
-        self._head_dim = units // num_heads
+        # head_dim=None is Llama's units // heads; a model whose heads
+        # project to another width (20 heads of 128 on a 5120 stream)
+        # says so
+        self._head_dim = int(head_dim) if head_dim else units // num_heads
         self._theta = theta
+        # key_multiplier=None multiplies nothing (Llama): the traced
+        # program is then the one it always was
+        self._key_mult = key_multiplier
+        q_units = self._q_units = self._head_dim * num_heads
         kv_units = self._head_dim * num_kv_heads
         # explicit in_units: static shapes at construction, required by
         # the abstract (compile-only) functionalize path used for the 8B
         # AOT memory proof (parallel/functional.functionalize_abstract)
-        self.q_proj = nn.Dense(units, flatten=False, use_bias=False,
+        self.q_proj = nn.Dense(q_units, flatten=False, use_bias=False,
                                in_units=units)
         self.k_proj = nn.Dense(kv_units, flatten=False, use_bias=False,
                                in_units=units)
         self.v_proj = nn.Dense(kv_units, flatten=False, use_bias=False,
                                in_units=units)
         self.o_proj = nn.Dense(units, flatten=False, use_bias=False,
-                               in_units=units)
+                               in_units=q_units)
+
+    def cache_geometry(self):
+        """(kv_heads, head_dim): what a position of this layer's K/V
+        cache holds."""
+        return self._kv_heads, self._head_dim
+
+    def _keys(self, k):
+        return k if self._key_mult is None else k * self._key_mult
 
     def _heads_split(self, x, n):
         b, t, _ = x.shape
@@ -118,7 +157,8 @@ class LlamaAttention(HybridBlock):
         rep = self._heads // self._kv_heads
         if cache is None:
             q = self._heads_split(self.q_proj(x), self._heads)
-            k = self._heads_split(self.k_proj(x), self._kv_heads)
+            k = self._heads_split(self._keys(self.k_proj(x)),
+                                  self._kv_heads)
             v = self._heads_split(self.v_proj(x), self._kv_heads)
             cos_t, sin_t = _rope_tables(t, self._head_dim, self._theta)
             cos = mnp.array(cos_t)
@@ -142,7 +182,7 @@ class LlamaAttention(HybridBlock):
                 _ops.stable_dense(x, self.q_proj.weight.data()),
                 self._heads)
             k = self._heads_split(
-                _ops.stable_dense(x, self.k_proj.weight.data()),
+                self._keys(_ops.stable_dense(x, self.k_proj.weight.data())),
                 self._kv_heads)
             v = self._heads_split(
                 _ops.stable_dense(x, self.v_proj.weight.data()),
@@ -160,9 +200,9 @@ class LlamaAttention(HybridBlock):
                 k_all = mnp.repeat(k_all, rep, axis=1)
                 v_all = mnp.repeat(v_all, rep, axis=1)
             out = _ops.cached_attention(q, k_all, v_all, start_pos)
-            out = out.transpose(0, 2, 1, 3).reshape(b, t, self._units)
+            out = out.transpose(0, 2, 1, 3).reshape(b, t, self._q_units)
             return _ops.stable_dense(out, self.o_proj.weight.data())
-        out = out.transpose(0, 2, 1, 3).reshape(b, t, self._units)
+        out = out.transpose(0, 2, 1, 3).reshape(b, t, self._q_units)
         return self.o_proj(out)
 
     def _forward_cached_fast(self, x, cache, start_pos, path):
@@ -174,8 +214,9 @@ class LlamaAttention(HybridBlock):
         b, t, _ = x.shape
         q = self._heads_split(_serving_dense(x, self.q_proj.weight, cache),
                               self._heads)
-        k = self._heads_split(_serving_dense(x, self.k_proj.weight, cache),
-                              self._kv_heads)
+        k = self._heads_split(
+            self._keys(_serving_dense(x, self.k_proj.weight, cache)),
+            self._kv_heads)
         v = self._heads_split(_serving_dense(x, self.v_proj.weight, cache),
                               self._kv_heads)
         cos_t, sin_t = _rope_tables(cache.max_seq, self._head_dim,
@@ -199,15 +240,20 @@ class LlamaAttention(HybridBlock):
             cache.update(k_all, v_all)
             out = _ops.cached_attention(q, k_all, v_all, start_pos,
                                         path=path)
-        out = out.transpose(0, 2, 1, 3).reshape(b, t, self._units)
+        out = out.transpose(0, 2, 1, 3).reshape(b, t, self._q_units)
         return _serving_dense(out, self.o_proj.weight, cache)
 
 
 class LlamaFFN(HybridBlock):
-    """SwiGLU: down(silu(gate(x)) * up(x))."""
+    """SwiGLU: down(silu(gate(x)) * up(x)). ``gate_multiplier`` scales
+    the gate's pre-activation and ``down_multiplier`` the output; None
+    (Llama) multiplies nothing."""
 
-    def __init__(self, units, hidden_size, **kwargs):
+    def __init__(self, units, hidden_size, gate_multiplier=None,
+                 down_multiplier=None, **kwargs):
         super().__init__(**kwargs)
+        self._gate_mult = gate_multiplier
+        self._down_mult = down_multiplier
         self.gate_proj = nn.Dense(hidden_size, flatten=False,
                                   use_bias=False, in_units=units)
         self.up_proj = nn.Dense(hidden_size, flatten=False, use_bias=False,
@@ -215,45 +261,30 @@ class LlamaFFN(HybridBlock):
         self.down_proj = nn.Dense(units, flatten=False, use_bias=False,
                                   in_units=hidden_size)
 
-    def forward(self, x, stable=False, cache=None):
-        if stable:
-            # serving decode path: shape-stable projections (see
-            # ops.nn.stable_dense) keep T=1 bitwise equal to T=bucket
-            g = _ops.activation(
-                _ops.stable_dense(x, self.gate_proj.weight.data()), "silu")
-            return _ops.stable_dense(
-                g * _ops.stable_dense(x, self.up_proj.weight.data()),
-                self.down_proj.weight.data())
-        if cache is not None:
-            # serving fast rungs: gemm / int8 projections via the cache's
-            # quant side table
-            g = _ops.activation(
-                _serving_dense(x, self.gate_proj.weight, cache), "silu")
-            up = _serving_dense(x, self.up_proj.weight, cache)
-            return _serving_dense(g * up, self.down_proj.weight, cache)
-        g = _ops.activation(self.gate_proj(x), "silu")
-        return self.down_proj(g * self.up_proj(x))
+    def forward(self, x, cache=None):
+        dense = _dense_on(cache)
+        gate = dense(x, self.gate_proj)
+        if self._gate_mult is not None:
+            gate = gate * self._gate_mult
+        out = dense(_ops.activation(gate, "silu") * dense(x, self.up_proj),
+                    self.down_proj)
+        return out if self._down_mult is None else out * self._down_mult
 
 
 class LlamaBlock(HybridBlock):
     def __init__(self, units, hidden_size, num_heads, num_kv_heads,
-                 norm_eps=1e-5, **kwargs):
+                 norm_eps=1e-5, theta=10000.0, **kwargs):
         super().__init__(**kwargs)
         self.attn_norm = nn.RMSNorm(epsilon=norm_eps, in_channels=units)
-        self.attention = LlamaAttention(units, num_heads, num_kv_heads)
+        self.attention = LlamaAttention(units, num_heads, num_kv_heads,
+                                        theta=theta)
         self.ffn_norm = nn.RMSNorm(epsilon=norm_eps, in_channels=units)
         self.ffn = LlamaFFN(units, hidden_size)
 
     def forward(self, x, cache=None, start_pos=None):
         x = x + self.attention(self.attn_norm(x), cache=cache,
                                start_pos=start_pos)
-        fast = (cache is not None
-                and getattr(cache, "path", "baseline") != "baseline")
-        if fast:
-            x = x + self.ffn(self.ffn_norm(x), cache=cache)
-        else:
-            x = x + self.ffn(self.ffn_norm(x), stable=cache is not None)
-        return x
+        return x + self.ffn(self.ffn_norm(x), cache=cache)
 
 
 class LlamaModel(HybridBlock):
@@ -267,7 +298,7 @@ class LlamaModel(HybridBlock):
     def __init__(self, vocab_size=32000, units=4096, hidden_size=11008,
                  num_layers=32, num_heads=32, num_kv_heads=None,
                  norm_eps=1e-5, tie_embeddings=False, remat=False,
-                 layer_barrier=False, **kwargs):
+                 layer_barrier=False, theta=10000.0, **kwargs):
         super().__init__(**kwargs)
         self._units = units
         self._tie = tie_embeddings
@@ -295,13 +326,19 @@ class LlamaModel(HybridBlock):
         self._blocks = []
         for i in range(num_layers):
             blk = LlamaBlock(units, hidden_size, num_heads, num_kv_heads,
-                             norm_eps)
+                             norm_eps, theta=theta)
             self._blocks.append(blk)
             self.register_child(blk, f"layer{i}")
         self.norm = nn.RMSNorm(epsilon=norm_eps, in_channels=units)
         if not tie_embeddings:
             self.lm_head = nn.Dense(vocab_size, flatten=False,
                                     use_bias=False, in_units=units)
+
+    def cache_spec(self):
+        """What each layer keeps between serving steps (see
+        :data:`LayerCache`): K/V rows and no recurrent state."""
+        return [LayerCache(*blk.attention.cache_geometry(), ())
+                for blk in self._blocks]
 
     def forward(self, input_ids, cache=None, start_pos=None):
         x = self.embed(input_ids)

@@ -1293,6 +1293,193 @@ def paged_kv_scatter(pool, page_table, ring, start_pos, length):
 
 
 # ---------------------------------------------------------------------------
+# Recurrent-state serving ops (the Mamba-2 mixer of models/falcon_h1.py)
+#
+# A state-space layer keeps, for every sequence, a fixed-size state that
+# every position advances: the last K-1 inputs of its causal convolution
+# and the (H, P, N) matrix of the selective scan. Unlike a KV ring there
+# is no position mask to hide stale or padded rows behind, so the two ops
+# below take the step's lane contract as arguments and keep it themselves:
+#
+# (a) positions ``t >= valid_len[b]`` (the padded tail of a prefill chunk)
+#     leave both states as the last valid position left them;
+# (b) rows with ``live[b]`` false (an empty slot, a slot whose neighbour
+#     is being prefilled) hand their state back bit for bit;
+# (c) rows with ``start_pos[b] == 0`` (a request's first call) start from
+#     zero, whatever the lane's last tenant left.
+#
+# Both are plain XLA operations inside the step executable. Outside a
+# serving step (a whole sequence from zero state, every position valid,
+# every row live) the four lane arguments are left ``None``.
+# ---------------------------------------------------------------------------
+
+
+def _lane_defaults(batch, t_len, state_shape, state, start_pos, valid_len,
+                   live):
+    """The lane arguments with what ``None`` stands for filled in."""
+    jnp = _jnp()
+    if state is None:
+        state = jnp.zeros((batch,) + state_shape, jnp.float32)
+    if start_pos is None:
+        start_pos = jnp.zeros((batch,), jnp.int32)
+    if valid_len is None:
+        valid_len = jnp.full((batch,), t_len, jnp.int32)
+    if live is None:
+        live = jnp.ones((batch,), bool)
+    return state, start_pos, valid_len, live
+
+
+def state_rows_gather(store, lanes):
+    """The rows of a recurrent-state array (R, ...) that a call's batch
+    rows read: ``lanes`` (B,) int32 names each batch row's row of
+    ``store``, negative for a row that is not live. A call as wide as
+    the store (a decode step over every slot; a ring cache) maps row i to
+    row i and moves nothing; a narrower one (the (1, chunk) prefill)
+    takes its rows, an exact copy."""
+    if store.shape[0] == lanes.shape[0]:
+        return store
+
+    def f(st, ln):
+        jnp = _jnp()
+        return jnp.take(st, jnp.maximum(ln.astype(jnp.int32), 0), axis=0)
+
+    return _apply(f, (store, lanes), name="state_rows_gather")
+
+
+def state_rows_scatter(store, lanes, rows):
+    """The inverse of :func:`state_rows_gather`: ``store`` with ``rows``
+    written back at ``lanes``. Rows that are not live are dropped, so the
+    store keeps theirs untouched."""
+    if store.shape[0] == lanes.shape[0]:
+        return rows
+
+    def f(st, ln, new):
+        jnp = _jnp()
+        ln = ln.astype(jnp.int32)
+        at = jnp.where(ln >= 0, ln, st.shape[0])    # out of range: dropped
+        return st.at[at].set(new, mode="drop")
+
+    return _apply(f, (store, lanes, rows), name="state_rows_scatter")
+
+
+def grouped_rms_norm(data, gamma, groups=1, eps=1e-6):
+    """RMSNorm over each of ``groups`` equal slices of the last axis
+    (Mamba-2's gated norm with ``n_groups`` > 1), then the gain."""
+
+    def f(x, g):
+        jnp = _jnp()
+        xg = x.reshape(x.shape[:-1] + (groups, x.shape[-1] // groups))
+        ms = jnp.mean(jnp.square(xg), axis=-1, keepdims=True)
+        return (xg * (1.0 / jnp.sqrt(ms + eps))).reshape(x.shape) * g
+
+    return _apply(f, (data, gamma), name="grouped_rms_norm")
+
+
+def causal_conv1d(data, weight, bias, state=None, start_pos=None,
+                  valid_len=None, live=None):
+    """Depthwise causal convolution along time with carried context.
+
+    ``data`` (B, T, C), ``weight`` (C, K) with ``weight[:, K-1]`` on the
+    current position, ``bias`` (C,), ``state`` (B, K-1, C): the K-1 inputs
+    before ``data[:, 0]``. Returns ``(out (B, T, C), new_state)`` under the
+    lane contract of the section comment: ``new_state`` holds the last
+    K-1 inputs at or before position ``valid_len[b] - 1``.
+    """
+
+    def f(x, w, b, st, sp, vl, lv):
+        jnp = _jnp()
+        t_len, k = x.shape[1], w.shape[1]
+        st, sp, vl, lv = _lane_defaults(
+            x.shape[0], t_len, (k - 1, x.shape[2]), st, sp, vl, lv)
+        fresh = (sp.astype(jnp.int32) == 0)[:, None, None]
+        win = jnp.concatenate([jnp.where(fresh, 0.0, st), x], axis=1)
+        out = b
+        for j in range(k):
+            out = out + win[:, j:j + t_len, :] * w[:, j]
+        # window row i holds input i - (K-1): the last K-1 valid inputs
+        # are rows valid_len .. valid_len + K - 2
+        rows = vl.astype(jnp.int32)[:, None] \
+            + jnp.arange(k - 1, dtype=jnp.int32)[None, :]
+        new = jnp.take_along_axis(win, rows[:, :, None], axis=1)
+        return out, jnp.where(lv.astype(bool)[:, None, None], new, st)
+
+    return _apply(f, (data, weight, bias, state, start_pos, valid_len, live),
+                  name="causal_conv1d")
+
+
+def ssd_scan(x, dt, a, b_mat, c_mat, d, state=None, start_pos=None,
+             valid_len=None, live=None, chunk=128):
+    """Mamba-2 selective scan (state-space duality form) with carried state.
+
+    For every head ``h`` (scalar decay), ``S_t = exp(dt_t a) S_{t-1} +
+    dt_t x_t (x) B_t`` and ``y_t = S_t C_t + d x_t``. ``x`` (B, T, H, P),
+    ``dt`` (B, T, H) (after softplus), ``a`` (H,) negative, ``b_mat`` /
+    ``c_mat`` (B, T, G, N) with H / G heads to a group, ``d`` (H,),
+    ``state`` (B, H, P, N). Returns ``(y (B, T, H, P), new_state)`` under
+    the lane contract of the section comment.
+
+    T = 1 is the recurrence itself, one pass over the state. T > 1 runs
+    in chunks of ``chunk`` positions: inside a chunk the outputs are a
+    masked (T, T) product and the state enters and leaves once, so a
+    prefill chunk costs matrix products, not T passes over the state.
+    The two are the same mathematics (tests/test_falcon_h1.py holds them
+    to each other across a chunk edge and a padded tail).
+    """
+    chunk = int(chunk)
+
+    def f(xv, dtv, av, bv, cv, dv, st, sp, vl, lv):
+        jnp = _jnp()
+        t_len, heads = xv.shape[1], xv.shape[2]
+        st, sp, vl, lv = _lane_defaults(
+            xv.shape[0], t_len, xv.shape[2:] + bv.shape[3:], st, sp, vl, lv)
+        rep = heads // bv.shape[2]
+        prec = stored_precision(xv, st)
+        alive = lv.astype(bool)[:, None, None, None]
+        fresh = (sp.astype(jnp.int32) == 0)[:, None, None, None]
+        s0 = jnp.where(fresh, 0.0, st)
+        valid = jnp.arange(t_len, dtype=jnp.int32)[None, :] \
+            < vl.astype(jnp.int32)[:, None]                      # (B, T)
+        # a position past the row's valid length neither decays the
+        # state nor adds to it
+        dtv = jnp.where(valid[:, :, None], dtv, 0.0)
+        bh = jnp.repeat(bv, rep, axis=2)                         # (B,T,H,N)
+        ch = jnp.repeat(cv, rep, axis=2)
+        xdt = xv * dtv[..., None]                                # (B,T,H,P)
+        if t_len == 1:
+            decay = jnp.exp(dtv[:, 0] * av)                      # (B, H)
+            s1 = s0 * decay[:, :, None, None] \
+                + xdt[:, 0, :, :, None] * bh[:, 0, :, None, :]
+            y = jnp.sum(s1 * ch[:, 0, :, None, :], axis=-1) \
+                + dv[:, None] * xv[:, 0]
+            return y[:, None], jnp.where(alive, s1, st)
+        q = min(chunk, t_len)
+        ys, s = [], s0
+        for lo in range(0, t_len, q):
+            hi = min(lo + q, t_len)
+            la = jnp.cumsum(dtv[:, lo:hi] * av, axis=1)          # (B,q,H)
+            # W[b,h,t,s] = (C_t . B_s) exp(L_t - L_s) for s <= t
+            cb = jnp.einsum("btgn,bsgn->bgts", cv[:, lo:hi], bv[:, lo:hi],
+                            precision=prec)
+            seg = la[:, :, None, :] - la[:, None, :, :]          # (B,t,s,H)
+            causal = jnp.tril(jnp.ones((hi - lo, hi - lo), bool))
+            m = jnp.exp(jnp.where(causal[None, :, :, None], seg, -jnp.inf))
+            w = jnp.repeat(cb, rep, axis=1) * m.transpose(0, 3, 1, 2)
+            y = jnp.einsum("bhts,bshp->bthp", w, xdt[:, lo:hi],
+                           precision=prec)
+            y = y + jnp.exp(la)[..., None] * jnp.einsum(
+                "bthn,bhpn->bthp", ch[:, lo:hi], s, precision=prec)
+            ys.append(y + dv[:, None] * xv[:, lo:hi])
+            to_end = jnp.exp(la[:, -1:, :] - la)                 # (B,q,H)
+            s = s * jnp.exp(la[:, -1])[:, :, None, None] + jnp.einsum(
+                "bshp,bshn->bhpn", xdt[:, lo:hi] * to_end[..., None],
+                bh[:, lo:hi], precision=prec)
+        return jnp.concatenate(ys, axis=1), jnp.where(alive, s, st)
+
+    return _apply(f, (x, dt, a, b_mat, c_mat, d, state, start_pos,
+                      valid_len, live), name="ssd_scan")
+
+
+# ---------------------------------------------------------------------------
 # misc framework extras
 # ---------------------------------------------------------------------------
 

@@ -82,6 +82,23 @@ class _LayerKV:
     def quant_weights(self):
         return self._cache.quant_weights
 
+    # the layer's recurrent state (one row a sequence) and the step's
+    # lane contract for it (ops/nn.py, the recurrent-state section)
+    @property
+    def state(self):
+        return self._cache._state[self._idx]
+
+    @property
+    def valid_len(self):
+        return self._cache.valid_len
+
+    @property
+    def live(self):
+        return self._cache.live
+
+    def update_state(self, new_state):
+        self._cache._state[self._idx] = tuple(new_state)
+
     def update(self, new_k, new_v, new_k_scale=None, new_v_scale=None):
         self._cache._k[self._idx] = new_k
         self._cache._v[self._idx] = new_v
@@ -89,6 +106,102 @@ class _LayerKV:
             self._cache._ks[self._idx] = new_k_scale
         if new_v_scale is not None:
             self._cache._vs[self._idx] = new_v_scale
+
+
+class CacheLayout:
+    """What a model keeps between serving steps, read from its
+    ``cache_spec()``: for each layer a K/V geometry and the per-sequence
+    shapes of its recurrent state (``models.llama.LayerCache``). The one
+    description that :class:`KVCache` (rings), ``kv_blocks.PagedKVPool``
+    (pages, and state rows a slot) and the compiled step's calling
+    convention are all built from; a model with another kind of state
+    says so there and nowhere else.
+
+    The flat order is a layer at a time: ``k, v`` (``k, k_scale, v,
+    v_scale`` when quantized), then the layer's state arrays. ``kinds``
+    names each flat position ``"kv"`` (indexed by position: rings or
+    pages) or ``"state"`` (one row a sequence, never paged).
+    """
+
+    def __init__(self, model, quant=None):
+        spec = getattr(model, "cache_spec", None)
+        if spec is None:
+            raise MXNetError(
+                f"{type(model).__name__} cannot be served from a cache: it "
+                "has no cache_spec() (a list, one models.llama.LayerCache "
+                "a layer, of what the layer keeps between steps)")
+        if quant not in (None, "int8"):
+            raise MXNetError(f"unknown KV cache quant {quant!r}")
+        self.layers = list(spec())
+        self.quant = quant
+        self.kinds = []
+        for lay in self.layers:
+            self.kinds += ["kv"] * (4 if quant else 2)
+            self.kinds += ["state"] * len(lay.state)
+        self.has_state = "state" in self.kinds
+
+    def __len__(self):
+        return len(self.kinds)
+
+    def alloc(self, zeros, kv_lead, kv_seq, state_rows, dtype="float32"):
+        """The zeroed flat arrays: K/V of shape ``(kv_lead, kv_heads,
+        kv_seq, head_dim)`` (rings: batch and max_seq; pools: pages and
+        page size), state arrays with ``state_rows`` leading rows."""
+        out = []
+        for lay in self.layers:
+            shape = (int(kv_lead), lay.kv_heads, int(kv_seq), lay.head_dim)
+            if self.quant:
+                out += [zeros(shape, dtype="int8"),
+                        zeros(shape[:3], dtype="float32")] * 2
+            else:
+                out += [zeros(shape, dtype=dtype), zeros(shape, dtype=dtype)]
+            out += [zeros((int(state_rows),) + tuple(s), dtype="float32")
+                    for s in lay.state]
+        return out
+
+    def state_nbytes(self, arrays):
+        """Bytes of the state arrays among the flat ``arrays``."""
+        return sum(_nbytes(a) for a, k in zip(arrays, self.kinds)
+                   if k == "state")
+
+
+def _nbytes(a):
+    return int(_onp.prod(a.shape)) * _onp.dtype(a.dtype).itemsize
+
+
+def require_kv_only(model, what, missing):
+    """``what`` (a serving feature that shares or rewinds cache
+    positions) cannot serve a model that keeps recurrent state: refuse
+    loudly, naming what is ``missing``, rather than serve it wrongly."""
+    if CacheLayout(model).has_state:
+        raise MXNetError(
+            f"{what} cannot serve {type(model).__name__}: its layers keep "
+            f"recurrent state beside K/V, and {missing}")
+
+
+# what and missing of :func:`require_kv_only` for the prefix cache, which
+# Generator and ContinuousEngine both refuse
+PREFIX_CACHE_NEEDS = (
+    "the prefix cache (prefix_cache=True)",
+    "a prefix hit skips prefill by sharing pages, while the state after "
+    "the shared prefix was never kept (no state snapshots)")
+
+
+def gather_rings(layout, stores, table):
+    """The strict rung's standalone bracket before the ring executable:
+    every paged K/V pool of ``stores`` as per-row rings through
+    ``table`` (exact copies); state arrays pass as they are (the step
+    takes their rows itself, by ``lanes``)."""
+    return [_ops.paged_kv_gather(a, table) if kind == "kv" else a
+            for a, kind in zip(stores, layout.kinds)]
+
+
+def scatter_rings(layout, stores, table, returned, start_pos, t_len):
+    """The bracket after it: the freshly written ring rows back into
+    their pools; state arrays come back whole."""
+    return [_ops.paged_kv_scatter(a, table, r, start_pos, t_len)
+            if kind == "kv" else r
+            for a, r, kind in zip(stores, returned, layout.kinds)]
 
 
 class KVCache:
@@ -111,7 +224,7 @@ class KVCache:
     """
 
     def __init__(self, keys, values, max_seq, key_scales=None,
-                 value_scales=None, quant=None):
+                 value_scales=None, quant=None, states=None):
         if len(keys) != len(values):
             raise MXNetError("KVCache needs one value ring per key ring")
         self._k = list(keys)
@@ -120,36 +233,33 @@ class KVCache:
         self._vs = list(value_scales) if value_scales is not None else None
         if quant is not None and (self._ks is None or self._vs is None):
             raise MXNetError("quantized KVCache needs scale rings")
+        # per layer, the tuple of its recurrent-state arrays (one row a
+        # sequence; empty for a layer that keeps none)
+        self._state = ([tuple(st) for st in states] if states is not None
+                       else [()] * len(self._k))
+        if len(self._state) != len(self._k):
+            raise MXNetError("KVCache needs one state tuple per layer")
         self.quant = quant
         self.max_seq = int(max_seq)
         self.path = "baseline"
         self.quant_weights = None
+        # the step's lane contract for the recurrent state, set by the
+        # serving step before the model forward like ``path``: valid
+        # positions of each row in this call, and which rows are live
+        self.valid_len = None
+        self.live = None
 
     @classmethod
     def alloc(cls, model, batch, max_seq, dtype="float32", quant=None):
-        """Zeroed rings sized from the model's attention geometry."""
+        """Zeroed rings (and one state row a sequence) sized from the
+        model's own cache description (:class:`CacheLayout`)."""
         from .. import numpy as mnp
 
+        layout = CacheLayout(model, quant)
         zeros = functools.partial(mnp.zeros, ctx=block_context(model))
-        keys, values = [], []
-        kscales, vscales = [], []
-        for blk in model._blocks:
-            attn = blk.attention
-            shape = (int(batch), attn._kv_heads, int(max_seq),
-                     attn._head_dim)
-            if quant == "int8":
-                keys.append(zeros(shape, dtype="int8"))
-                values.append(zeros(shape, dtype="int8"))
-                kscales.append(zeros(shape[:3], dtype="float32"))
-                vscales.append(zeros(shape[:3], dtype="float32"))
-            elif quant is None:
-                keys.append(zeros(shape, dtype=dtype))
-                values.append(zeros(shape, dtype=dtype))
-            else:
-                raise MXNetError(f"unknown KVCache quant {quant!r}")
-        if quant is None:
-            return cls(keys, values, max_seq)
-        return cls(keys, values, max_seq, kscales, vscales, quant)
+        return cls.from_flat(
+            layout.alloc(zeros, batch, max_seq, batch, dtype), max_seq,
+            quant=quant, layout=layout)
 
     @property
     def num_layers(self):
@@ -163,37 +273,57 @@ class KVCache:
         return _LayerKV(self, i)
 
     def flat(self):
-        """Interleaved [k0, v0, k1, v1, ...] — the executable's calling
-        convention for cache state. Quantized caches interleave
-        [k0, ks0, v0, vs0, ...] (scale ring right after its int8 ring)."""
+        """The executable's calling convention for cache state, a layer
+        at a time (:class:`CacheLayout`): [k0, v0, *state0, k1, v1, ...];
+        quantized caches put each scale ring right after its int8 ring
+        ([k0, ks0, v0, vs0, *state0, ...])."""
         out = []
-        if self.quant is not None:
-            for k, ks, v, vs in zip(self._k, self._ks, self._v, self._vs):
-                out.extend((k, ks, v, vs))
-            return out
-        for k, v in zip(self._k, self._v):
-            out.extend((k, v))
+        for i, (k, v) in enumerate(zip(self._k, self._v)):
+            if self.quant is not None:
+                out.extend((k, self._ks[i], v, self._vs[i]))
+            else:
+                out.extend((k, v))
+            out.extend(self._state[i])
         return out
 
     @classmethod
-    def from_flat(cls, arrays, max_seq, quant=None):
+    def from_flat(cls, arrays, max_seq, quant=None, layout=None):
+        """The cache over ``arrays`` in :meth:`flat` order. ``layout``
+        says how many state arrays follow each layer's rings; without
+        one there are none."""
         arrays = list(arrays)
-        if quant is not None:
-            if len(arrays) % 4:
+        per = 4 if quant is not None else 2
+        if layout is None:
+            if len(arrays) % per:
                 raise MXNetError(
-                    "flat quantized KVCache needs 4 arrays per layer")
-            return cls(arrays[0::4], arrays[2::4], max_seq,
-                       arrays[1::4], arrays[3::4], quant)
-        if len(arrays) % 2:
-            raise MXNetError("flat KVCache needs an even array count")
-        return cls(arrays[0::2], arrays[1::2], max_seq)
+                    "flat quantized KVCache needs 4 arrays per layer"
+                    if quant is not None else
+                    "flat KVCache needs an even array count")
+            counts = [0] * (len(arrays) // per)
+        else:
+            counts = [len(lay.state) for lay in layout.layers]
+            if len(arrays) != len(layout):
+                raise MXNetError(
+                    f"flat KVCache: got {len(arrays)} arrays, the model's "
+                    f"cache description has {len(layout)}")
+        rings, states, at = [], [], 0
+        for n in counts:
+            rings.append(arrays[at:at + per])
+            states.append(tuple(arrays[at + per:at + per + n]))
+            at += per + n
+        if quant is not None:
+            return cls([r[0] for r in rings], [r[2] for r in rings], max_seq,
+                       [r[1] for r in rings], [r[3] for r in rings], quant,
+                       states=states)
+        return cls([r[0] for r in rings], [r[1] for r in rings], max_seq,
+                   states=states)
 
     def nbytes(self):
-        arrays = self._k + self._v
-        if self.quant is not None:
-            arrays = arrays + self._ks + self._vs
-        return sum(int(_onp.prod(a.shape)) * _onp.dtype(a.dtype).itemsize
-                   for a in arrays)
+        return sum(_nbytes(a) for a in self.flat())
+
+    def state_nbytes(self):
+        """The recurrent state's part of :meth:`nbytes`."""
+        return sum(_nbytes(a) for st in self._state for a in st)
 
 
 class _CacheForward(HybridBlock):
@@ -221,6 +351,19 @@ class _CacheForward(HybridBlock):
     paged baseline decode is bitwise identical to ring decode because
     it literally replays the same compiled step
     (tests/test_kv_blocks.py asserts it).
+
+    The calling convention follows the model's :class:`CacheLayout`. A
+    model whose layers keep recurrent state adds a ``lanes`` (B,) int32
+    arg before the cache arrays (after ``page_table`` when paged): the
+    row of the state arrays that each batch row reads and writes, -1
+    for a row that is not live in this call (its state comes back bit
+    for bit). State arrays are one row a sequence and never paged; a
+    call as wide as they are (a decode step over every slot, a ring
+    cache) maps row i to row i, a narrower one (the (1, chunk) prefill)
+    takes and returns its rows by ``lanes``. ``last_idx`` doubles as the
+    count of real positions (``last_idx + 1``), past which a padded
+    prefill chunk leaves the state alone. A model with K/V alone keeps
+    the convention, and the traced program, it always had.
     """
 
     def __init__(self, model, max_seq, path="baseline", quant=None,
@@ -233,23 +376,32 @@ class _CacheForward(HybridBlock):
         self._qindex = list(qindex)
         self._all_logits = bool(all_logits)
         self._paged = bool(paged)
-        n_layers = len(model._blocks)
-        self._n_cache = n_layers * (4 if quant else 2)
+        self._layout = CacheLayout(model, quant)
 
     def forward(self, tokens, start_pos, last_idx, *rest):
-        page_table = None
+        layout = self._layout
+        page_table = lanes = None
         if self._paged:
             page_table, rest = rest[0], rest[1:]
-        flat_cache = rest[:self._n_cache]
-        qflat = rest[self._n_cache:]
-        pools = None
-        if self._paged:
-            pools = flat_cache
-            flat_cache = [_ops.paged_kv_gather(p, page_table)
-                          for p in pools]
+        if layout.has_state:
+            lanes, rest = rest[0], rest[1:]
+        stores = rest[:len(layout)]
+        qflat = rest[len(layout):]
+        # what the model's cache path reads: K/V as per-row rings (gathered
+        # through the page table when paged), state as one row a batch row
+        flat_cache = [
+            _ops.state_rows_gather(a, lanes) if kind == "state"
+            else _ops.paged_kv_gather(a, page_table) if self._paged else a
+            for a, kind in zip(stores, layout.kinds)]
         cache = KVCache.from_flat(flat_cache, self._max_seq,
-                                  quant=self._quant)
+                                  quant=self._quant, layout=layout)
         cache.path = self._path
+        if layout.has_state:
+            # the lane contract of the recurrent-state ops: the real
+            # positions of a row are 0 .. last_idx, and a row whose lane
+            # is negative is not live in this call
+            cache.valid_len = last_idx + 1
+            cache.live = lanes >= 0
         if qflat:
             # int8 weight side table: quantized weights enter as two packed
             # traced call args (appended after the rings by Generator._run),
@@ -264,12 +416,12 @@ class _CacheForward(HybridBlock):
                 soff += o
             cache.quant_weights = table
         logits = self.model(tokens, cache=cache, start_pos=start_pos)
-        updated = tuple(cache.flat())
-        if self._paged:
-            t_len = tokens.shape[1]
-            updated = tuple(
-                _ops.paged_kv_scatter(p, page_table, r, start_pos, t_len)
-                for p, r in zip(pools, updated))
+        t_len = tokens.shape[1]
+        updated = tuple(
+            _ops.state_rows_scatter(a, lanes, new) if kind == "state"
+            else _ops.paged_kv_scatter(a, page_table, new, start_pos, t_len)
+            if self._paged else new
+            for a, new, kind in zip(stores, cache.flat(), layout.kinds))
         if self._all_logits:
             # speculative verify step: the caller scores every position of
             # the (k+1)-token block, not just the last real one
@@ -384,8 +536,12 @@ class _MultiStepForward(HybridBlock):
         self._quant = quant
         self._qindex = list(qindex)
         self._paged = bool(paged)
-        n_layers = len(model._blocks)
-        self._n_cache = n_layers * (4 if quant else 2)
+        require_kv_only(
+            model, "multi-step decode (multistep=True)",
+            "a lane that finished inside the compiled loop would have to "
+            "freeze its state as it freezes its position (the loop "
+            "rewrites frozen K/V, which is idempotent; a state is not)")
+        self._n_cache = len(CacheLayout(model, quant))
 
     def forward(self, tokens, start_pos, steps_limit, remaining, seeds,
                 temps, top_ks, stops, key_bits, *rest):
@@ -577,8 +733,9 @@ class Generator:
 
     Parameters
     ----------
-    model : LlamaModel (or any block with ``_blocks[i].attention`` KV
-        geometry and a ``cache=``/``start_pos=`` forward).
+    model : a block with a ``cache=``/``start_pos=`` forward and a
+        ``cache_spec()`` saying what each layer keeps between steps
+        (:class:`CacheLayout`): ``LlamaModel``, ``FalconH1Model``.
     max_seq : ring length — prompt + generated tokens must fit.
     batch_buckets / prompt_buckets : the compiled shape lattice.
     decode_path : which rung this generator compiles (see
@@ -629,6 +786,9 @@ class Generator:
         if prefix_cache is None:
             prefix_cache = bool(config.get("MXNET_SERVE_PREFIX_CACHE"))
         self._prefix_on = bool(prefix_cache)
+        self._layout = CacheLayout(model, self._quant)
+        if self._prefix_on:
+            require_kv_only(model, *PREFIX_CACHE_NEEDS)
         if self._prefix_on and paged is False:
             raise MXNetError(
                 "prefix_cache requires the paged KV pool (prefix pages "
@@ -692,6 +852,12 @@ class Generator:
                 seq_buckets=(1,), pad_value=self.pad_id,
                 name=f"{name}_multi")
 
+    def _set_cache_gauges(self):
+        caches = self._zero_caches.values()
+        self.metrics.set_kv_cache_bytes(
+            sum(c.nbytes() for c in caches),
+            state=sum(c.state_nbytes() for c in caches))
+
     def _fresh_cache(self, batch_bucket):
         """Zeroed rings for one batch bucket, allocated once and shared
         by every request: device arrays are immutable and prefill/decode
@@ -727,9 +893,7 @@ class Generator:
                     for s in range(batch_bucket):
                         pool.assign(s, self.max_seq)
                 self._zero_caches[batch_bucket] = pool
-                self.metrics.set_kv_cache_bytes(
-                    sum(c.nbytes()
-                        for c in self._zero_caches.values()))
+                self._set_cache_gauges()
                 self.metrics.set_kv_pages(pool.pages_used,
                                           pool.pages_free)
             return pool
@@ -739,8 +903,7 @@ class Generator:
                 batch_bucket,
                 KVCache.alloc(self.model, batch_bucket, self.max_seq,
                               quant=self._quant))
-            self.metrics.set_kv_cache_bytes(
-                sum(c.nbytes() for c in self._zero_caches.values()))
+            self._set_cache_gauges()
         return cache
 
     # -- phase helpers (also the parity-test surface) -----------------------
@@ -748,36 +911,35 @@ class Generator:
     def _run(self, tokens, start_pos, last_idx, cache):
         from .. import numpy as mnp
 
+        toks = mnp.array(_onp.asarray(tokens, _onp.int32))
+        sp = mnp.array(_onp.asarray(start_pos, _onp.int32))
+        li = mnp.array(_onp.asarray(last_idx, _onp.int32))
+        # every row of a Generator call is live and owns state row i
+        lanes = ([mnp.array(_onp.arange(toks.shape[0], dtype=_onp.int32))]
+                 if self._layout.has_state else [])
         if self._paged:
-            toks = mnp.array(_onp.asarray(tokens, _onp.int32))
-            sp = mnp.array(_onp.asarray(start_pos, _onp.int32))
-            li = mnp.array(_onp.asarray(last_idx, _onp.int32))
             if not self._fused_paged:
                 # strict rung: run the paging brackets as standalone
                 # exact-copy device ops around the UNCHANGED ring
                 # executable -> bitwise identical to ring decode
                 table = cache.table_nd()
-                rings = [_ops.paged_kv_gather(p, table)
-                         for p in cache.flat()]
-                out = self.session.run(toks, sp, li, *rings,
+                rings = gather_rings(self._layout, cache.flat(), table)
+                out = self.session.run(toks, sp, li, *lanes, *rings,
                                        *self._qflat)
-                t_len = _onp.asarray(tokens).shape[1]
-                cache.update_from_flat([
-                    _ops.paged_kv_scatter(p, table, r, sp, t_len)
-                    for p, r in zip(cache.flat(), out[1:])])
+                cache.update_from_flat(scatter_rings(
+                    self._layout, cache.flat(), table, out[1:], sp,
+                    toks.shape[1]))
                 return out[0], cache
-            out = self.session.run(toks, sp, li, cache.table_nd(),
+            out = self.session.run(toks, sp, li, cache.table_nd(), *lanes,
                                    *cache.flat(), *self._qflat)
             cache.update_from_flat(out[1:])
             return out[0], cache
-        out = self.session.run(
-            mnp.array(_onp.asarray(tokens, _onp.int32)),
-            mnp.array(_onp.asarray(start_pos, _onp.int32)),
-            mnp.array(_onp.asarray(last_idx, _onp.int32)),
-            *cache.flat(), *self._qflat)
+        out = self.session.run(toks, sp, li, *lanes, *cache.flat(),
+                               *self._qflat)
         logits, flat = out[0], out[1:]
         return logits, KVCache.from_flat(flat, self.max_seq,
-                                         quant=self._quant)
+                                         quant=self._quant,
+                                         layout=self._layout)
 
     def prefill(self, prompts, prompt_lens, cache):
         """Run the prompt block through the cache path. ``prompts`` is a
@@ -1342,6 +1504,12 @@ class SpeculativeGenerator:
             config.get("MXNET_SERVE_SPEC_TOKENS"))
         if self.k < 1:
             raise MXNetError("speculative decoding needs k >= 1")
+        for m in (model, draft_model):
+            require_kv_only(
+                m, "speculative decoding (SpeculativeGenerator)",
+                "a rejected proposal rolls a row's position back, which a "
+                "K/V ring forgives and a state that has already advanced "
+                "does not (no rollback without state snapshots)")
         if multistep is None:
             multistep = bool(config.get("MXNET_SERVE_MULTISTEP"))
         self._multistep = bool(multistep)

@@ -62,6 +62,7 @@ import numpy as _onp
 
 from ..base import MXNetError
 from .engine import PoolExhausted, block_context
+from .generate import CacheLayout
 
 
 def resolve_page_size(page_size, max_seq):
@@ -94,8 +95,14 @@ class PagedKVPool:
 
     Parameters
     ----------
-    model : block with ``_blocks[i].attention`` KV geometry (same duck
-        type :class:`~.generate.KVCache.alloc` reads).
+    model : block with a ``cache_spec()``: the same description
+        (:class:`~.generate.CacheLayout`) that
+        :meth:`~.generate.KVCache.alloc` builds rings from. K/V is paged;
+        a layer's recurrent state, fixed in size whatever the sequence's
+        length, is one row a slot beside the pages (``num_slots`` rows,
+        never paged, no null row: the step keeps a dead lane's row as it
+        is and zeroes a row itself when its request starts, see the
+        recurrent-state ops of ``ops/nn.py``).
     num_slots : fixed decode width — page-table rows (the trace-static
         slot lattice of the continuous-batching step).
     max_seq : logical ring length per slot (page table width =
@@ -130,29 +137,17 @@ class PagedKVPool:
             raise MXNetError(
                 f"PagedKVPool needs >= 2 pages (1 null + 1 usable), got "
                 f"{self.num_pages}")
-        if quant not in (None, "int8"):
-            raise MXNetError(f"unknown PagedKVPool quant {quant!r}")
+        self.layout = CacheLayout(model, quant)
         self.quant = quant
-        # one (P, KV, page, D) k/v pool pair per layer; int8 adds the
-        # (P, KV, page) f32 scale pools — interleaved in flat() exactly
-        # like KVCache.flat() so _CacheForward's calling convention is
-        # shared between ring and paged steps
+        # a layer at a time, in the layout's flat order (KVCache.flat()'s
+        # too, so _CacheForward's calling convention is shared between
+        # ring and paged steps): (P, KV, page, D) k/v pools, with
+        # (P, KV, page) f32 scale pools on the int8 rung, then the
+        # layer's (num_slots, ...) state rows
         self.ctx = block_context(model)
-        zeros = functools.partial(mnp.zeros, ctx=self.ctx)
-        self._arrays = []
-        for blk in model._blocks:
-            attn = blk.attention
-            shape = (self.num_pages, attn._kv_heads, self.page_size,
-                     attn._head_dim)
-            if quant == "int8":
-                self._arrays.extend((
-                    zeros(shape, dtype="int8"),
-                    zeros(shape[:3], dtype="float32"),
-                    zeros(shape, dtype="int8"),
-                    zeros(shape[:3], dtype="float32")))
-            else:
-                self._arrays.extend((zeros(shape, dtype="float32"),
-                                     zeros(shape, dtype="float32")))
+        self._arrays = self.layout.alloc(
+            functools.partial(mnp.zeros, ctx=self.ctx), self.num_pages,
+            self.page_size, self.num_slots)
         # host allocator state: LIFO free list (hot pages recycle first),
         # per-slot owned pages, the canonical page-table matrix
         self._lock = threading.Lock()
@@ -370,8 +365,15 @@ class PagedKVPool:
         return self.pages_total - len(self._free)
 
     def nbytes(self):
+        """Bytes of everything the pool holds on the device: the pages
+        and the state rows."""
         return sum(int(_onp.prod(a.shape)) * _onp.dtype(a.dtype).itemsize
                    for a in self._arrays)
+
+    def state_nbytes(self):
+        """The state rows' part of :meth:`nbytes` (0 for a model that
+        keeps K/V alone)."""
+        return self.layout.state_nbytes(self._arrays)
 
     def stats(self):
         with self._lock:
@@ -386,4 +388,5 @@ class PagedKVPool:
                 "pages_shared": shared,
                 "high_water": self.high_water,
                 "exhausted_count": self.exhausted_count,
-                "nbytes": self.nbytes()}
+                "nbytes": self.nbytes(),
+                "state_nbytes": self.state_nbytes()}

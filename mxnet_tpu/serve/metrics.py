@@ -82,6 +82,7 @@ class ServeMetrics:
         # decode-rung gauges (tentpole PR 10): footprint of the pooled KV
         # rings and which decode path this generator traced
         self.kv_cache_bytes = 0
+        self.state_pool_bytes = 0
         self.decode_path = None
         # continuous-batching telemetry (tentpole PR 12): streaming SLOs
         # (time-to-first-token, inter-token latency) plus the paged-KV and
@@ -320,13 +321,18 @@ class ServeMetrics:
             _prof.set_counter(f"serve.queue_depth({self.name})", int(depth),
                               cat="serve")
 
-    def set_kv_cache_bytes(self, nbytes):
-        """Gauge: total bytes of the generator's pooled KV-cache rings
-        (``KVCache.nbytes()`` summed over the warm batch buckets)."""
+    def set_kv_cache_bytes(self, nbytes, state=0):
+        """Gauges: total bytes of the cache a server holds on the device
+        (``KVCache.nbytes()`` summed over the warm batch buckets, or the
+        page pool's), and ``state``, the part of it that is recurrent
+        state (one row a slot; 0 for a model that keeps K/V alone)."""
         self.kv_cache_bytes = int(nbytes)
+        self.state_pool_bytes = int(state)
         if _prof.ENABLED:
             _prof.set_counter(f"serve.kv_cache_bytes({self.name})",
                               int(nbytes), cat="serve")
+            _prof.set_counter(f"serve.state_pool_bytes({self.name})",
+                              int(state), cat="serve")
 
     def set_decode_path(self, path):
         """Gauge: the decode rung this generator compiled
@@ -398,6 +404,7 @@ class ServeMetrics:
                 "rate_limited": self.rate_limited,
                 "swaps": self.swaps,
                 "kv_cache_bytes": self.kv_cache_bytes,
+                "state_pool_bytes": self.state_pool_bytes,
                 "decode_path": self.decode_path,
                 "kv_pages_used": self.kv_pages_used,
                 "kv_pages_free": self.kv_pages_free,

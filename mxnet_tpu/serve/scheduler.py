@@ -52,16 +52,17 @@ import numpy as _onp
 
 from ..base import MXNetError
 from ..profiler import attribution as _attr
+from ..profiler import core as _prof
 from ..profiler import trace as _trace
 from ..profiler.core import host_span
 from ..resilience import faults as _faults
 from .batcher import DynamicBatcher
 from .engine import DeadlineExceeded, InferenceSession, PoolExhausted, \
     ServeError, ServiceUnavailable, on_block_context
-from .generate import _CacheForward, _MultiStepForward, _STOP_WIDTH, \
-    _fresh_key_bits, _int8_weights_enabled, _quantize_serving_weights, \
-    _stop_matrix, resolve_decode_path, sample_tokens
-from ..ops import nn as _ops
+from .generate import PREFIX_CACHE_NEEDS, _CacheForward, _MultiStepForward, \
+    _STOP_WIDTH, _fresh_key_bits, _int8_weights_enabled, \
+    _quantize_serving_weights, _stop_matrix, gather_rings, require_kv_only, \
+    resolve_decode_path, sample_tokens, scatter_rings
 from .kv_blocks import PagedKVPool
 from .prefix_cache import PrefixCache
 
@@ -123,8 +124,15 @@ class ContinuousEngine:
 
     Parameters
     ----------
-    model : LlamaModel (same duck type :class:`~.generate.Generator`
-        serves).
+    model : what :class:`~.generate.Generator` serves: a block with a
+        ``cache=``/``start_pos=`` forward and a ``cache_spec()`` saying
+        what each layer keeps between steps (K/V rows, which are paged,
+        and recurrent state, which is one row a slot). With recurrent
+        state the step keeps the lane contract (a dead lane's state
+        comes back as it went in, a padded prefill position does not
+        advance it, a request starts from zero), and ``prefix_cache``
+        and ``multistep`` raise: neither can be done to a state without
+        snapshots of it.
     max_seq : per-request logical ring length (prompt + generated tokens
         must fit); must be a whole number of KV pages.
     num_slots : decode lanes — the ONE compiled decode width
@@ -175,6 +183,8 @@ class ContinuousEngine:
         # _admit can skip the matched portion of chunked prefill
         if prefix_cache is None:
             prefix_cache = bool(config.get("MXNET_SERVE_PREFIX_CACHE"))
+        if prefix_cache:
+            require_kv_only(model, *PREFIX_CACHE_NEEDS)
         self.prefix = (PrefixCache(self.pool, name=f"{name}_prefix")
                        if prefix_cache else None)
         # fast rungs fuse the paging brackets into the step executable;
@@ -195,7 +205,8 @@ class ContinuousEngine:
         self.ctx = self.session.ctx  # the model's device: inputs go there
         self.metrics = self.session.metrics
         self.metrics.set_decode_path(self.decode_path)
-        self.metrics.set_kv_cache_bytes(self.pool.nbytes())
+        self.metrics.set_kv_cache_bytes(self.pool.nbytes(),
+                                        state=self.pool.state_nbytes())
         # the admission queue: PR-6 semantics intact, flusher OFF — the
         # scheduler consumes via take()/settle_one() between decode steps
         self._batcher = DynamicBatcher(
@@ -377,31 +388,44 @@ class ContinuousEngine:
                 raise
             return self.pool.assign_with_prefix(i, budget, pages)
 
-    def _run_step(self, tokens, start_pos, last_idx, table):
+    def _run_step(self, tokens, start_pos, last_idx, table, lanes):
+        """One call of the step executable. ``lanes`` names, for each
+        row of the call, the slot whose recurrent state it reads and
+        writes, -1 for a row that is not live (an empty slot, a slot
+        still prefilling while its neighbours decode): the step hands
+        such a row's state back untouched. Only a model that keeps
+        recurrent state is given it."""
         from .. import numpy as mnp
 
+        stateful = self.pool.layout.has_state
         with host_span("mxnet_tpu.serve.to_device"):
             toks = mnp.array(_onp.asarray(tokens, _onp.int32))
             sp = mnp.array(_onp.asarray(start_pos, _onp.int32))
             li = mnp.array(_onp.asarray(last_idx, _onp.int32))
             tab = mnp.array(_onp.asarray(table, _onp.int32))
+            ln = ([mnp.array(_onp.asarray(lanes, _onp.int32))]
+                  if stateful else [])
         with host_span("mxnet_tpu.serve.dispatch"):
             if self._fused_paged:
-                out = self.session.run(toks, sp, li, tab,
+                out = self.session.run(toks, sp, li, tab, *ln,
                                        *self.pool.flat(), *self._qflat)
                 flat = out[1:]
             else:
                 # strict rung: paging brackets as standalone exact-copy
                 # ops around the unchanged ring executable (bitwise
                 # contract)
-                rings = [_ops.paged_kv_gather(p, tab)
-                         for p in self.pool.flat()]
-                out = self.session.run(toks, sp, li, *rings, *self._qflat)
-                t_len = _onp.asarray(tokens).shape[1]
-                flat = [_ops.paged_kv_scatter(p, tab, r, sp, t_len)
-                        for p, r in zip(self.pool.flat(), out[1:])]
+                layout = self.pool.layout
+                rings = gather_rings(layout, self.pool.flat(), tab)
+                out = self.session.run(toks, sp, li, *ln, *rings,
+                                       *self._qflat)
+                flat = scatter_rings(layout, self.pool.flat(), tab, out[1:],
+                                     sp, toks.shape[1])
         with host_span("mxnet_tpu.serve.pool_update"):
+            # the pages' and the state rows' swap alike
             self.pool.update_from_flat(flat)
+        if stateful:
+            live = int((_onp.asarray(lanes) >= 0).sum())
+            _prof.incr_counter("serve.state_lane_steps", live, cat="serve")
         return out[0]
 
     def _prefill_once(self):
@@ -432,8 +456,13 @@ class ContinuousEngine:
             with _attr.phase_scope("prefill"):
                 p0_ns = time.perf_counter_ns()
                 try:
+                    if s.consumed == 0 and self.pool.layout.has_state:
+                        # the step zeroes the lane's state itself, on
+                        # start_pos 0: whatever its last tenant left
+                        _prof.incr_counter("serve.state_resets",
+                                           cat="serve")
                     logits = self._run_step(toks, [s.consumed], [n - 1],
-                                            table)
+                                            table, [i])
                 except Exception as e:
                     pf_args["error"] = type(e).__name__
                     raise
@@ -466,7 +495,10 @@ class ContinuousEngine:
         that are empty or still prefilling ride along as dead lanes:
         all-null page-table rows route their writes to the null page
         (re-zeroed in the scatter op), so they can neither corrupt live
-        state nor feed garbage back to themselves."""
+        state nor feed garbage back to themselves. A recurrent state has
+        no null page: a dead lane's ``lanes`` entry is -1 and the step
+        returns its state row bit for bit (a slot in the middle of its
+        prefill keeps what its chunks have built)."""
         decoding = [i for i, s in enumerate(self._slots)
                     if s is not None and s.decoding and not s.finished]
         if not decoding:
@@ -486,12 +518,14 @@ class ContinuousEngine:
             S = self.num_slots
             toks = _onp.zeros((S, 1), _onp.int32)
             pos = _onp.zeros(S, _onp.int32)
+            lanes = _onp.full(S, -1, _onp.int32)
             table = _onp.zeros((S, self.pool.pages_per_slot), _onp.int32)
             live_table = self.pool.table()
             for i in decoding:
                 s = self._slots[i]
                 toks[i, 0] = s.pending
                 pos[i] = s.pos
+                lanes[i] = i
                 table[i] = live_table[i]
             temps = [self._slots[i].temperature for i in decoding]
         # the iteration's four-way attribution (host/dispatch/device/
@@ -508,7 +542,8 @@ class ContinuousEngine:
             s0_ns = time.perf_counter_ns()
             try:
                 logits = self._run_step(toks, pos,
-                                        _onp.zeros(S, _onp.int32), table)
+                                        _onp.zeros(S, _onp.int32), table,
+                                        lanes)
                 t2 = time.perf_counter()
                 w2 = _attr.thread_wait_ns() if attributing else 0
                 with host_span("mxnet_tpu.serve.sample"):
@@ -807,8 +842,6 @@ class ContinuousEngine:
         return not self._live() and self._batcher.queue_depth() == 0
 
     def _run_loop(self):
-        from ..profiler import core as _prof
-
         _prof.register_thread_name()
         while not self._stop.is_set():
             if self._idle():
@@ -832,9 +865,10 @@ class ContinuousEngine:
         t0 = time.perf_counter()
         n = self.pool.pages_per_slot
         S = self.num_slots
+        # warm-up rows are not live: they leave the state rows alone
         self._run_step(
             _onp.zeros((1, self.prefill_chunk), _onp.int32), [0], [0],
-            _onp.zeros((1, n), _onp.int32))
+            _onp.zeros((1, n), _onp.int32), [-1])
         if self._multistep:
             # remaining=0: zero runtime iterations, full trace/compile
             self._run_multi(
@@ -849,7 +883,7 @@ class ContinuousEngine:
                 _onp.zeros((S, 1), _onp.int32),
                 _onp.zeros(S, _onp.int32),
                 _onp.zeros(S, _onp.int32),
-                _onp.zeros((S, n), _onp.int32))
+                _onp.zeros((S, n), _onp.int32), _onp.full(S, -1))
         self.session.freeze_signatures()
         sigs = self.session.signature_count()
         if self._msession is not None:
@@ -910,6 +944,9 @@ class ContinuousEngine:
     def stats(self):
         out = self.session.stats()
         out["pool"] = self.pool.stats()
+        out["state_pool_bytes"] = self.pool.state_nbytes()
+        out["state_bytes_per_lane"] = (self.pool.state_nbytes()
+                                       // self.num_slots)
         out["steps"] = self._steps
         if self._msession is not None:
             out["multistep"] = self._msession.stats()
