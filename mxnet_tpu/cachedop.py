@@ -60,12 +60,12 @@ def cache_stats():
     (plus the instance count and the persistent compile cache's
     disk_hits/disk_misses — see :mod:`mxnet_tpu.compile_cache`)."""
     agg = {"instances": 0, "hits": 0, "misses": 0, "signatures": 0,
-           "serve_hits": 0, "compile_ms": 0.0}
+           "serve_hits": 0, "compile_ms": 0.0, "fast_calls": 0,
+           "keys_skipped": 0}
     for op in list(_instances):
         s = op.cache_stats()
         agg["instances"] += 1
-        for k in ("hits", "misses", "signatures", "serve_hits",
-                  "compile_ms"):
+        for k in s:
             agg[k] += s[k]
     from . import compile_cache as _cc
 
@@ -178,21 +178,22 @@ class CachedOp:
         self._bwd_cache = {}
         # telemetry (always maintained — int increments on an already-
         # expensive path): per-instance cache traffic + compile wall time
-        self._hits = 0
-        self._misses = 0
+        self._hits = self._misses = self._serve_hits = 0
+        self._fast_calls = self._keys_skipped = 0
         self._compile_ns = 0
         self._storm_warned = False
-        self._serve_hits = 0
         self._call_tls = threading.local()
         _instances.add(self)
 
     def cache_stats(self):
-        """Signature-cache telemetry: hits/misses/signatures/compile time
-        (plus ``serve_hits``, the warm calls issued through
-        ``mxnet_tpu.serve`` — see :meth:`record_serve_hit`)."""
+        """Signature-cache telemetry: hits/misses/signatures/compile time,
+        ``serve_hits`` (warm calls issued through ``mxnet_tpu.serve``),
+        ``fast_calls`` (warm calls that derived nothing anew from the
+        model), ``keys_skipped`` (calls whose program reads no RNG key)."""
         return {"hits": self._hits, "misses": self._misses,
                 "signatures": len(self._cache),
-                "serve_hits": self._serve_hits,
+                "serve_hits": self._serve_hits, "fast_calls": self._fast_calls,
+                "keys_skipped": self._keys_skipped,
                 "compile_ms": self._compile_ns / 1e6}
 
     def signature_count(self) -> int:
@@ -307,10 +308,10 @@ class CachedOp:
             build, site=f"CachedOp::compile({type(self.block).__name__})",
             policy=_retry.compile_policy())
 
-    def _write_back_state(self, state_params, new_states):
-        """Write back mutated state (BatchNorm running stats etc.)."""
-        for p, ns in zip(state_params, new_states):
-            arr = p.data()
+    def _write_back_state(self, state_handles, new_states):
+        """Write back mutated state (BatchNorm running stats etc.); a
+        state the forward left alone came back as None."""
+        for arr, ns in zip(state_handles, new_states):
             if ns is not None and arr._data is not ns:
                 arr._set_data_internal(ns)
 
@@ -320,21 +321,20 @@ class CachedOp:
         state = [p for p in params if p.grad_req == "null"]
         return train, state
 
-    @staticmethod
-    def _sig_of(datas):
-        return tuple((tuple(d.shape), str(d.dtype)) for d in datas)
-
-    def _key(self, arg_datas, grad_mode, args_tracked, static_args):
-        train, state = self._split_params()
-        return (
-            self._sig_of(arg_datas),
-            self._sig_of([p.data()._data for p in train]),
-            self._sig_of([p.data()._data for p in state]),
-            autograd.is_training(),
-            grad_mode,
-            tuple(args_tracked),
-            static_args,
-        )
+    def _params(self, recording):
+        """The block's parameters as this call needs them, and whether
+        they had to be derived anew (see :class:`_ParamSnapshot`)."""
+        store = _snapshots  # before any read: see params_changed()
+        snap = store.get(self)
+        scoped = getattr(_trace_state, "replica_ctx", None) is not None
+        fresh = snap is None or scoped or not snap.stands()
+        if fresh:
+            snap = self._take_snapshot()
+        if recording or scoped or not snap.plain:
+            store.pop(self, None)
+        elif fresh:
+            store[self] = snap
+        return snap, fresh
 
     def _build(self, key, grad_mode, args_tracked, static_args):
         import jax
@@ -364,7 +364,7 @@ class CachedOp:
                 finally:
                     autograd.set_training(prev_train)
                     autograd.set_recording(prev_rec)
-                    _rng.pop_trace_rng()
+                    out_tree_box["draws"] = _rng.pop_trace_rng().counter
                 # only a state the forward rebound (BatchNorm running
                 # stats) is an output. An untouched one would come back
                 # as its own input tracer, and XLA copies a passed-through
@@ -425,18 +425,31 @@ class CachedOp:
             "fwd": fwd_jit,
             "bwd": bwd_jit,
             "out_tree": out_tree_box,
-            "train_params": train_params,
-            "state_params": state_params,
             "diff_arg_idx": diff_arg_idx,
         }
 
-    def _read_param_datas(self, entry):
-        """Snapshot the raw param buffers for one call. A hook so the
-        thread-safe subclass can exclude this read from trace windows
-        (an active trace rebinds the SHARED Parameter NDArrays to
-        tracers; a concurrent reader would leak them into its own jit)."""
-        return (tuple(p.data()._data for p in entry["train_params"]),
-                tuple(p.data()._data for p in entry["state_params"]))
+    @staticmethod
+    def _sig_of(datas):
+        return tuple((tuple(d.shape), str(d.dtype)) for d in datas)
+
+    def _take_snapshot(self):
+        """Walk the block and read every parameter's buffer: the per-call
+        work of old, now done when :meth:`_params` finds no snapshot that
+        stands. A hook so the thread-safe subclass can exclude this read
+        from trace windows (an active trace rebinds the SHARED Parameter
+        NDArrays to tracers; a concurrent reader would leak them into its
+        own jit)."""
+        train, state = self._split_params()
+        handles = [p.data() for p in train + state]
+        # versions before buffers: a rebind in between must read as stale
+        versions = tuple([h._version for h in handles])
+        return _ParamSnapshot(len(train), handles, versions,
+                              tuple(h._data for h in handles))
+
+    def _run_fwd(self, entry, snap, rng_key, arg_datas):
+        """Call the entry's executable (a hook for the thread-safe
+        subclass, whose first call of a jax signature holds a lock)."""
+        return entry["fwd"](snap.tp_datas, snap.st_datas, rng_key, *arg_datas)
 
     # -- call -------------------------------------------------------------
     def __call__(self, *args):
@@ -481,18 +494,33 @@ class CachedOp:
             _tracked(a) for a in traced_args
         ) if grad_mode else tuple(False for _ in traced_args)
 
-        key = self._key(arg_datas, grad_mode, args_tracked, static_args)
-        entry = self._lookup_or_build(key, grad_mode, args_tracked,
-                                      static_args)
+        snap, fresh = self._params(grad_mode)
+        key = (self._sig_of(arg_datas), snap.train_sig, snap.state_sig,
+               autograd.is_training(), grad_mode, args_tracked, static_args)
+        entry = self._cache.get(key)
+        if entry is None:
+            entry = self._lookup_or_build(key, grad_mode, args_tracked,
+                                          static_args)
+        else:
+            self._hits += 1
+            if not fresh:
+                self._fast_calls += 1
 
-        train_params = entry["train_params"]
-        state_params = entry["state_params"]
-        tp_datas, st_datas = self._read_param_datas(entry)
-        rng_key = _rng.next_key()
+        # an entry whose trace drew no key is handed the key of its first
+        # call again (the key stays an argument: the HLO does not change)
+        # and the stream's counters advance as if one had been made, so
+        # every later seeded draw in the process is what it was
+        rng_key = entry.get("free_key")
+        drew = rng_key is None
+        if drew:
+            rng_key = _rng.next_key()
+        else:
+            _rng.skip_key()
+            self._keys_skipped += 1
 
         t0 = _prof.begin() if _prof.ENABLED else 0
-        out_datas, new_states, vjp = entry["fwd"](tp_datas, st_datas, rng_key,
-                                                  *arg_datas)
+        out_datas, new_states, vjp = self._run_fwd(entry, snap, rng_key,
+                                                   arg_datas)
         if t0:
             # host-side dispatch window (XLA executes async; device time
             # comes from profiler.device_op_stats)
@@ -500,7 +528,10 @@ class CachedOp:
                 f"CachedOp::forward({type(self.block).__name__})",
                 "cachedop", t0)
 
-        self._write_back_state(state_params, new_states)
+        if (drew and entry["out_tree"].get("draws") == 0
+                and not _rng.in_trace()):  # an outer trace's key is a tracer
+            entry["free_key"] = rng_key
+        self._write_back_state(snap.state_handles, new_states)
 
         wrapped = [NDArray(d) for d in out_datas]
 
@@ -519,7 +550,7 @@ class CachedOp:
                 arg_grads = grads[1:]
                 return tuple(param_grads) + tuple(arg_grads)
 
-            in_slots = [_slot_of(p.data()) for p in train_params]
+            in_slots = [_slot_of(h) for h in snap.train_handles]
             in_slots += [_slot_of(traced_args[i]) for i in diff_arg_idx]
             node = autograd.TapeNode(
                 vjp_fn,
@@ -536,6 +567,64 @@ class CachedOp:
         import jax
 
         return jax.tree_util.tree_unflatten(tree, wrapped)
+
+
+# CachedOp -> its _ParamSnapshot (weak keys: the store never pins an
+# executor, nor a dead one's weights)
+_snapshots = weakref.WeakKeyDictionary()
+
+
+def params_changed():
+    """Throw every CachedOp's parameter snapshot away. ``gluon`` calls it
+    AFTER any change to what ``block.collect_params()`` or
+    ``Parameter.data()`` resolve to (a parameter initialised, moved, cast,
+    loaded, its ``grad_req`` set; a child or parameter registered on a
+    Block). The whole store is replaced, and a call takes hold of the store
+    before it reads a parameter, so a snapshot derived while a change was
+    under way lands in a store nobody reads. Also frees, at once, the
+    buffers the snapshots held. A buffer rebound through its NDArray handle
+    (an optimizer step, a written-back state) needs no call: the handle's
+    ``_version`` says it."""
+    global _snapshots
+    _snapshots = weakref.WeakKeyDictionary()
+
+
+def _weak_of(datas):
+    """The positions among ``datas`` whose jax type is weak."""
+    return tuple(i for i, d in enumerate(datas)
+                 if getattr(d, "weak_type", False))
+
+
+class _ParamSnapshot:
+    """What a call needs of the block's parameters, a function of the
+    model alone and so derived once, not per call: the train/state split,
+    the handles ``Parameter.data()`` resolved to, their buffers, the
+    parameter half of the signature key. It stands until
+    :func:`params_changed` throws it away or a handle is rebound. A
+    recording call leaves none behind: its weights are about to be
+    stepped, and the snapshot would keep the old buffers alive beside the
+    new ones. Nor does a call inside a ``replica_context``, where
+    ``Parameter.data()`` resolves by the thread's scope."""
+
+    __slots__ = ("handles", "versions", "train_handles", "state_handles",
+                 "tp_datas", "st_datas", "train_sig", "state_sig", "weak",
+                 "plain")
+
+    def __init__(self, n, handles, versions, datas):
+        """``n``: how many of ``handles`` (and ``datas``) are trainable;
+        the rest are state."""
+        self.handles, self.versions = handles, versions
+        self.train_handles, self.state_handles = handles[:n], handles[n:]
+        self.tp_datas, self.st_datas = datas[:n], datas[n:]
+        self.train_sig = CachedOp._sig_of(self.tp_datas)
+        self.state_sig = CachedOp._sig_of(self.st_datas)
+        self.weak = _weak_of(datas)
+        # reading a view resyncs it and moves its version: never kept
+        self.plain = all(getattr(h, "_view_parent", None) is None
+                         for h in handles)
+
+    def stands(self):
+        return tuple([h._version for h in self.handles]) == self.versions
 
 
 class CachedOpThreadSafe(CachedOp):
@@ -579,55 +668,42 @@ class CachedOpThreadSafe(CachedOp):
             if entry is None:
                 entry = super()._lookup_or_build(
                     key, grad_mode, args_tracked, static_args)
-                self._guard_first_call(entry)
             else:
                 # raced build won while we waited: still a cache hit for
                 # cache_stats accounting
                 self._hits += 1
             return entry
 
-    def _guard_first_call(self, entry):
+    def _run_fwd(self, entry, snap, rng_key, arg_datas):
         """jax.jit traces on FIRST INVOCATION PER JAX SIGNATURE, and the
         trace rebinds the shared Parameter NDArrays to tracers
         (_ParamBinding); a concurrent p.data() read would leak them (the
         round-4 cold-start probe: 4 unwarmed threads ->
-        UnexpectedTracerError). Any call whose jax-level signature —
-        shape/dtype AND weak_type, which the CachedOp cache key does NOT
-        capture (jnp scalars are weak) — hasn't completed yet holds the
-        process-wide ``_TRACE_LOCK`` (the rebinding hits every op that
-        shares the params, not just this one); known-signature calls run
-        lock-free."""
-        import jax
+        UnexpectedTracerError). Any call whose jax-level signature hasn't
+        completed yet holds the process-wide ``_TRACE_LOCK`` (the
+        rebinding hits every op that shares the params, not just this
+        one); known-signature calls run lock-free. The entry's key already
+        says every shape and dtype; what it does NOT capture is weak_type
+        (jnp scalars are weak), so that is all a call adds: the explicit
+        arguments' here, the parameters' from the snapshot."""
+        sig = (snap.weak, _weak_of(arg_datas))
+        traced = entry.get("traced")
+        if traced is None:
+            traced = entry.setdefault("traced", set())
+        if sig in traced:
+            return super()._run_fwd(entry, snap, rng_key, arg_datas)
+        with CachedOpThreadSafe._TRACE_LOCK:
+            out = super()._run_fwd(entry, snap, rng_key, arg_datas)
+            traced.add(sig)
+            return out
 
-        raw = entry["fwd"]
-        seen = set()
-
-        def sig_of(args):
-            return tuple(
-                (getattr(x, "shape", None), str(getattr(x, "dtype", type(x))),
-                 bool(getattr(x, "weak_type", False)))
-                for x in jax.tree_util.tree_leaves(args))
-
-        def guarded(*a):
-            s = sig_of(a)
-            if s in seen:
-                return raw(*a)
-            with CachedOpThreadSafe._TRACE_LOCK:
-                out = raw(*a)
-                seen.add(s)
-                return out
-
-        entry["fwd"] = guarded
-
-    def _read_param_datas(self, entry):
+    def _take_snapshot(self):
         # excluded from trace windows: the class trace lock is held by
         # any in-flight first-call trace of ANY op over these params
-        # (see _guard_first_call)
         with CachedOpThreadSafe._TRACE_LOCK:
-            return super()._read_param_datas(entry)
+            return super()._take_snapshot()
 
-    def _write_back_state(self, state_params, new_states):
-        if not state_params:
-            return
-        with self._lock:
-            super()._write_back_state(state_params, new_states)
+    def _write_back_state(self, state_handles, new_states):
+        if any(ns is not None for ns in new_states):
+            with self._lock:
+                super()._write_back_state(state_handles, new_states)

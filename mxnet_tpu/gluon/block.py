@@ -20,7 +20,7 @@ from collections import OrderedDict
 
 from .. import autograd
 from ..base import MXNetError
-from ..cachedop import CachedOp, in_trace
+from ..cachedop import CachedOp, in_trace, params_changed
 from ..device import Context, cpu, current_context
 from ..ndarray.ndarray import NDArray
 from .parameter import Parameter, ParameterDict
@@ -42,11 +42,13 @@ class Block:
             existing = self.__dict__.get("_children")
             if existing is not None:
                 existing[name] = value
+                params_changed()
         elif isinstance(value, Parameter):
             reg = self.__dict__.get("_reg_params")
             if reg is not None:
                 reg[name] = value
                 value._structure = (self, name)
+                params_changed()
         super().__setattr__(name, value)
 
     def register_child(self, block, name=None):
@@ -54,6 +56,7 @@ class Block:
             name = str(len(self._children))
         self._children[name] = block
         super().__setattr__(f"_child_{name}", block)
+        params_changed()
         return block
 
     def register_forward_hook(self, hook):
@@ -400,6 +403,7 @@ def _register_param_arrays(block, param_arrays):
         block._reg_params[f"p{i}"] = p
         object.__setattr__(block, f"p{i}", p)
         out[name] = p
+    params_changed()
     return out
 
 

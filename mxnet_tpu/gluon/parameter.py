@@ -15,22 +15,19 @@ TPU redesign notes:
 """
 from __future__ import annotations
 
-import threading as _threading
 from collections import OrderedDict
 
 import numpy as _onp
 
 from .. import autograd, initializer as _init_mod
 from ..base import MXNetError
+from ..cachedop import _trace_state, params_changed
 from ..device import Context, cpu, current_context
 from ..ndarray.ndarray import NDArray
 
 
 class DeferredInitializationError(MXNetError):
     """Parameter accessed before its shape is fully known."""
-
-
-_REPLICA = _threading.local()
 
 
 class replica_context:
@@ -53,17 +50,20 @@ class replica_context:
         self._prev = None
 
     def __enter__(self):
-        self._prev = getattr(_REPLICA, "ctx", None)
-        _REPLICA.ctx = self._ctx
+        # on CachedOp's thread-local: its parameter snapshot holds what
+        # ``data()`` resolves to outside any scope, and has to see a scope
+        # to step aside for it
+        self._prev = getattr(_trace_state, "replica_ctx", None)
+        _trace_state.replica_ctx = self._ctx
         return self._ctx
 
     def __exit__(self, *exc):
-        _REPLICA.ctx = self._prev
+        _trace_state.replica_ctx = self._prev
         return False
 
 
 def _active_replica_ctx():
-    return getattr(_REPLICA, "ctx", None)
+    return getattr(_trace_state, "replica_ctx", None)
 
 
 def _shape_complete(shape):
@@ -149,6 +149,7 @@ class Parameter:
                     arr._leaf = None
         elif self._data is not None:
             self._init_grad()
+        params_changed()  # the train/state split of every CachedOp over it
 
     # -- initialization ---------------------------------------------------
     def initialize(self, init=None, ctx=None, default_init=None, force_reinit=False):
@@ -195,6 +196,7 @@ class Parameter:
         self._deferred_init = None
         if self._grad_req != "null":
             self._init_grad()
+        params_changed()  # new handles (a deferred init finishes here too)
 
     def _finish_deferred_init(self):
         if self._deferred_init is None:
@@ -308,6 +310,10 @@ class Parameter:
                 jax.device_put(val.astype(arr.dtype) if val.dtype != arr.dtype else val,
                                ctx.jax_device()),
                 keep_tape=False)
+        # the handles' versions say it too, but only at a CachedOp's next
+        # call: until then its snapshot would keep the old buffers alive
+        # beside the new ones, a whole model loaded twice
+        params_changed()
 
     def zero_grad(self):
         if self._grad is None:
@@ -337,6 +343,7 @@ class Parameter:
             self._ctx_list = list(ctx)
             if self._grad_req != "null":
                 self._init_grad()
+            params_changed()
         elif self._deferred_init is not None:
             init, _ = self._deferred_init
             self._deferred_init = (init, list(ctx))
@@ -351,6 +358,7 @@ class Parameter:
             for ctx, g in self._grad.items():
                 g._set_data_internal(g._data.astype(dtype))
                 autograd.mark_variables([self._data[ctx]], [g], self._grad_req)
+        params_changed()
 
     # row_sparse API parity ------------------------------------------------
     def row_sparse_data(self, row_id):

@@ -96,6 +96,20 @@ def next_key():
     return _global_state.next_key()
 
 
+def skip_key():
+    """Advance the active stream exactly as :func:`next_key` does, without
+    making the key: for a caller that knows its compiled program reads
+    none (a CachedOp entry whose trace drew no key). The counters move,
+    so every later draw of every seeded stream is what it would have
+    been; the eager ``fold_in`` (a dispatch of its own) is not paid."""
+    global _consume_count
+    _consume_count += 1
+    if _trace_stack.stack:
+        _trace_stack.stack[-1].counter += 1
+    else:
+        _global_state._counter += 1
+
+
 def probe_marks():
     """Snapshot for :func:`rewind_probe`: (consume_count, state counter)."""
     return _consume_count, _global_state._counter
@@ -138,8 +152,10 @@ def push_trace_rng(base_key) -> TraceRNG:
     return rng
 
 
-def pop_trace_rng():
-    _trace_stack.stack.pop()
+def pop_trace_rng() -> TraceRNG:
+    """Pop and return the trace RNG: its ``counter`` says how many keys
+    the traced code drew."""
+    return _trace_stack.stack.pop()
 
 
 def in_trace() -> bool:

@@ -948,9 +948,15 @@ class ContinuousEngine:
         out["state_bytes_per_lane"] = (self.pool.state_nbytes()
                                        // self.num_slots)
         out["steps"] = self._steps
+        caches = [out["cache"]]
         if self._msession is not None:
             out["multistep"] = self._msession.stats()
             out["decode_steps"] = self.decode_steps
+            caches.append(out["multistep"]["cache"])
+        # warm calls of the step executables that derived nothing anew
+        # from the model, and those that made no RNG key (CachedOp)
+        for k in ("fast_calls", "keys_skipped"):
+            out[k] = sum(c[k] for c in caches)
         out["slots_live"] = len(self._live())
         out["slots_total"] = self.num_slots
         out["admit_wait_steps_max"] = self._admit_wait_max
