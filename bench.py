@@ -910,90 +910,6 @@ def bench_lenet_eager():
     })
 
 
-def bench_lenet_eager_bulk():
-    """Eager LeNet training under ``engine.bulk(16)`` — deferred eager
-    dispatch collapses ~tens of per-op dispatches per step into one
-    compiled segment executable per flush (fwd segment + segment vjp at
-    backward). The dispatches_per_step columns quantify the collapse;
-    each dispatch costs one round-trip (see rtt_ms), so the ratio
-    bounds the win the next real-TPU round should measure."""
-    import numpy as onp
-
-    import mxnet_tpu as mx
-    from mxnet_tpu import autograd, engine, gluon
-    from mxnet_tpu import np as mnp
-
-    BATCH = 64
-    BULK = 16
-    try:
-        ctx = mx.tpu()
-        ctx.jax_device()
-    except Exception:
-        ctx = mx.cpu()
-    net = gluon.nn.HybridSequential()
-    net.add(gluon.nn.Conv2D(6, 5, activation="relu"), gluon.nn.MaxPool2D(2),
-            gluon.nn.Conv2D(16, 5, activation="relu"), gluon.nn.MaxPool2D(2),
-            gluon.nn.Flatten(), gluon.nn.Dense(120, activation="relu"),
-            gluon.nn.Dense(84, activation="relu"), gluon.nn.Dense(10))
-    net.initialize(ctx=ctx)
-    x = mnp.array(onp.random.randn(BATCH, 1, 28, 28).astype("float32"),
-                  ctx=ctx)
-    y = mnp.array(onp.random.randint(0, 10, (BATCH,)), ctx=ctx)
-    with autograd.predict_mode():
-        net(x)
-    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
-    tr = gluon.Trainer(net.collect_params(), "sgd", {"learning_rate": 0.05})
-
-    def step_bulk():
-        with engine.bulk(BULK):
-            with autograd.record():
-                l = loss_fn(net(x), y).mean()
-            l.backward()
-            tr.step(1)
-            return l
-
-    def step_plain():
-        # pin deferral OFF: this is the honest unbulked comparison arm
-        # even when MXNET_ENGINE_BULK_SIZE is set globally
-        prev = engine.set_bulk_size(0)
-        try:
-            with autograd.record():
-                l = loss_fn(net(x), y).mean()
-            l.backward()
-            tr.step(1)
-            return l
-        finally:
-            engine.set_bulk_size(prev)
-
-    def dispatches(step):
-        float(step().asnumpy())
-        before = engine.dispatch_count()
-        float(step().asnumpy())
-        return engine.dispatch_count() - before
-
-    for _ in range(3):
-        float(step_bulk().asnumpy())  # compile the segment executables
-    dt = _timed_diff(step_bulk, lambda l: float(l.asnumpy()), 3, 18)
-    d_bulk = dispatches(step_bulk)
-    d_plain = dispatches(step_plain)
-    stats = engine.bulk_stats(reset=True)
-    return _emit({
-        "metric": "lenet_eager_train_bs64_bulk16",
-        "value": round(BATCH / dt, 2),
-        "unit": "img/s",
-        "vs_baseline": None,
-        "dispatches_per_step": d_bulk,
-        "dispatches_per_step_unbulked": d_plain,
-        "dispatch_collapse": round(d_plain / max(d_bulk, 1), 1),
-        "ops_per_flush": round(stats["ops_per_flush"], 1),
-        "seg_cache_hit_rate": round(
-            stats["cache_hits"] /
-            max(stats["cache_hits"] + stats["cache_misses"], 1), 3),
-        **_dispatch_meta(),
-        **_spread(invert_for=BATCH),
-    })
-
-
 def bench_trace_overhead():
     """Observability cost contract (OBSERVABILITY.md): the eager LeNet
     microloop under the production-default stack — profiler hooks
@@ -2002,7 +1918,6 @@ def main():
                      ("collective_overlap", bench_collective_overlap),
                      ("lenet_eager", bench_lenet_eager),
                      ("trace_overhead", bench_trace_overhead),
-                     ("lenet_eager_bulk16", bench_lenet_eager_bulk),
                      ("bert", bench_bert_train),
                      ("bert_fused", bench_bert_train_fused),
                      ("llama_decode", bench_llama_decode),

@@ -298,10 +298,6 @@ def _run_backward(heads, head_grads, retain_graph, create_graph=False):
     from . import engine
     from .ndarray.ndarray import NDArray
 
-    # tape boundary: any pending bulk segment must flush BEFORE the walk —
-    # it installs the segment tape nodes the heads' _tape links point at
-    engine.flush_current("tape")
-
     def lift(x):
         return NDArray(x) if create_graph and not isinstance(x, NDArray) else x
 
@@ -473,12 +469,8 @@ class Function:
         raise NotImplementedError
 
     def __call__(self, *inputs):
-        from . import engine
         from .ndarray.ndarray import NDArray, _tracked, _slot_of
 
-        # custom Functions capture input tape slots eagerly — pending bulk
-        # segments must install their tape nodes first
-        engine.flush_current("tape")
         with pause():
             outputs = self.forward(*inputs)
         single = not isinstance(outputs, (list, tuple))

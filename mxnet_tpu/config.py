@@ -79,25 +79,6 @@ register_flag(
     "Cache one jax.jit executable per (op, static config) for imperative "
     "dispatch (SURVEY §7 hard part 2). 0 disables.", _bool)
 register_flag(
-    "MXNET_ENGINE_BULK_SIZE", 0,
-    "Default per-thread bulk-execution segment size for deferred eager "
-    "dispatch (engine.bulk() analog, reference engine.h:311-317). > 1: "
-    "imperative ops record into a pending segment flushed as ONE compiled "
-    "executable (one dispatch) at N ops / materialization / wait points "
-    "/ tape boundaries. 0 (default) dispatches per op; NaiveEngine forces "
-    "per-op synchronous semantics regardless.", int)
-register_flag(
-    "MXNET_ENGINE_BULK_FUSE", False,
-    "Let XLA fuse across the ops of a bulk segment. Default off: per-op "
-    "optimization barriers keep bulk-vs-unbulked numerics bitwise "
-    "identical (the RTT win comes from batched dispatch, not fusion); "
-    "on trades last-ulp reduction drift for less memory traffic.", _bool)
-register_flag(
-    "MXNET_ENGINE_SEG_CACHE_MAX", 512,
-    "Segment-executable cache entries above which the deferred-dispatch "
-    "caches are cleared (same clear-don't-evict runaway guard as the "
-    "eager per-op jit cache).", int)
-register_flag(
     "MXNET_WAITALL_FULL", False,
     "mx.npx.waitall() sweeps every live array (exhaustive, slow) instead "
     "of the recently-dispatched set.", _bool)

@@ -32,17 +32,9 @@ def _jnp():
 
 
 def _tracked(a) -> bool:
-    if not isinstance(a, NDArray):
-        return False
-    if getattr(a, "_tape", None) is not None \
-            or getattr(a, "_leaf", None) is not None:
-        return True
-    # a pending deferred output of a recorded op is tracked even though its
-    # tape link only materializes at segment flush (engine._Segment);
-    # sparse subclasses store no _buf — getattr, not attribute access
-    buf = getattr(a, "_buf", None)
-    return type(buf) is engine._LazyRef and buf.seg is not None \
-        and buf.tainted
+    return isinstance(a, NDArray) and (
+        getattr(a, "_tape", None) is not None
+        or getattr(a, "_leaf", None) is not None)
 
 
 def _slot_of(a):
@@ -132,39 +124,8 @@ class NDArray:
     # immutable, so views are modeled as (parent, key) linkage with lazy
     # resync: reads refresh from the parent when its version moved, and
     # rebinds push the updated region back up the parent chain.
-    @classmethod
-    def _from_lazy(cls, ref):
-        """Wrap a deferred-dispatch placeholder (``engine._LazyRef``) —
-        the bulk-segment recorder's output handle. Materializes on first
-        ``_data`` access; shape/dtype answer from the recorded aval."""
-        self = cls.__new__(cls)
-        self._view_parent = None
-        self._view_key = None
-        self._view_pver = 0
-        self._buf = ref
-        self._tape = None
-        self._leaf = None
-        self._version = 0
-        self._stype = "default"
-        return self
-
-    def _lazy_or_data(self):
-        """The raw buffer WITHOUT forcing a pending bulk segment (lazy
-        placeholder passes through); concrete buffers resync views."""
-        buf = getattr(self, "_buf", None)  # sparse subclasses: no _buf
-        if getattr(self, "_view_parent", None) is None \
-                and type(buf) is engine._LazyRef:
-            return buf
-        return self._data
-
     @property
     def _data(self):
-        buf = self._buf
-        if type(buf) is engine._LazyRef:
-            # deferred bulk-segment output: materialize (flushes the
-            # segment); lazy buffers are never views, so no resync needed
-            self._buf = buf = buf.force()
-            return buf
         p = getattr(self, "_view_parent", None)
         if p is not None:
             src = p._data  # refresh the whole parent chain first
@@ -190,17 +151,7 @@ class NDArray:
 
     # -- mutation core ----------------------------------------------------
     def _set_data_internal(self, new_data, keep_tape=False):
-        """Rebind the buffer (engine Var version bump analog). Accepts a
-        lazy bulk-segment placeholder: the handle stays deferred (no
-        flush) and the placeholder's tape-wiring owner is repointed here
-        so the segment's flush tapes THIS handle, not the spent temp."""
-        if type(new_data) is engine._LazyRef:
-            if self._view_parent is not None:
-                new_data = new_data.force()  # view write-back needs values
-            else:
-                import weakref as _weakref
-
-                new_data.owner = _weakref.ref(self)
+        """Rebind the buffer (engine Var version bump analog)."""
         self._data = new_data
         self._version += 1
         if not keep_tape:
@@ -223,15 +174,8 @@ class NDArray:
             self._view_pver = p._version  # buffer already current
 
     # -- basic properties -------------------------------------------------
-    # shape/dtype/size/ndim peek the recorded aval of a deferred (lazy)
-    # buffer without flushing its segment — shape-dependent Python in the
-    # framework (gluon infer-shape, reshape legacy values) must not defeat
-    # bulking. Anything value-dependent still flushes via `_data`.
     @property
     def shape(self):
-        buf = self._buf
-        if type(buf) is engine._LazyRef:
-            return buf.shape
         return tuple(self._data.shape)
 
     @shape.setter
@@ -243,7 +187,7 @@ class NDArray:
             # rebind (mirrors the recording branch of __setitem__)
             res = _apply(lambda x: x.reshape(new_shape), (self,),
                          name="reshape")
-            self._set_data_internal(res._lazy_or_data(), keep_tape=True)
+            self._set_data_internal(res._data, keep_tape=True)
             self._tape = res._tape
             return
         key = None if getattr(self, "_view_parent", None) is None \
@@ -263,31 +207,19 @@ class NDArray:
 
     @property
     def dtype(self):
-        buf = self._buf
-        if type(buf) is engine._LazyRef:
-            return _np.dtype(buf.dtype)
         return self._data.dtype
 
     @property
     def size(self):
-        buf = self._buf
-        if type(buf) is engine._LazyRef:
-            n = 1
-            for d in buf.shape:
-                n *= int(d)
-            return n
         return int(self._data.size)
 
     @property
     def ndim(self):
-        buf = self._buf
-        if type(buf) is engine._LazyRef:
-            return len(buf.shape)
         return self._data.ndim
 
     @property
     def itemsize(self):
-        return self.dtype.itemsize  # aval peek: no flush on lazy buffers
+        return self.dtype.itemsize
 
     @property
     def nbytes(self):
@@ -485,7 +417,6 @@ class NDArray:
         jkey = self._prep_index(key)
         res = _apply(lambda x: x[jkey], (self,), name="getitem")
         if type(self) is NDArray and not autograd.is_recording() \
-                and type(res._buf) is not engine._LazyRef \
                 and self._is_contiguous_basic(jkey, self.shape):
             res._view_parent = self
             res._view_key = jkey
@@ -502,7 +433,7 @@ class NDArray:
                 (self, value),
                 name="setitem",
             )
-            self._set_data_internal(res._lazy_or_data(), keep_tape=True)
+            self._set_data_internal(res._data, keep_tape=True)
             self._tape = res._tape
             return
         val = value._data if isinstance(value, NDArray) else value
@@ -683,11 +614,10 @@ class NDArray:
     def __invert__(self):
         return _apply(_jnp().invert, (self,), name="invert")
 
-    # in-place ops rebind (recording-safe: produces a new tape entry);
-    # a deferred result rebinds lazily — no segment flush on `+=`
+    # in-place ops rebind (recording-safe: produces a new tape entry)
     def _inplace(self, other, fn, name):
         res = self._binop(other, fn, name)
-        self._set_data_internal(res._lazy_or_data(), keep_tape=True)
+        self._set_data_internal(res._data, keep_tape=True)
         self._tape = res._tape
         return self
 
@@ -750,12 +680,8 @@ class NDArray:
     # -- shape ops --------------------------------------------------------
     def _link_reshape_view(self, res):
         """Reference reshape/flatten/expand_dims share memory with the
-        source (``MXNDArrayReshape64``); link as a whole-array view.
-        Deferred (lazy) results are never linked: inside a bulk segment
-        the recording-path copy semantics apply — aliasing is traded for
-        batched dispatch."""
-        if type(self) is NDArray and not autograd.is_recording() \
-                and type(res._buf) is not engine._LazyRef:
+        source (``MXNDArrayReshape64``); link as a whole-array view."""
+        if type(self) is NDArray and not autograd.is_recording():
             res._view_parent = self
             res._view_key = None
             res._view_pver = self._version

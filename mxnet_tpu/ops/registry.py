@@ -55,14 +55,6 @@ _EAGER_JIT_MAX = 4096  # runaway guard: clear rather than evict
 _EAGER_JIT_CLEARS = 0  # how often the runaway guard wiped the cache
 _eager_jit_enabled = os.environ.get("MXNET_EAGER_JIT_CACHE", "1") != "0"
 
-# deferred-dispatch aval cache: (op key, input avals) -> either
-# ("ok", flat_avals, single, was_list) or ("nodefer",) for ops whose
-# abstract trace consumed RNG or failed (value-dependent Python) — those
-# always take the direct dispatch path.  Bounded by _EAGER_JIT_MAX with
-# the same clear-don't-evict discipline.
-_AVAL_CACHE: Dict[tuple, tuple] = {}
-_AVAL_CLEARS = 0  # runaway-guard wipes of the aval cache (cache_stats)
-
 
 def set_eager_jit(flag: bool) -> None:
     """Enable/disable the eager per-op jit cache (MXNET_EAGER_JIT_CACHE)."""
@@ -84,30 +76,25 @@ def cache_stats():
             "bwd_size": len(_EAGER_BWD_CACHE),
             "skips": len(_EAGER_JIT_SKIP),
             "clears": _EAGER_JIT_CLEARS,
-            "aval_size": len(_AVAL_CACHE),
-            "aval_clears": _AVAL_CLEARS,
             "limit": _EAGER_JIT_MAX}
 
 
-def _note_cache_clear(what="eager jit cache", counter="eager_jit_clears",
-                      count=1, limit=None):
-    """Account (and rate-limitedly warn about) a runaway-guard cache
-    clear — previously silent, so cache-thrash regressions in BENCH
-    rounds were unattributable. Shared by the per-op jit cache and the
-    deferred-dispatch aval cache; returns the new clear count."""
+def _note_cache_clear(count):
+    """Account (and rate-limitedly warn about) a runaway-guard clear of
+    the eager jit cache — previously silent, so cache-thrash regressions
+    in BENCH rounds were unattributable."""
     prof = _PROF
     if prof is not None:
-        prof.set_counter(f"registry.{counter}", count, cat="registry")
+        prof.set_counter("registry.eager_jit_clears", count, cat="registry")
     if count == 1 or count % 10 == 0:
         import warnings
 
         warnings.warn(
-            f"{what} hit its {limit or _EAGER_JIT_MAX}-entry runaway "
+            f"eager jit cache hit its {_EAGER_JIT_MAX}-entry runaway "
             f"guard and was cleared (clear #{count}); something is "
             f"generating unbounded distinct op signatures (varying "
             f"shapes/static args) and re-paying compiles — see "
             f"registry.cache_stats()", RuntimeWarning, stacklevel=3)
-    return count
 
 
 def _static_key(v, depth=0):
@@ -130,8 +117,7 @@ def _static_key(v, depth=0):
             _static_key(x, depth + 1) for x in v)
     if isinstance(v, slice):
         # unhashable before Python 3.12 — without this branch every basic
-        # __getitem__/__setitem__ closure (jkey) is uncacheable AND
-        # undeferrable, fragmenting bulk segments back to per-op dispatch
+        # __getitem__/__setitem__ closure (jkey) is uncacheable
         return ("slice", _static_key(v.start, depth + 1),
                 _static_key(v.stop, depth + 1),
                 _static_key(v.step, depth + 1))
@@ -267,25 +253,10 @@ def _make_cached_vjp(inner_fn, datas, key):
     return vjp_fn
 
 
-_NOT_DEFERRED = object()  # sentinel: _maybe_defer declined, dispatch directly
-_KEY_ERR = object()       # sentinel: static key is unhashable (TypeError)
-
-_TRACER_CLS = None
-
-
-def _jax_tracer():
-    global _TRACER_CLS
-    if _TRACER_CLS is None:
-        import jax.core
-
-        _TRACER_CLS = jax.core.Tracer
-    return _TRACER_CLS
-
-
 def _op_static_key(fn, args, kwargs, arr_pos, static_key):
-    """The (op, static config) identity used by both the per-op jit cache
-    and the deferred-dispatch recorder. Raises TypeError for unhashable
-    static config (array-valued kwargs etc.)."""
+    """The (op, static config) identity used by the per-op jit cache.
+    Raises TypeError for unhashable static config (array-valued kwargs
+    etc.)."""
     if static_key is not None:
         return static_key
     pos_set = set(arr_pos)
@@ -297,168 +268,6 @@ def _op_static_key(fn, args, kwargs, arr_pos, static_key):
               if i not in pos_set),
         _static_key(kwargs),
     )
-
-
-def _maybe_defer(fn, args, kwargs, name, record, sync_outputs, cacheable,
-                 static_key, arr_pos, arrays, NDArray, size):
-    """Record the call into the thread's pending bulk segment instead of
-    dispatching. Returns ``(result, key)``: lazy-handle NDArrays, or
-    ``_NOT_DEFERRED`` when the op must dispatch directly (flushing the
-    segment first, so program order is preserved across the deferral
-    boundary). ``key`` is the computed static key (``None`` if never
-    computed, ``_KEY_ERR`` if unhashable) — apply's jit-cache block
-    reuses it instead of walking the closure twice."""
-    import weakref
-
-    _eng = engine
-    if not sync_outputs or not cacheable:
-        # tape-replay internals (create_graph) and explicitly uncacheable
-        # calls: correctness first — flush and dispatch directly
-        _eng.flush_current("undeferrable")
-        return _NOT_DEFERRED, None
-    try:
-        key = _op_static_key(fn, args, kwargs, arr_pos, static_key)
-    except TypeError:
-        _eng.flush_current("undeferrable")
-        return _NOT_DEFERRED, _KEY_ERR
-    if key in _EAGER_JIT_SKIP:
-        # known jit-incompatible / RNG-consuming op: never defer
-        _eng.flush_current("undeferrable")
-        return _NOT_DEFERRED, key
-
-    if arr_pos and len(arr_pos) == len(args) and not kwargs:
-        closed = fn
-    else:
-        template = list(args)
-
-        def closed(*xs):
-            for pos, x in zip(arr_pos, xs):
-                template[pos] = x
-            return fn(*template, **kwargs)
-
-    from ..ndarray.ndarray import _tracked
-
-    rec_on = record and autograd.is_recording()
-    for _attempt in (0, 1, 2, 3):
-        seg = _eng._segment_for_record(size)
-        ins = []
-        tracked_flags = []
-        reflush = False
-        for a in arrays:
-            # getattr: sparse subclasses store indices+values, no _buf slot
-            buf = getattr(a, "_buf", None) \
-                if getattr(a, "_view_parent", None) is None else None
-            if type(buf) is _eng._LazyRef and buf.value is None \
-                    and buf.err is None and buf.seg is seg:
-                if rec_on and getattr(a, "_leaf", None) is not None:
-                    # a LEAF handle whose value is still a pending lazy
-                    # (deferred `w -= ...`): unbulked semantics route the
-                    # gradient to the leaf slot, NOT through the deferred
-                    # update chain — flush, then record it as a concrete
-                    # tracked external input
-                    reflush = True
-                    break
-                ins.append(buf)
-                tracked_flags.append(buf.tainted or _tracked(a))
-            else:
-                # concrete (or foreign-segment / failed lazy: _data forces
-                # and surfaces the error exactly like a materialization)
-                d = a._data
-                if isinstance(d, _jax_tracer()):
-                    # inside someone's trace (hybridize/cachedop): the
-                    # tracer must flow through THAT trace — recording it
-                    # into a host segment would leak it. Dispatch
-                    # directly, no flush.
-                    return _NOT_DEFERRED, key
-                ins.append(d)
-                tracked_flags.append(_tracked(a))
-        if reflush:
-            _eng.flush_current("tape")
-            continue
-        if seg.done:
-            # scanning an input forced THIS segment to flush (a view over
-            # a lazy parent, a shared handle materialized mid-scan): the
-            # captured segment can't record anymore — restart on a fresh
-            # one (inputs are concrete now, so this converges)
-            continue
-        akey = (key, tuple((tuple(x.shape), str(x.dtype)) for x in ins))
-        try:
-            info = _AVAL_CACHE.get(akey)
-        except TypeError:
-            info = ("nodefer",)
-        if info is None:
-            info = _infer_avals(closed, ins, akey)
-        if info[0] != "ok":
-            _eng.flush_current("undeferrable")
-            return _NOT_DEFERRED, key
-        _, flat_avals, single, was_list = info
-        recording = rec_on and any(tracked_flags)
-        refs = seg.record(closed, key, ins, arrays, tracked_flags,
-                          flat_avals, single, was_list, recording, name)
-        if refs is not None:
-            break
-        # None: a cross-thread materialization flushed the segment between
-        # the scan and the record — restart on a fresh segment
-    else:
-        # pathologically unstable: dispatch directly
-        return _NOT_DEFERRED, key
-
-    wrapped = []
-    for r in refs:
-        w = NDArray._from_lazy(r)
-        r.owner = weakref.ref(w)
-        wrapped.append(w)
-    if len(seg.ops) >= seg.size:
-        seg.flush("size")
-    if single:
-        return wrapped[0], key
-    return (wrapped if was_list else tuple(wrapped)), key
-
-
-def _infer_avals(closed, ins, akey):
-    """Abstract-trace ``closed`` (jax.eval_shape) to learn output
-    structure without dispatching; detects RNG consumption (those ops are
-    never deferred — a cached segment trace would bake their keys)."""
-    import jax
-
-    from .. import random as _rng
-
-    specs = [jax.ShapeDtypeStruct(tuple(x.shape), x.dtype) for x in ins]
-    marks = _rng.probe_marks()
-    mark = marks[0]
-    try:
-        out = jax.eval_shape(closed, *specs)
-    except Exception:
-        _rng.rewind_probe(marks)
-        info = ("nodefer",)
-    else:
-        if _rng.consume_count() != mark:
-            # the probe burned real keys tracing an RNG op: un-draw them
-            # so seeded streams match a bulk-disabled run exactly
-            _rng.rewind_probe(marks)
-            info = ("nodefer",)
-        else:
-            single = not isinstance(out, (tuple, list))
-            was_list = isinstance(out, list)
-            flat = [out] if single else list(out)
-            if any(not hasattr(o, "shape") or not hasattr(o, "dtype")
-                   for o in flat):
-                info = ("nodefer",)  # non-array outputs: dispatch directly
-            else:
-                info = ("ok",
-                        tuple((tuple(o.shape), o.dtype) for o in flat),
-                        single, was_list)
-    if len(_AVAL_CACHE) >= _EAGER_JIT_MAX:
-        # a wiped aval cache re-pays one eval_shape per bulked op until
-        # it refills — same attributability discipline as the jit cache
-        global _AVAL_CLEARS
-
-        _AVAL_CACHE.clear()
-        _AVAL_CLEARS += 1
-        _note_cache_clear("deferred-dispatch aval cache",
-                          "aval_cache_clears", _AVAL_CLEARS)
-    _AVAL_CACHE[akey] = info
-    return info
 
 
 def apply(fn, args, kwargs=None, name="", record=True, sync_outputs=True,
@@ -488,19 +297,6 @@ def apply(fn, args, kwargs=None, name="", record=True, sync_outputs=True,
     arr_pos = [i for i, a in enumerate(args) if isinstance(a, NDArray)]
     arrays = [args[i] for i in arr_pos]
 
-    op_key = None  # static key computed by the defer fork, reused below
-    if engine._BULK_POSSIBLE:
-        # deferred eager dispatch (engine bulk segments): record instead
-        # of dispatching when a segment is open and the op is deferrable.
-        # The op:dispatch fault site fires per recorded op at flush.
-        bulk_n = engine._active_bulk_size()
-        if bulk_n > 1:
-            deferred, op_key = _maybe_defer(
-                fn, args, kwargs, name, record, sync_outputs, cacheable,
-                static_key, arr_pos, arrays, NDArray, bulk_n)
-            if deferred is not _NOT_DEFERRED:
-                return deferred
-
     flt = _FAULTS
     if flt is not None:
         # injected transient dispatch error (resilience.faults): raised
@@ -526,10 +322,9 @@ def apply(fn, args, kwargs=None, name="", record=True, sync_outputs=True,
     cache_candidate = None
     rng_mark = 0
     jit_hit_key = None  # verified-cacheable op: fast fwd AND cached-vjp bwd
-    if _eager_jit_enabled and cacheable and op_key is not _KEY_ERR:
+    if _eager_jit_enabled and cacheable:
         try:
-            key = op_key if op_key is not None \
-                else _op_static_key(fn, args, kwargs, arr_pos, static_key)
+            key = _op_static_key(fn, args, kwargs, arr_pos, static_key)
             if key not in _EAGER_JIT_SKIP:
                 jitted = _EAGER_JIT_CACHE.get(key)
                 if jitted is not None:
@@ -611,7 +406,7 @@ def apply(fn, args, kwargs=None, name="", record=True, sync_outputs=True,
                 _EAGER_JIT_CACHE.clear()
                 _EAGER_BWD_CACHE.clear()
                 _EAGER_JIT_CLEARS += 1
-                _note_cache_clear(count=_EAGER_JIT_CLEARS)
+                _note_cache_clear(_EAGER_JIT_CLEARS)
             _EAGER_JIT_CACHE[cache_key] = cache_candidate
         else:
             _EAGER_JIT_SKIP.add(cache_key)
@@ -657,8 +452,8 @@ def apply_out(fn, args, kwargs=None, out=None, name=""):
         return res
     if isinstance(out, (tuple, list)):
         for o, r in zip(out, res):
-            o._set_data_internal(r._lazy_or_data())
+            o._set_data_internal(r._data)
         return out
-    out._set_data_internal(res._lazy_or_data())
+    out._set_data_internal(res._data)
     out._tape = getattr(res, "_tape", None)
     return out

@@ -8,11 +8,7 @@ Three layers:
   chrome://tracing export (:func:`dump`) and the aggregate table
   (:func:`dumps`). Instrumentation hooks in ``cachedop.py`` (compile
   timing, cache hit/miss, recompile-storm warning), ``engine.py`` (wait
-  stalls, async queue depth, and the deferred-dispatch segment counters:
-  ``engine::bulk_flush`` ranges with reason/op-count args, the
-  ``engine.bulk_flushes`` / ``engine.bulk_segment_ops`` gauges —
-  cumulative totals incl. the flush-reason histogram and segment-cache
-  hit rate live in ``engine.bulk_stats()``), ``kvstore/dist_tpu.py``
+  stalls, async queue depth, bulk sizes), ``kvstore/dist_tpu.py``
   (allreduce timing/bytes, AOT-compile split) and ``ops/registry.py``
   (per-op call counters under ``profile_imperative``) feed it. All hooks
   are near-zero-cost while stopped: a module-level bool guard per site.

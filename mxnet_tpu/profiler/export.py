@@ -1,6 +1,6 @@
 """Unified telemetry export: every subsystem's counters behind ONE
-``snapshot()`` — profiler aggregates/counters, ``engine`` dispatch/bulk
-stats, ``cachedop.cache_stats()``, ``kvstore.dist_tpu
+``snapshot()`` — profiler aggregates/counters, ``engine`` dispatch
+count, ``cachedop.cache_stats()``, ``kvstore.dist_tpu
 .collective_stats()``, the ``resilience.*`` counters, per-instance
 ``ServeMetrics`` percentiles/goodput, per-replica straggler gauges, and
 the flight-recorder/trace bookkeeping — flattened into a single
@@ -89,7 +89,6 @@ def snapshot(include_aggregates=True):
     eng = sys.modules.get("mxnet_tpu.engine")
     if eng is not None:
         out["engine.dispatches"] = eng.dispatch_count()
-        _flatten("engine.bulk", eng.bulk_stats(), out)
 
     cop = sys.modules.get("mxnet_tpu.cachedop")
     if cop is not None:

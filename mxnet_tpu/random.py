@@ -110,21 +110,6 @@ def skip_key():
         _global_state._counter += 1
 
 
-def probe_marks():
-    """Snapshot for :func:`rewind_probe`: (consume_count, state counter)."""
-    return _consume_count, _global_state._counter
-
-
-def rewind_probe(marks):
-    """Undo key draws made by an abstract probe (the deferred-dispatch
-    recorder's ``jax.eval_shape`` pass in ``ops/registry._infer_avals``):
-    the probe traces the op body host-side, so an RNG op draws a real key
-    there — without the rewind, every seeded random stream would shift by
-    one draw per probed RNG-op signature vs a bulk-disabled run."""
-    global _consume_count
-    _consume_count, _global_state._counter = marks
-
-
 def as_threefry(key):
     """Derive a threefry2x32 key from any PRNG key.
 

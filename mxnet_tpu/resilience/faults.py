@@ -122,11 +122,7 @@ from . import counters as _counters
 # Sites wired in this PR (documented; fault_point accepts any name so new
 # sites need no registry change):
 KNOWN_SITES = (
-    "op:dispatch",          # ops/registry.apply, before the op executes;
-                            # under engine bulking (deferred dispatch) it
-                            # fires once per RECORDED op at segment flush
-                            # — the async boundary where the error then
-                            # surfaces (engine._Segment._execute)
+    "op:dispatch",          # ops/registry.apply, before the op executes
     "cachedop:compile",     # cachedop._lookup_or_build cache miss
     "kvstore:allreduce",    # dist_tpu fast-path collective body
     "kvstore:allreduce_compile",  # dist_tpu AOT lower().compile()

@@ -1,7 +1,7 @@
 """Runtime lock-order sanitizer (``MXNET_LOCKDEP=1``).
 
 The serving/training stack holds ~22 lock sites (batcher flushers, the
-Router supervisor, hedge timers, engine segments, the ContinuousEngine);
+Router supervisor, hedge timers, the ContinuousEngine);
 their ordering discipline is a convention nothing enforces at runtime.
 This module is the dynamic half of the PR-13 gate (the static half is
 ``tools/mxlint`` rule L001): :func:`enable` replaces the

@@ -986,14 +986,8 @@ def fromjson(text):
         if name:
             op_sym.name = name
         if op in _LAYER_INPUTS:
-            # aux-ness is not serialized in nnvm JSON — it derives from
-            # the op's input slots (reference FListAuxiliaryStates)
-            slots, aux_slots = _LAYER_INPUTS[op]
-            n_main = len(slots)
-            for j, a in enumerate(args[n_main:], start=n_main):
-                if isinstance(a, Symbol) and a._op is None and \
-                        j - n_main < len(aux_slots):
-                    a.attr["__aux__"] = "true"
+            # aux-ness is not serialized in nnvm JSON
+            _mark_aux_vars(op, args)
         built.append(op_sym)
     heads = data.get("heads", [[len(built) - 1, 0, 0]])
     if len(heads) != 1:
@@ -1070,6 +1064,19 @@ _LAYER_INPUTS = {
 }
 
 
+def _mark_aux_vars(op_name, args):
+    """A variable an op consumes in an auxiliary slot is an auxiliary
+    state, whoever made it (reference ``FListAuxiliaryStates``: aux-ness
+    derives from the op's input slots): ``list_auxiliary_states()`` lists
+    it and ``list_arguments()`` does not. No op with auxiliary slots has
+    an optional ``bias``, so the slots' positions are fixed."""
+    slots, aux_slots = _LAYER_INPUTS[op_name]
+    n_main = len(slots)
+    for a in args[n_main:n_main + len(aux_slots)]:
+        if isinstance(a, Symbol) and a._op is None:
+            a.attr["__aux__"] = "true"
+
+
 def _auto_input_vars(op_name, resolved_name, args, kwargs):
     """Fill missing tensor inputs with auto-named variables."""
     slots, aux_slots = _LAYER_INPUTS[op_name]
@@ -1083,10 +1090,8 @@ def _auto_input_vars(op_name, resolved_name, args, kwargs):
         if slot in kwargs:
             filled.append(kwargs.pop(slot))
             continue
-        v = Symbol(None, (), {}, name=f"{resolved_name}_{slot}")
-        if slot in aux_slots:
-            v.attr["__aux__"] = "true"
-        filled.append(v)
+        filled.append(Symbol(None, (), {}, name=f"{resolved_name}_{slot}"))
+    _mark_aux_vars(op_name, filled)
     return tuple(filled), kwargs
 
 

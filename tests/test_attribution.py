@@ -3,8 +3,8 @@ four-way phase ledger (host / dispatch / device / wait partitioning each
 ``serve::decode_step`` span), phase-tagged ``engine:wait`` accounting,
 per-request ``attribution.report(trace_id)`` over a live
 ContinuousEngine, the ``ServeMetrics`` ``(ms, live)`` ITL pairs +
-attribution gauges, and the <5% disabled-path overhead contract."""
-import time
+attribution gauges, and the disabled path's cost contract (no call
+added, nothing recorded)."""
 
 import numpy as np
 import pytest
@@ -204,45 +204,52 @@ def test_disabled_engine_records_nothing():
     assert rep["decode_steps"] > 0 and rep["ledger_steps"] == 0
 
 
-# -- overhead bound ----------------------------------------------------------
+# -- the disabled path's cost ------------------------------------------------
 
 
-@pytest.mark.serial
-def test_disabled_attribution_overhead_under_5pct():
+def test_disabled_attribution_adds_no_call_and_records_nothing():
     """Eager microloop with the attribution slot installed but ENABLED
-    False must stay within 5% of the slot-removed baseline — the same
-    cost contract as the profiler/trace hooks."""
+    False: the same cost contract as the profiler/trace hooks, held as a
+    count and a state (ROADMAP D9: a CPU timing is not a gate). The loop
+    makes not one Python or C call more than with the slot removed, and
+    no wait is recorded."""
+    import sys
+
     from mxnet_tpu import engine
 
     x = mnp.ones((4,))
 
-    def loop(n=10_000):
+    def loop(n=500):
         y = x
-        t0 = time.perf_counter()
         for _ in range(n):
             y = y + 1.0
         y.wait_to_read()
-        return time.perf_counter() - t0
+
+    def calls(fn):
+        seen = [0]
+
+        def count(_frame, event, _arg):
+            if event in ("call", "c_call"):
+                seen[0] += 1
+
+        sys.setprofile(count)
+        try:
+            fn()
+        finally:
+            sys.setprofile(None)
+        return seen[0]
 
     saved = engine._ATTR
-
-    def measure(rounds=7):
-        base = hooked = float("inf")
-        for _ in range(rounds):
-            engine._ATTR = None
-            base = min(base, loop())
-            attribution._install_engine_slot()
-            attribution.disable()  # slot present, ledger off
-            hooked = min(hooked, loop())
-        return base, hooked
-
     try:
-        loop(2000)  # warm caches before either arm
-        base, hooked = measure()
-        if hooked > base * 1.05:  # timing noise: one clean re-measure
-            base, hooked = measure(rounds=9)
+        loop(200)  # warm caches before either arm
+        engine._ATTR = None
+        base = calls(loop)
+        attribution._install_engine_slot()
+        attribution.disable()  # slot present, ledger off
+        attribution.reset()
+        hooked = calls(loop)
+        assert base > 500 and hooked == base, (base, hooked)
+        assert attribution.wait_ms_by_phase() == {}
+        assert attribution.thread_wait_ns() == 0
     finally:
         engine._ATTR = saved
-    assert hooked <= base * 1.05, (
-        f"disabled attribution overhead {hooked / base - 1:.1%} "
-        f"(baseline {base:.3f}s, hooked {hooked:.3f}s)")

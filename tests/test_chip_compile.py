@@ -1,4 +1,4 @@
-"""The main path's Pallas kernels, compiled for a described TPU v5e.
+"""The main path's kernels and serving ops, compiled for a described TPU v5e.
 
 Nothing runs: the chip's own compiler (Mosaic + XLA:TPU, installed with
 libtpu) lowers each kernel at the widths ``chip_smoke.py`` serves and
@@ -17,6 +17,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from mxnet_tpu.models.llama import _LLAMA_CONFIGS
+from mxnet_tpu.ndarray.ndarray import NDArray
+from mxnet_tpu.ops import nn as ops
 from mxnet_tpu.ops.pallas import decode_attention as da
 from mxnet_tpu.ops.pallas import flash_attention as fa
 
@@ -99,6 +101,57 @@ def test_flash_attention_compiles_for_v5e(one_chip, grad):
     assert "tpu_custom_call" in _compile(fn, qkv, qkv, qkv, vl)
 
 
+# falcon_h1_34b's mixer (chipbench/configs/falcon_h1_34b.json): d_ssm 4096
+# in 32 heads of 128, state 256, 2 groups, conv 4 over x, B and C together
+_H, _P, _N, _G, _K, _LANES = 32, 128, 256, 2, 4, 32
+_CONV = _H * _P + 2 * _G * _N
+
+
+def _ssd_scan(b, t):
+    f32, i32 = jnp.float32, jnp.int32
+    args = [((b, t, _H, _P), f32), ((b, t, _H), f32), ((_H,), f32),
+            ((b, t, _G, _N), f32), ((b, t, _G, _N), f32), ((_H,), f32),
+            ((b, _H, _P, _N), f32), ((b,), i32), ((b,), i32), ((b,), bool)]
+    return (lambda *a: ops.ssd_scan(*a, chunk=128), args,
+            [(b, t, _H, _P), (b, _H, _P, _N)])
+
+
+def _causal_conv1d(b, t):
+    f32, i32 = jnp.float32, jnp.int32
+    args = [((b, t, _CONV), f32), ((_CONV, _K), f32), ((_CONV,), f32),
+            ((b, _K - 1, _CONV), f32), ((b,), i32), ((b,), i32), ((b,), bool)]
+    return ops.causal_conv1d, args, [(b, t, _CONV), (b, _K - 1, _CONV)]
+
+
+def _state_rows_scatter():
+    """The (1, chunk) prefill writes its one row back into every lane's."""
+    args = [((_LANES, _H, _P, _N), jnp.float32), ((1,), jnp.int32),
+            ((1, _H, _P, _N), jnp.float32)]
+    return ops.state_rows_scatter, args, [(_LANES, _H, _P, _N)]
+
+
+@pytest.mark.parametrize("case", [
+    lambda: _ssd_scan(1, 128), lambda: _ssd_scan(_LANES, 1),
+    lambda: _causal_conv1d(1, 128), lambda: _causal_conv1d(_LANES, 1),
+    _state_rows_scatter,
+], ids=["ssd_scan-prefill", "ssd_scan-decode", "causal_conv1d-prefill",
+        "causal_conv1d-decode", "state_rows_scatter"])
+def test_recurrent_state_op_compiles_for_v5e(one_chip, case):
+    """The Mamba-2 mixer's serving ops at ``falcon_h1_34b``'s widths, the
+    (1, 128) prefill chunk and the (32, 1) decode step: plain XLA, so what
+    the chip's compiler can refuse is their size and their layouts."""
+    op, args, out_shapes = case()
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in args]
+
+    def fn(*xs):
+        out = op(*(NDArray(x) for x in xs))
+        return [o._data for o in (out if isinstance(out, tuple) else (out,))]
+
+    lowered = jax.jit(fn).lower(*shapes)
+    assert [o.shape for o in lowered.out_info] == out_shapes
+    assert lowered.compile().as_text()
+
+
 def test_dropout_partitions_over_four_chips(topo):
     """A dp-sharded dropout mask under the default ``rbg`` generator. With
     a bare Python probability ``jax_enable_x64`` made it a float64 draw
@@ -109,8 +162,6 @@ def test_dropout_partitions_over_four_chips(topo):
 
     from mxnet_tpu import autograd
     from mxnet_tpu import random as mx_random
-    from mxnet_tpu.ndarray.ndarray import NDArray
-    from mxnet_tpu.ops import nn as ops
 
     mesh = Mesh(np.asarray(topo.devices[:4]), ("dp",))
     x = jax.ShapeDtypeStruct((64, 128, 768), jnp.bfloat16,

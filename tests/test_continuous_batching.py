@@ -116,16 +116,45 @@ class TestScheduler:
             eng.resume()
             eng.assert_no_recompiles()
 
-    def test_deadline_mid_decode_is_504_with_partial(self, net):
-        with _engine(net, num_slots=2, name="cb_dl") as eng:
-            f = eng.submit([9, 9, 9], max_new_tokens=40, deadline_ms=60)
+    def test_deadline_mid_decode_is_504_with_partial(self, net,
+                                                     monkeypatch):
+        """The deadline lapses between two decode steps. The test drives
+        the engine's steps itself and moves the clock the scheduler
+        reads, so it asserts a state and no rate: the host's speed
+        decides nothing."""
+        from mxnet_tpu.serve import scheduler
+
+        class _Clock:
+            """``time`` as the scheduler sees it, ``ahead`` seconds on."""
+            ahead = 0.0
+
+            def monotonic(self):
+                return time.monotonic() + self.ahead
+
+            def __getattr__(self, name):
+                return getattr(time, name)
+
+        clock = _Clock()
+        monkeypatch.setattr(scheduler, "time", clock)
+        eng = _engine(net, num_slots=2, name="cb_dl")
+        try:
+            eng.warmup()
+            f = eng.submit([9, 9, 9], max_new_tokens=40,
+                           deadline_ms=600_000)
+            for _ in range(3):      # admit, prefill, the first tokens
+                eng.step()
+            assert not f.done()
+            clock.ahead = 3600.0    # the budget is gone
+            eng.step()              # retires the lane
             with pytest.raises(DeadlineExceeded) as ei:
-                f.result(timeout=60)
+                f.result(timeout=0)
             assert ei.value.status == 504
             assert 0 < len(ei.value.partial) < 40
             snap = eng.metrics.snapshot()
             assert snap["deadline_expired"].get("decode", 0) >= 1
             eng.assert_no_recompiles()
+        finally:
+            eng.close()
 
     def test_pool_exhaustion_queues_not_crashes(self, net):
         """Undersized pool (pages for ~1 request): admissions beyond

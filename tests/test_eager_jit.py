@@ -1,27 +1,12 @@
 """Eager per-op jit cache (SURVEY §7 hard part 2: the `SetShapeType`
 signature-cache role, done the XLA way — one compiled executable per
 (op, static config), reused across imperative calls)."""
-import contextlib
-
 import numpy as onp
 
 import mxnet_tpu as mx
-from mxnet_tpu import autograd, engine
+from mxnet_tpu import autograd
 from mxnet_tpu import np
 from mxnet_tpu.ops import registry
-
-
-@contextlib.contextmanager
-def _no_bulk():
-    """Pin deferred bulk dispatch off: these tests assert on the PER-OP
-    jit cache, which a bulk segment legitimately bypasses (ops compile
-    through engine._SEG_CACHE instead) — they must stay meaningful under
-    the tier-1 MXNET_ENGINE_BULK_SIZE=16 second pass."""
-    prev = engine.set_bulk_size(0)
-    try:
-        yield
-    finally:
-        engine.set_bulk_size(prev)
 
 
 def _cache_delta(fn, *calls):
@@ -31,32 +16,30 @@ def _cache_delta(fn, *calls):
 
 
 def test_repeat_op_hits_cache():
-    with _no_bulk():
-        a = np.array(onp.random.randn(8, 8).astype("float32"))
-        registry._EAGER_JIT_CACHE.clear()
+    a = np.array(onp.random.randn(8, 8).astype("float32"))
+    registry._EAGER_JIT_CACHE.clear()
+    np.tanh(a)
+    n1 = registry.eager_jit_cache_size()
+    assert n1 >= 1
+    for _ in range(5):
         np.tanh(a)
-        n1 = registry.eager_jit_cache_size()
-        assert n1 >= 1
-        for _ in range(5):
-            np.tanh(a)
-        assert registry.eager_jit_cache_size() == n1  # no growth: hits
-        out = np.tanh(a).asnumpy()
-        onp.testing.assert_allclose(out, onp.tanh(a.asnumpy()), rtol=1e-6)
+    assert registry.eager_jit_cache_size() == n1  # no growth: hits
+    out = np.tanh(a).asnumpy()
+    onp.testing.assert_allclose(out, onp.tanh(a.asnumpy()), rtol=1e-6)
 
 
 def test_distinct_static_config_distinct_entries():
-    with _no_bulk():
-        a = np.array(onp.random.randn(4, 6).astype("float32"))
-        registry._EAGER_JIT_CACHE.clear()
-        s0 = np.sum(a, axis=0)
-        n1 = registry.eager_jit_cache_size()
-        s1 = np.sum(a, axis=1)
-        n2 = registry.eager_jit_cache_size()
-        assert n2 > n1  # axis is static config -> its own executable
-        onp.testing.assert_allclose(s0.asnumpy(), a.asnumpy().sum(0),
-                                    rtol=1e-6)
-        onp.testing.assert_allclose(s1.asnumpy(), a.asnumpy().sum(1),
-                                    rtol=1e-6)
+    a = np.array(onp.random.randn(4, 6).astype("float32"))
+    registry._EAGER_JIT_CACHE.clear()
+    s0 = np.sum(a, axis=0)
+    n1 = registry.eager_jit_cache_size()
+    s1 = np.sum(a, axis=1)
+    n2 = registry.eager_jit_cache_size()
+    assert n2 > n1  # axis is static config -> its own executable
+    onp.testing.assert_allclose(s0.asnumpy(), a.asnumpy().sum(0),
+                                rtol=1e-6)
+    onp.testing.assert_allclose(s1.asnumpy(), a.asnumpy().sum(1),
+                                rtol=1e-6)
 
 
 def test_rng_ops_never_cached_and_stay_random():
@@ -118,11 +101,10 @@ def test_cached_vjp_matches_eager_backward():
         return grads
 
     try:
-        with _no_bulk():
-            cached = run_steps(True)
-            # the cached-vjp path must actually have been exercised
-            assert len(registry._EAGER_BWD_CACHE) > 0
-            eager = run_steps(False)
+        cached = run_steps(True)
+        # the cached-vjp path must actually have been exercised
+        assert len(registry._EAGER_BWD_CACHE) > 0
+        eager = run_steps(False)
     finally:
         registry.set_eager_jit(True)
     for c, e in zip(cached, eager):

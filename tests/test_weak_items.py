@@ -39,6 +39,24 @@ def test_csr_lazy_and_correct():
     onp.testing.assert_array_equal(csr.tostype("default").asnumpy(), want)
 
 
+@pytest.mark.parametrize("stype", ["row_sparse", "csr"])
+def test_sparse_size_metadata_does_not_densify(stype):
+    """``itemsize`` / ``nbytes`` answer from the stored values' dtype and
+    the dense shape, like ``shape`` and ``dtype``: no dense buffer."""
+    if stype == "row_sparse":
+        a = mx.nd.sparse.row_sparse_array(
+            (onp.ones((3, 4), "float32"), onp.array([1, 5, 7], "int64")),
+            shape=(100000, 4))
+    else:
+        a = mx.nd.sparse.csr_matrix(
+            (onp.array([1.0, 2, 3], "float32"),
+             onp.array([0, 2, 1], "int64"), onp.array([0, 2, 3], "int64")),
+            shape=(2, 3))
+    assert a.itemsize == 4
+    assert a.nbytes == a.size * 4
+    assert not a.is_materialized()
+
+
 def test_waitall_bounded_and_correct():
     from mxnet_tpu import engine
 

@@ -23,27 +23,6 @@ timeout -k 10 "$TIMEOUT_S" env JAX_PLATFORMS=cpu \
 rc=${PIPESTATUS[0]}
 echo "DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' "$LOG" | tr -cd . | wc -c)"
 
-# Opt-in second pass (TIER1_BULK=1): re-run the eager-path test files with
-# deferred bulk dispatch force-enabled, so a bulking regression can't hide
-# behind the default-off MXNET_ENGINE_BULK_SIZE knob.
-if [[ "${TIER1_BULK:-0}" != "0" ]]; then
-    BULK_LOG="${TIER1_BULK_LOG:-/tmp/_t1_bulk.log}"
-    rm -f "$BULK_LOG"
-    timeout -k 10 "$TIMEOUT_S" env JAX_PLATFORMS=cpu \
-        MXNET_ENGINE_BULK_SIZE=16 \
-        python -m pytest \
-        tests/test_engine_bulk.py tests/test_eager_jit.py \
-        tests/test_ndarray.py tests/test_autograd.py tests/test_gluon.py \
-        -q -m 'not slow' \
-        --continue-on-collection-errors \
-        -p no:cacheprovider -p no:xdist -p no:randomly \
-        2>&1 | tee "$BULK_LOG"
-    bulk_rc=${PIPESTATUS[0]}
-    echo "BULK_DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' "$BULK_LOG" | tr -cd . | wc -c)"
-    if [[ "$rc" -eq 0 && "$bulk_rc" -ne 0 ]]; then
-        rc=$bulk_rc
-    fi
-fi
 # Serve smoke pass (TIER1_SERVE=0 to skip): one InferenceSession behind a
 # DynamicBatcher, 32 concurrent requests — asserts correct results, a p99
 # latency bound, zero recompiles after warmup, and clean shutdown.
