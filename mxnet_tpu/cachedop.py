@@ -19,7 +19,11 @@ Per input signature (shapes/dtypes/train-mode/grad-mode) we build and cache:
 
 Static buffer reuse, memory planning, and op fusion — the reason the
 reference has ``static_alloc``/``static_shape`` (``cached_op.h:415-436``) —
-are XLA's job; ``static_alloc`` maps to donating the state buffers.
+are XLA's job; ``static_alloc`` maps to donating the state buffers. A block
+may also consume call arguments: ``block.donate_args`` names the positions
+of its call that it takes over and returns (a serving step's cache arrays),
+and those are donated to the executable too. The caller must not read an
+array it passed at such a position again.
 
 Mutable state (BatchNorm running stats, any ``grad_req='null'`` parameter a
 layer rebinds during forward) is handled structurally: state params enter as
@@ -408,7 +412,14 @@ class CachedOp:
                                                list(arg_datas))
                 return out_datas, new_states, None
 
-            donate = (1,) if donate_states else ()
+            # call arguments the block says it consumes and returns:
+            # positions of the call -> positions among fwd's arguments
+            traced_at = [i for i, a in enumerate(static_args)
+                         if a is _TRACED]
+            donate = tuple(3 + traced_at.index(i)
+                           for i in getattr(block, "donate_args", ()))
+            if donate_states:
+                donate = (1,) + donate
             fwd_jit = jax.jit(fwd, donate_argnums=donate,
                               compiler_options=self._compiler_options)
 

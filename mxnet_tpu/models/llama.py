@@ -225,21 +225,26 @@ class LlamaAttention(HybridBlock):
                                        start_pos, t)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
+        # a layer whose K/V are pages (the engine's step) says so with its
+        # page table: rows are written into their pages and read there
+        table = getattr(cache, "page_table", None)
         if getattr(cache, "quant", None) == "int8":
             k_all, k_s = _ops.kv_cache_write_q(cache.k, cache.k_scale, k,
-                                               start_pos)
+                                               start_pos, page_table=table)
             v_all, v_s = _ops.kv_cache_write_q(cache.v, cache.v_scale, v,
-                                               start_pos)
+                                               start_pos, page_table=table)
             cache.update(k_all, v_all, k_s, v_s)
             out = _ops.cached_attention(q, k_all, v_all, start_pos,
                                         path=path, k_scale=k_s,
-                                        v_scale=v_s)
+                                        v_scale=v_s, page_table=table)
         else:
-            k_all = _ops.kv_cache_write(cache.k, k, start_pos)
-            v_all = _ops.kv_cache_write(cache.v, v, start_pos)
+            k_all = _ops.kv_cache_write(cache.k, k, start_pos,
+                                        page_table=table)
+            v_all = _ops.kv_cache_write(cache.v, v, start_pos,
+                                        page_table=table)
             cache.update(k_all, v_all)
             out = _ops.cached_attention(q, k_all, v_all, start_pos,
-                                        path=path)
+                                        path=path, page_table=table)
         out = out.transpose(0, 2, 1, 3).reshape(b, t, self._q_units)
         return _serving_dense(out, self.o_proj.weight, cache)
 

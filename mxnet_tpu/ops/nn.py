@@ -877,7 +877,7 @@ def attention(query, key, value, mask=None, causal=False, scale=None,
 # ---------------------------------------------------------------------------
 
 
-def kv_cache_write(cache, new, start_pos):
+def kv_cache_write(cache, new, start_pos, page_table=None):
     """Write ``new`` (B, H, T, D) into the ring ``cache`` (B, H, S, D) at
     per-row positions ``start_pos[b] + [0..T)``.
 
@@ -885,7 +885,13 @@ def kv_cache_write(cache, new, start_pos):
     a scatter: deterministic, differentiable-free, and exact — selected
     elements are copied, not arithmetically merged, so ``-0.0`` and
     payload bits survive untouched.
+
+    With a ``page_table`` (B, N), ``cache`` is a page pool (P, H, page, D)
+    and the rows go straight into their pages (:func:`write_pages`).
     """
+    if page_table is not None:
+        return _apply(write_pages, (cache, page_table, new, start_pos),
+                      name="paged_kv_write")
 
     def f(c, n, sp):
         jnp = _jnp()
@@ -901,7 +907,17 @@ def kv_cache_write(cache, new, start_pos):
     return _apply(f, (cache, new, start_pos), name="kv_cache_write")
 
 
-def kv_cache_write_q(cache_q, cache_scale, new, start_pos):
+def _quantize_rows(n):
+    """Per token per head symmetric int8: ``(rows int8, scale f32)``."""
+    jnp = _jnp()
+    amax = jnp.max(jnp.abs(n), axis=-1)                          # (B, H, T)
+    scale = jnp.maximum(amax / 127.0, 1e-8)
+    nq = jnp.clip(jnp.round(n / scale[..., None]),
+                  -127, 127).astype(jnp.int8)
+    return nq, scale
+
+
+def kv_cache_write_q(cache_q, cache_scale, new, start_pos, page_table=None):
     """Quantize-on-write into an int8 KV ring: ``new`` (B, H, T, D) f32 is
     symmetric-quantized per token per head (scale = max|row| / 127 over D)
     and written into ``cache_q`` (B, H, S, D) int8 with its scale row into
@@ -909,17 +925,23 @@ def kv_cache_write_q(cache_q, cache_scale, new, start_pos):
 
     Same gather+select window as ``kv_cache_write`` — untouched ring slots
     are copied, not merged. Returns ``(new_cache_q, new_cache_scale)``;
-    dequantization happens inside ``cached_attention``'s fast path.
+    dequantization happens inside ``cached_attention``'s fast path. With
+    a ``page_table`` the two are page pools, written like
+    ``kv_cache_write``'s.
     """
+    if page_table is not None:
+        def paged(pq, ps, t, n, sp):
+            nq, scale = _quantize_rows(n)
+            return write_pages(pq, t, nq, sp), write_pages(ps, t, scale, sp)
+
+        return _apply(paged, (cache_q, cache_scale, page_table, new,
+                              start_pos), name="paged_kv_write_q")
 
     def f(cq, cs, n, sp):
         jnp = _jnp()
         s_len = cq.shape[2]
         t_len = n.shape[2]
-        amax = jnp.max(jnp.abs(n), axis=-1)                      # (B, H, T)
-        scale = jnp.maximum(amax / 127.0, 1e-8)
-        nq = jnp.clip(jnp.round(n / scale[..., None]),
-                      -127, 127).astype(jnp.int8)
+        nq, scale = _quantize_rows(n)
         s_idx = jnp.arange(s_len, dtype=jnp.int32)[None, :]      # (1, S)
         sp_ = sp.astype(jnp.int32)[:, None]                      # (B, 1)
         in_window = (s_idx >= sp_) & (s_idx < sp_ + t_len)       # (B, S)
@@ -981,7 +1003,8 @@ def quantized_dense(data, qweight, scale, bias=None):
 
 
 def cached_attention(query, key, value, start_pos, scale=None,
-                     path="baseline", k_scale=None, v_scale=None):
+                     path="baseline", k_scale=None, v_scale=None,
+                     page_table=None):
     """Causal attention of ``query`` (B, H, T, D) — absolute positions
     ``start_pos[b] + t`` — over a KV ring (B, H, S, D).
 
@@ -997,7 +1020,10 @@ def cached_attention(query, key, value, start_pos, scale=None,
     (``ops/pallas/decode_attention``), which takes *unexpanded* GQA K/V of
     shape (B, KV, S, D) — optionally int8 with (B, KV, S)
     ``k_scale``/``v_scale`` rings dequantized in-kernel — and carries a
-    tolerance (not bitwise) parity contract.
+    tolerance (not bitwise) parity contract. With a ``page_table`` (B, N)
+    (fast rungs only) key/value and their scales are page pools that
+    already hold this call's rows, and the kernel reads them in place
+    (``decode_attention.paged_decode_attention``).
     """
     d = query.shape[-1]
     sc = float(scale) if scale is not None else 1.0 / math.sqrt(d)
@@ -1009,15 +1035,24 @@ def cached_attention(query, key, value, start_pos, scale=None,
         routing = (da._FORCE_PATH, da._INTERPRET)
         has_scales = k_scale is not None
 
+        paged = page_table is not None
+
         def f(q, k, v, sp, *extra):
             assert routing == (da._FORCE_PATH, da._INTERPRET)
-            ks, vs = (extra[0], extra[1]) if has_scales else (None, None)
+            extra = list(extra)
+            table = extra.pop() if paged else None
+            ks, vs = extra if has_scales else (None, None)
+            if paged:
+                return da.paged_decode_attention(q, k, v, table, sp, scale=sc,
+                                                 k_scale=ks, v_scale=vs)
             return da.decode_attention(q, k, v, sp, scale=sc,
                                        k_scale=ks, v_scale=vs)
 
         args = (query, key, value, start_pos)
         if has_scales:
             args = args + (k_scale, v_scale)
+        if paged:
+            args = args + (page_table,)
         return _apply(f, args, name="cached_attention_fast")
 
     def f(q, k, v, sp):
@@ -1224,6 +1259,54 @@ def sample_step(logits, temperature, top_k, seeds, positions, key_bits):
 # ---------------------------------------------------------------------------
 
 
+def gather_pages(p, t):
+    """:func:`paged_kv_gather` on raw arrays."""
+    jnp = _jnp()
+    g = jnp.take(p, t.astype(jnp.int32), axis=0)      # (B, N, KV, pg[, D])
+    if p.ndim == 4:
+        g = g.transpose(0, 2, 1, 3, 4)
+        b, kv, n, pg, d = g.shape
+        return g.reshape(b, kv, n * pg, d)
+    g = g.transpose(0, 2, 1, 3)
+    b, kv, n, pg = g.shape
+    return g.reshape(b, kv, n * pg)
+
+
+def write_pages(p, t, new, sp):
+    """``new`` (B, KV, T, D) — or (B, KV, T) scale rows — written
+    straight into the pool ``p`` (P, KV, page[, D]) at positions
+    ``sp[b] + [0..T)`` of each row's logical ring, through the page table
+    ``t`` (B, N), on raw arrays. No ring is built: where the pool is a
+    donated argument of the executable this is an update in place.
+
+    The scatter runs over the pool viewed as rows, ``(P * KV * page[,
+    D])`` with one indexed dimension. Indexed as ``p.at[pid, :, off]``
+    XLA:TPU lays the operand out with the two indexed dimensions
+    outermost and copies the whole pool there and back around the
+    scatter, donated or not (tests/test_chip_compile.py holds the
+    compiled step to having no such copy).
+
+    The invariants are :func:`paged_kv_scatter`'s: a scatter-``set`` of
+    copied rows; only positions at or past ``sp`` are written (pages a
+    prefix shares stay as they are); rows of all-null table rows (dead
+    lanes) and positions past the ring's end land on page 0, which is
+    zeroed again at the end.
+    """
+    jnp = _jnp()
+    kv, page = p.shape[1], p.shape[2]
+    n_pages, t_len = t.shape[1], new.shape[2]
+    pos = sp.astype(jnp.int32)[:, None] \
+        + jnp.arange(t_len, dtype=jnp.int32)[None, :]               # (B, T)
+    pid = jnp.take_along_axis(
+        t.astype(jnp.int32), jnp.clip(pos // page, 0, n_pages - 1), axis=1)
+    pid = jnp.where(pos < n_pages * page, pid, 0)
+    head = jnp.arange(kv, dtype=jnp.int32)[None, :, None]
+    row = (pid[:, None, :] * kv + head) * page + (pos % page)[:, None, :]
+    rows = p.reshape((-1,) + p.shape[3:])                 # (P*KV*page[, D])
+    out = rows.at[row].set(new.astype(p.dtype)).reshape(p.shape)
+    return out.at[0].set(jnp.zeros_like(out[0]))
+
+
 def paged_kv_gather(pool, page_table):
     """Materialize per-slot contiguous KV rings from a paged pool.
 
@@ -1237,18 +1320,7 @@ def paged_kv_gather(pool, page_table):
     being overwritten.
     """
 
-    def f(p, t):
-        jnp = _jnp()
-        g = jnp.take(p, t.astype(jnp.int32), axis=0)  # (B, N, KV, pg[, D])
-        if p.ndim == 4:
-            g = g.transpose(0, 2, 1, 3, 4)
-            b, kv, n, pg, d = g.shape
-            return g.reshape(b, kv, n * pg, d)
-        g = g.transpose(0, 2, 1, 3)
-        b, kv, n, pg = g.shape
-        return g.reshape(b, kv, n * pg)
-
-    return _apply(f, (pool, page_table), name="paged_kv_gather")
+    return _apply(gather_pages, (pool, page_table), name="paged_kv_gather")
 
 
 def paged_kv_scatter(pool, page_table, ring, start_pos, length):

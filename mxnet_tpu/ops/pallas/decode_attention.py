@@ -19,8 +19,21 @@ int8 KV rings dequantize in-kernel: pass ``k_scale``/``v_scale`` of shape
 the kernel widens int8 blocks to f32 right next to the MXU dot, so the ring
 stays half-size in HBM end to end.
 
+Paged form (``paged_decode_attention``): the serving step of
+``ContinuousEngine`` keeps K/V in page pools (P, KV, page, D) and never
+builds a ring for a decode step. The page table and ``start_pos`` arrive
+as scalar-prefetch operands; the K/V index maps pick pool page
+``table[b, min(j, start_pos[b] // page)]``, so a page past a sequence's
+end repeats the last block index and is not fetched at all. One program
+is one lane with all its KV heads (a page with its heads is one
+contiguous block of the pool); per head the arithmetic and the block
+order are the ring kernel's. What it cannot tile (a page that is neither
+a multiple of the block nor smaller than it; T > 1) reads the pages
+gathered into rings through the ring paths below.
+
 Introspection follows ``flash_attention``'s conventions: ``last_path()``
-reports which implementation the last call traced ("pallas" | "xla"),
+reports which implementation the last call traced ("pallas_paged" |
+"pallas" | "xla"),
 ``force_path()`` overrides routing, ``use_interpret(True)`` runs the kernel
 through the Pallas interpreter on CPU. Decode-shaped calls (T == 1) that
 land on the XLA fallback additionally record a flight-recorder note and
@@ -173,6 +186,40 @@ def _xla_decode(q, k, v, start_pos, scale, k_scale, v_scale):
     return out.reshape(b, h, t, d).astype(q.dtype)
 
 
+def _flash_block(q, k, v, k_scale, v_scale, first, sp, scale, prec,
+                 m_prev, l_prev, acc_prev):
+    """One K/V block of one KV head into the flash accumulators: q
+    (Gp, D) against k, v (bk, D) whose first position is ``first``, masked
+    to positions <= ``sp``; int8 blocks are widened by their (bk,) scale
+    rows right next to the dots. Returns the new (max, sum, acc). The
+    ring kernel and the paged kernel share it, so a lane's result comes
+    from the same arithmetic whichever reads its blocks."""
+    import jax
+    import jax.numpy as jnp
+
+    qb = q.astype(jnp.float32)                     # (Gp, D)
+    kb = k.astype(jnp.float32)                     # (bk, D)
+    vb = v.astype(jnp.float32)
+    if k_scale is not None:
+        kb = kb * k_scale[:, None]
+        vb = vb * v_scale[:, None]
+    sc = jax.lax.dot_general(
+        qb, kb, (((1,), (1,)), ((), ())), precision=prec,
+        preferred_element_type=jnp.float32) * scale   # (Gp, bk)
+    kpos = first + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+    sc = jnp.where(kpos <= sp, sc, jnp.float32(_NEG_INF))
+
+    m_cur = jnp.max(sc, axis=-1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    p = jnp.exp(sc - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p, vb, (((1,), (0,)), ((), ())), precision=prec,
+        preferred_element_type=jnp.float32)
+    return m_new, l_new, acc_prev * alpha + pv
+
+
 def _decode_kernel(quant, kv, g, d, bk, n_k, scale, prec,
                    sp_ref, q_ref, k_ref, v_ref, *rest):
     """One (batch·kv_head) program: stream S in ``bk`` blocks with flash
@@ -201,30 +248,10 @@ def _decode_kernel(quant, kv, g, d, bk, n_k, scale, prec,
 
     @pl.when(run)
     def _body():
-        qb = q_ref[0].astype(jnp.float32)          # (Gp, Dp)
-        kb = k_ref[0].astype(jnp.float32)          # (bk, Dp)
-        vb = v_ref[0].astype(jnp.float32)
-        if quant:
-            kb = kb * ks_ref[0, 0][:, None]
-            vb = vb * vs_ref[0, 0][:, None]
-        sc = jax.lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())), precision=prec,
-            preferred_element_type=jnp.float32) * scale   # (Gp, bk)
-        kpos = si * bk + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        sc = jnp.where(kpos <= sp, sc, jnp.float32(_NEG_INF))
-
-        m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_cur = jnp.max(sc, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(sc - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = m_new
-        pv = jax.lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())), precision=prec,
-            preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...], l_ref[...], acc_ref[...] = _flash_block(
+            q_ref[0], k_ref[0], v_ref[0],
+            ks_ref[0, 0] if quant else None, vs_ref[0, 0] if quant else None,
+            si * bk, sp, scale, prec, m_ref[...], l_ref[...], acc_ref[...])
 
     @pl.when(si == n_k - 1)
     def _finish():
@@ -345,3 +372,173 @@ def decode_attention(q, k, v, start_pos, scale=None,
             reason = "forced_xla"
         _record_fallback(reason, q.shape)
     return _xla_decode(q, k, v, start_pos, sc, k_scale, v_scale)
+
+
+# ---------------------------------------------------------------------------
+# Paged form: K/V read where they live, through the page table
+# ---------------------------------------------------------------------------
+
+
+def _paged_block(page):
+    """The stream block inside a pool page: the ring kernel's 128 where
+    it tiles the page, the page itself (a whole-dimension block) where
+    the page is narrower, None where neither holds."""
+    if page % _BLOCK == 0:
+        return _BLOCK
+    return page if page < _BLOCK else None
+
+
+def _supports_paged(q, k_pool):
+    """Coverage of the paged kernel: the ring kernel's (the pool has its
+    KV heads where a ring has them) and a page the stream block tiles. On
+    the chip the pool's head_dim is the block's lane extent and cannot be
+    padded without a copy of the pool."""
+    return (_supports_pallas(q, k_pool)
+            and _paged_block(k_pool.shape[2]) is not None
+            and (_INTERPRET or q.shape[-1] % _BLOCK == 0))
+
+
+def _paged_kernel(quant, kv, bk, n_blk, scale, prec,
+                  tbl_ref, sp_ref, q_ref, k_ref, v_ref, *rest):
+    """One lane: stream its reached pages in ``bk`` blocks, every KV head
+    of a block in turn through :func:`_flash_block` (the G grouped query
+    heads on the sublane axis, as in ``_decode_kernel``)."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    del tbl_ref  # the index maps' alone
+    if quant:
+        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        o_ref, m_ref, l_ref, acc_ref = rest
+
+    si = pl.program_id(1)
+    sp = sp_ref[pl.program_id(0)]
+
+    @pl.when(si == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # block needed iff its first position is still <= start_pos
+    @pl.when(si * bk <= sp)
+    def _body():
+        for n in range(kv):
+            m_ref[n], l_ref[n], acc_ref[n] = _flash_block(
+                q_ref[0, n], k_ref[0, n], v_ref[0, n],
+                ks_ref[0, n] if quant else None,
+                vs_ref[0, n] if quant else None,
+                si * bk, sp, scale, prec, m_ref[n], l_ref[n], acc_ref[n])
+
+    @pl.when(si == n_blk - 1)
+    def _finish():
+        l = l_ref[...]
+        # padded sublane rows: emit zeros
+        l = jnp.where(l == 0.0, jnp.float32(1.0), l)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def _pallas_paged_decode(q, k_pool, v_pool, page_table, start_pos, scale,
+                         k_scale, v_scale):
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ..nn import stored_precision
+
+    b, h, _, d = q.shape
+    kv, page = k_pool.shape[1], k_pool.shape[2]
+    n_pages = page_table.shape[1]
+    g = h // kv
+    quant = k_scale is not None
+    bk = _paged_block(page)
+    sub = page // bk                 # stream blocks a page
+    n_blk = n_pages * sub
+    gp = _round_up(g, 8)             # f32 sublane tile
+
+    q4 = jnp.pad(q.reshape(b, kv, g, d), ((0, 0), (0, 0), (0, gp - g), (0, 0)))
+
+    # index maps return int32 throughout (jax_enable_x64 is on). A block
+    # past the lane's last reached one repeats that one's index: the
+    # pipeline fetches a block only when its index moves.
+    def lane_map(i, j, tbl, sp):
+        return (i, jnp.int32(0), jnp.int32(0), jnp.int32(0))
+
+    def _at(i, j, tbl, sp):
+        jc = jnp.minimum(j, jax.lax.div(sp[i], jnp.int32(bk)))
+        pid = tbl[i * jnp.int32(n_pages) + jax.lax.div(jc, jnp.int32(sub))]
+        return pid, jax.lax.rem(jc, jnp.int32(sub))
+
+    def page_map(i, j, tbl, sp):
+        pid, blk = _at(i, j, tbl, sp)
+        return (pid, jnp.int32(0), blk, jnp.int32(0))
+
+    def scale_map(i, j, tbl, sp):
+        pid, blk = _at(i, j, tbl, sp)
+        return (pid, jnp.int32(0), blk)
+
+    in_specs = [
+        pl.BlockSpec((1, kv, gp, d), lane_map),
+        pl.BlockSpec((1, kv, bk, d), page_map),
+        pl.BlockSpec((1, kv, bk, d), page_map),
+    ]
+    args = [q4, k_pool, v_pool]
+    if quant:
+        in_specs += [pl.BlockSpec((1, kv, bk), scale_map),
+                     pl.BlockSpec((1, kv, bk), scale_map)]
+        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+
+    kernel = functools.partial(_paged_kernel, quant, kv, bk, n_blk, scale,
+                               stored_precision(q, k_pool, v_pool))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, n_blk),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, kv, gp, d), lane_map),
+            scratch_shapes=[pltpu.VMEM((kv, gp, 1), jnp.float32),
+                            pltpu.VMEM((kv, gp, 1), jnp.float32),
+                            pltpu.VMEM((kv, gp, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, kv, gp, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=_INTERPRET,
+    )(page_table.astype(jnp.int32).reshape(-1), start_pos.astype(jnp.int32),
+      *args)
+    return out[:, :, :g, :].reshape(b, h, 1, d)
+
+
+def paged_decode_attention(q, k_pool, v_pool, page_table, start_pos,
+                           scale=None, k_scale=None, v_scale=None):
+    """:func:`decode_attention` over K/V that stay in their page pools.
+
+    q: (B, H, T, D); k_pool/v_pool: (P, KV, page, D) (f32, or int8 with
+    (P, KV, page) scale pools); page_table: (B, N) int32 pool page ids of
+    each row's logical pages (0 = the null page); start_pos: (B,) int32.
+    The new rows of this call are in the pools already. A decode-shaped
+    call (T == 1) the paged kernel covers reads the pages in place;
+    anything else gathers the rows' pages into rings and goes through
+    :func:`decode_attention` (a decode-shaped one counts as a fallback).
+    """
+    global _LAST_PATH
+    sc = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    decode = q.ndim == 4 and q.shape[2] == 1
+    if decode and _FORCE_PATH != "xla" and _supports_paged(q, k_pool):
+        _LAST_PATH = "pallas_paged"
+        return _pallas_paged_decode(q, k_pool, v_pool, page_table,
+                                    start_pos, sc, k_scale, v_scale)
+    from ..nn import gather_pages
+
+    k, v = gather_pages(k_pool, page_table), gather_pages(v_pool, page_table)
+    if k_scale is not None:
+        k_scale = gather_pages(k_scale, page_table)
+        v_scale = gather_pages(v_scale, page_table)
+    if decode and _FORCE_PATH != "xla" and _supports_pallas(q, k):
+        # the ring kernel serves it; the xla path records its own
+        _record_fallback("page_untiled", q.shape)
+    return decode_attention(q, k, v, start_pos, sc, k_scale, v_scale)

@@ -82,6 +82,10 @@ class _LayerKV:
     def quant_weights(self):
         return self._cache.quant_weights
 
+    @property
+    def page_table(self):
+        return self._cache.page_table
+
     # the layer's recurrent state (one row a sequence) and the step's
     # lane contract for it (ops/nn.py, the recurrent-state section)
     @property
@@ -151,8 +155,10 @@ class CacheLayout:
         for lay in self.layers:
             shape = (int(kv_lead), lay.kv_heads, int(kv_seq), lay.head_dim)
             if self.quant:
-                out += [zeros(shape, dtype="int8"),
-                        zeros(shape[:3], dtype="float32")] * 2
+                # four arrays, not two listed twice: a step that consumes
+                # its stores cannot be handed one buffer at two positions
+                out += [zeros(sh, dtype=dt) for _ in range(2)
+                        for sh, dt in ((shape, "int8"), (shape[:3], "float32"))]
             else:
                 out += [zeros(shape, dtype=dtype), zeros(shape, dtype=dtype)]
             out += [zeros((int(state_rows),) + tuple(s), dtype="float32")
@@ -220,7 +226,10 @@ class KVCache:
     the serving step before the model forward: which ``cached_attention``
     formulation the layers should compile, and (int8 rung) the
     ``{id(param): (int8_weight, scale)}`` side table for
-    ``ops.nn.quantized_dense``.
+    ``ops.nn.quantized_dense``. ``page_table`` is set the same way by a
+    step whose K/V arrays are page pools and not rings (the engine's
+    in-place step): the layers then write a position into its page and
+    read the pages through the table.
     """
 
     def __init__(self, keys, values, max_seq, key_scales=None,
@@ -243,6 +252,7 @@ class KVCache:
         self.max_seq = int(max_seq)
         self.path = "baseline"
         self.quant_weights = None
+        self.page_table = None
         # the step's lane contract for the recurrent state, set by the
         # serving step before the model forward like ``path``: valid
         # positions of each row in this call, and which rows are live
@@ -352,6 +362,19 @@ class _CacheForward(HybridBlock):
     it literally replays the same compiled step
     (tests/test_kv_blocks.py asserts it).
 
+    ``inplace=True`` (with ``paged``; the continuous engine's fast rungs)
+    leaves K/V in the pool: no ring is gathered and none scattered. The
+    layers are handed the pools and the page table; each writes its new
+    rows straight into their pages (``ops.nn.write_pages``) and attends
+    through the table — a decode step (T == 1) in the paged Pallas
+    kernel, a prefill chunk over its one row's gathered pages in XLA.
+    The step CONSUMES its cache arguments: ``donate_args`` names their
+    positions in the call, ``CachedOp`` donates them to the executable,
+    and every one comes back as an output in the same order, so the
+    writes are updates in place and a caller must rebind to the outputs
+    (``PagedKVPool.update_from_flat``) and never read the arrays it
+    passed again.
+
     The calling convention follows the model's :class:`CacheLayout`. A
     model whose layers keep recurrent state adds a ``lanes`` (B,) int32
     arg before the cache arrays (after ``page_table`` when paged): the
@@ -367,7 +390,8 @@ class _CacheForward(HybridBlock):
     """
 
     def __init__(self, model, max_seq, path="baseline", quant=None,
-                 qindex=(), all_logits=False, paged=False, **kwargs):
+                 qindex=(), all_logits=False, paged=False, inplace=False,
+                 **kwargs):
         super().__init__(**kwargs)
         self.model = model  # child registration shares the params
         self._max_seq = int(max_seq)
@@ -376,7 +400,16 @@ class _CacheForward(HybridBlock):
         self._qindex = list(qindex)
         self._all_logits = bool(all_logits)
         self._paged = bool(paged)
+        self._inplace = bool(inplace)
+        if self._inplace and (not self._paged or path == "baseline"):
+            raise MXNetError("the in-place step is the paged fast rungs'")
         self._layout = CacheLayout(model, quant)
+        # the call's positions that the step consumes and returns (read
+        # by CachedOp): the cache stores, after tokens, start_pos,
+        # last_idx, the page table and the lanes
+        first = 3 + int(self._paged) + int(self._layout.has_state)
+        self.donate_args = (tuple(range(first, first + len(self._layout)))
+                            if self._inplace else ())
 
     def forward(self, tokens, start_pos, last_idx, *rest):
         layout = self._layout
@@ -387,15 +420,19 @@ class _CacheForward(HybridBlock):
             lanes, rest = rest[0], rest[1:]
         stores = rest[:len(layout)]
         qflat = rest[len(layout):]
+        ringed = self._paged and not self._inplace
         # what the model's cache path reads: K/V as per-row rings (gathered
-        # through the page table when paged), state as one row a batch row
+        # through the page table when paged; the pools themselves, with
+        # the table beside them, in place), state as one row a batch row
         flat_cache = [
             _ops.state_rows_gather(a, lanes) if kind == "state"
-            else _ops.paged_kv_gather(a, page_table) if self._paged else a
+            else _ops.paged_kv_gather(a, page_table) if ringed else a
             for a, kind in zip(stores, layout.kinds)]
         cache = KVCache.from_flat(flat_cache, self._max_seq,
                                   quant=self._quant, layout=layout)
         cache.path = self._path
+        if self._inplace:
+            cache.page_table = page_table
         if layout.has_state:
             # the lane contract of the recurrent-state ops: the real
             # positions of a row are 0 .. last_idx, and a row whose lane
@@ -420,7 +457,7 @@ class _CacheForward(HybridBlock):
         updated = tuple(
             _ops.state_rows_scatter(a, lanes, new) if kind == "state"
             else _ops.paged_kv_scatter(a, page_table, new, start_pos, t_len)
-            if self._paged else new
+            if ringed else new
             for a, new, kind in zip(stores, cache.flat(), layout.kinds))
         if self._all_logits:
             # speculative verify step: the caller scores every position of

@@ -12,7 +12,10 @@ the table into a contiguous ring, runs the unchanged model cache path,
 and scatters the freshly-written rows back — both directions exact
 copies (``ops.nn.paged_kv_gather`` / ``paged_kv_scatter``). Fast rungs
 fuse the brackets into the step executable
-(``serve.generate._CacheForward(paged=True)``); the strict baseline
+(``serve.generate._CacheForward(paged=True)``) or, in the continuous
+engine, drop them: its step consumes the pool arrays (donated), writes
+new rows into their pages in place and reads the pages through the table
+in the kernel (``_CacheForward(inplace=True)``); the strict baseline
 rung instead runs them as standalone eager device ops around the
 UNCHANGED ring executable, so its bitwise decode contract survives
 paging by construction (in-graph, XLA partitions the attention loops
@@ -122,7 +125,6 @@ class PagedKVPool:
     def __init__(self, model, num_slots, max_seq, page_size=None,
                  num_pages=None, quant=None):
         from .. import config
-        from .. import numpy as mnp
 
         self.num_slots = int(num_slots)
         self.max_seq = int(max_seq)
@@ -145,9 +147,7 @@ class PagedKVPool:
         # (P, KV, page) f32 scale pools on the int8 rung, then the
         # layer's (num_slots, ...) state rows
         self.ctx = block_context(model)
-        self._arrays = self.layout.alloc(
-            functools.partial(mnp.zeros, ctx=self.ctx), self.num_pages,
-            self.page_size, self.num_slots)
+        self.reallocate()
         # host allocator state: LIFO free list (hot pages recycle first),
         # per-slot owned pages, the canonical page-table matrix
         self._lock = threading.Lock()
@@ -177,6 +177,22 @@ class PagedKVPool:
                 f"pool update: got {len(arrays)} arrays, expected "
                 f"{len(self._arrays)}")
         self._arrays = arrays
+
+    def lost(self):
+        """True if a device buffer of the pool is gone: a step that
+        consumes its cache arguments (``_CacheForward(inplace=True)``)
+        was dispatched and its outputs never came back."""
+        return any(a._data.is_deleted() for a in self._arrays)
+
+    def reallocate(self):
+        """Zeroed device arrays in place of whatever the pool held (its
+        first ones; new ones after :meth:`lost`). The host allocator's
+        state is the caller's to settle: every page's content is gone."""
+        from .. import numpy as mnp
+
+        self._arrays = self.layout.alloc(
+            functools.partial(mnp.zeros, ctx=self.ctx), self.num_pages,
+            self.page_size, self.num_slots)
 
     def table(self):
         """Copy of the canonical (num_slots, pages_per_slot) int32 page

@@ -192,9 +192,15 @@ class ContinuousEngine:
         # the brackets as standalone exact copies in _run_step, which is
         # what makes its decode bitwise identical to the ring path
         self._fused_paged = self.decode_path != "baseline"
+        # ... and there the step keeps K/V in the pool: it consumes the
+        # pool arrays (donated to the executable), writes in place and
+        # reads pages through the table (_CacheForward, inplace)
         self._step_block = _CacheForward(
             model, self.max_seq, path=self.decode_path, quant=self._quant,
-            qindex=self._qindex, paged=self._fused_paged)
+            qindex=self._qindex, paged=self._fused_paged,
+            inplace=self._fused_paged)
+        self._inplace_steps = 0       # step calls that consumed the pool
+        self._pool_reallocations = 0  # pools lost to a failed call
         # exactly two live signatures: (1, chunk) chunked prefill and
         # (num_slots, 1) decode — the whole point of the design
         self.session = InferenceSession(
@@ -407,9 +413,22 @@ class ContinuousEngine:
                   if stateful else [])
         with host_span("mxnet_tpu.serve.dispatch"):
             if self._fused_paged:
-                out = self.session.run(toks, sp, li, tab, *ln,
-                                       *self.pool.flat(), *self._qflat)
+                try:
+                    out = self.session.run(toks, sp, li, tab, *ln,
+                                           *self.pool.flat(), *self._qflat)
+                except Exception as exc:  # pylint: disable=broad-except
+                    # a call that failed before dispatch left the pool
+                    # alive and costs its own slots alone (the callers'
+                    # handling); one that took the buffers with it costs
+                    # every lane's cache
+                    if self.pool.lost():
+                        self._recover_pool(exc)
+                    raise
                 flat = out[1:]
+                if self._step_block.donate_args:
+                    self._inplace_steps += 1
+                    _prof.incr_counter("serve.pool_inplace_steps",
+                                       cat="serve")
             else:
                 # strict rung: paging brackets as standalone exact-copy
                 # ops around the unchanged ring executable (bitwise
@@ -427,6 +446,22 @@ class ContinuousEngine:
             live = int((_onp.asarray(lanes) >= 0).sum())
             _prof.incr_counter("serve.state_lane_steps", live, cat="serve")
         return out[0]
+
+    def _recover_pool(self, error):
+        """The pool's buffers went with a failed or timed-out call (it
+        had been dispatched: the step consumes its cache arguments).
+        Every live lane's K/V and state is gone, so each is settled with
+        ``error``; cached prefixes point at pages that no longer hold
+        them, so the trie is emptied; the pool starts again from zeros
+        and the engine serves the next request."""
+        for i, s in enumerate(self._slots):
+            if s is not None:
+                self._settle_slot(i, error=error)
+        if self.prefix is not None:
+            self.prefix.clear()
+        self.pool.reallocate()
+        self._pool_reallocations += 1
+        _prof.incr_counter("serve.pool_reallocations", cat="serve")
 
     def _prefill_once(self):
         """Advance ONE prefilling slot by one chunk (round-robin), at the
@@ -471,8 +506,10 @@ class ContinuousEngine:
                                       time.perf_counter_ns(), pf_args,
                                       (i,))
         except Exception as exc:  # pylint: disable=broad-except
-            # only THIS slot was inside the failing call
-            self._settle_slot(i, error=exc)
+            # only THIS slot was inside the failing call (a call that
+            # took the pool with it has settled every slot already)
+            if self._slots[i] is not None:
+                self._settle_slot(i, error=exc)
             return
         s.consumed += n
         if s.consumed < len(s.prompt):
@@ -948,6 +985,11 @@ class ContinuousEngine:
         out["state_bytes_per_lane"] = (self.pool.state_nbytes()
                                        // self.num_slots)
         out["steps"] = self._steps
+        # calls of the step executable that consumed the pool arrays and
+        # updated them in place; pools lost to a failed call and started
+        # again from zeros
+        out["pool_inplace_steps"] = self._inplace_steps
+        out["pool_reallocations"] = self._pool_reallocations
         caches = [out["cache"]]
         if self._msession is not None:
             out["multistep"] = self._msession.stats()

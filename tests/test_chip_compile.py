@@ -179,3 +179,148 @@ def test_dropout_partitions_over_four_chips(topo):
             mx_random.pop_trace_rng()
 
     assert "rng-bit-generator" in _compile(fn, key, x)
+
+
+# -- the serving step that leaves its K/V in the pool (PR 32) -----------------
+# Both serving cells' widths (chipbench/configs): mistral_7b_v01, 8 lanes of
+# 32 heads over 8 KV heads, rings of 2,048; falcon_h1_34b, 32 lanes of 20
+# heads over 4, rings of 512, a Mamba-2 state beside them. Pages of 128.
+
+_PAGE = 128
+_CELLS = {"mistral": dict(lanes=8, heads=32, kv=8, max_seq=2048),
+          "falcon": dict(lanes=32, heads=20, kv=4, max_seq=512)}
+
+
+def _cell_model(cell, layers=1):
+    from mxnet_tpu.models.falcon_h1 import FalconH1Model
+    from mxnet_tpu.models.llama import LlamaModel
+
+    if cell == "mistral":
+        return LlamaModel(vocab_size=32000, units=4096, hidden_size=14336,
+                          num_heads=32, num_kv_heads=8, num_layers=layers)
+    return FalconH1Model(
+        vocab_size=32640, units=5120, hidden_size=21504, num_layers=layers,
+        num_heads=20, num_kv_heads=4, head_dim=128, mamba_d_ssm=_H * _P,
+        mamba_d_state=_N, mamba_n_heads=_H, mamba_d_head=_P,
+        mamba_n_groups=_G, mamba_d_conv=_K, theta=1e11,
+        key_multiplier=0.011, mlp_multipliers=(0.5, 0.5),
+        ssm_multipliers=(0.3, 0.3, 0.3, 0.3, 0.3))
+
+
+@pytest.mark.parametrize("cell,int8", [
+    ("mistral", False), ("mistral", True), ("falcon", False),
+    ("falcon", True)], ids=["mistral-f32", "mistral-int8", "falcon-f32",
+                            "falcon-int8"])
+def test_paged_decode_kernel_compiles_for_v5e(one_chip, cell, int8):
+    c = _CELLS[cell]
+    n_pages = c["max_seq"] // _PAGE
+    pool = (c["lanes"] * n_pages + 1, c["kv"], _PAGE, 128)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q = sds((c["lanes"], c["heads"], 1, 128), jnp.float32)
+    k = sds(pool, jnp.int8 if int8 else jnp.float32)
+    scales = [sds(pool[:3], jnp.float32)] * 2 if int8 else []
+
+    def fn(q, k, v, table, sp, *scales):
+        return da._pallas_paged_decode(q, k, v, table, sp, 128 ** -0.5,
+                                       *(scales or (None, None)))
+
+    text = _compile(fn, q, k, k, sds((c["lanes"], n_pages), jnp.int32),
+                    sds((c["lanes"],), jnp.int32), *scales)
+    assert "tpu_custom_call" in text
+
+
+def _inplace_step(cell, rows, t_len, path, one_chip):
+    """The engine's in-place step over ``cell``'s model (one layer, every
+    width real, parameters never materialized), compiled for the
+    described chip with its cache stores donated as ``CachedOp`` donates
+    them. Returns the compiled text, the stores' shapes and for each
+    store its (output, parameter) numbers."""
+    from mxnet_tpu.parallel.functional import functionalize_abstract
+    from mxnet_tpu.serve.generate import CacheLayout, _CacheForward
+
+    c = _CELLS[cell]
+    quant = "int8" if path == "int8" else None
+    net = _cell_model(cell)
+    step = _CacheForward(net, c["max_seq"], path=path, quant=quant,
+                         paged=True, inplace=True)
+    apply_fn, structs = functionalize_abstract(step)
+
+    def sds(shape, dtype="float32"):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    layout = CacheLayout(net, quant)
+    n_pages = c["max_seq"] // _PAGE
+    stores = layout.alloc(sds, c["lanes"] * n_pages + 1, _PAGE, c["lanes"])
+    args = [sds((rows, t_len), "int32"), sds((rows,), "int32"),
+            sds((rows,), "int32"), sds((rows, n_pages), "int32")]
+    args += [sds((rows,), "int32")] * layout.has_state
+    first = len(args)
+    assert step.donate_args == tuple(range(first, first + len(stores)))
+    params = {n: sds(s.shape, s.dtype) for n, s in structs.items()}
+    key = jax.random.PRNGKey(0)
+    fn = jax.jit(lambda p, *a: apply_fn(p, *a, rng_key=key),
+                 donate_argnums=tuple(1 + i for i in step.donate_args))
+    text = fn.lower(params, *args, *stores).compile().as_text()
+    pairs = {(1 + j, len(params) + first + j) for j in range(len(stores))}
+    return text, stores, pairs
+
+
+def _aliases(text):
+    import re
+
+    head = text.split("entry_computation_layout", 1)[0]
+    return {(int(o), int(p)) for o, p in
+            re.findall(r"\{(\d+)\}: \((\d+), \{\}", head)}
+
+
+def _type(s):
+    kind = {"float32": "f32", "int8": "s8", "int32": "s32"}[str(s.dtype)]
+    return f"{kind}[{','.join(str(d) for d in s.shape)}]"
+
+
+def _no_copy_of(stores, text):
+    """No ``copy`` makes an array of a cache store's type (stores under
+    4 MB aside: the conv state's 2 MB changes its tiling on the way)."""
+    import re
+
+    for s in stores:
+        if s.size * s.dtype.itemsize >= 4 << 20:
+            assert not re.search(
+                rf"= {re.escape(_type(s))}\S* copy\(", text), _type(s)
+
+
+@pytest.mark.parametrize("cell,path", [
+    ("mistral", "pallas"), ("mistral", "int8"), ("falcon", "pallas")])
+def test_decode_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell,
+                                               path):
+    """The (lanes, 1) decode step: every cache store is an input-output
+    alias of the executable, the kernel is in it, nothing of a ring's
+    shape is ever made, and no copy of a pool stands beside the writes
+    (XLA:TPU transposes the whole operand of a scatter whose indexed
+    dimensions are not its outermost: ``ops.nn.write_pages``)."""
+    monkeypatch.setattr(da, "_platform_of", lambda x: "tpu")  # the described chip
+    c = _CELLS[cell]
+    text, stores, pairs = _inplace_step(cell, c["lanes"], 1, path, one_chip)
+    assert da.last_path() == "pallas_paged"
+    assert "tpu_custom_call" in text
+    assert pairs <= _aliases(text), (pairs, _aliases(text))
+    for kind in ("f32", "s8"):
+        assert f"{kind}[{c['lanes']},{c['kv']},{c['max_seq']}," not in text
+        assert f"{kind}[{c['lanes']},{c['kv']},{c['max_seq']}]" not in text
+    _no_copy_of(stores, text)
+
+
+@pytest.mark.parametrize("cell", ["mistral", "falcon"])
+def test_prefill_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell):
+    """The (1, 128) prefill chunk: no Mosaic call (the benchmark tells the
+    two step executables apart by it), every store aliased, no copy of a
+    pool or of the state rows."""
+    monkeypatch.setattr(da, "_platform_of", lambda x: "tpu")
+    text, stores, pairs = _inplace_step(cell, 1, 128, "pallas", one_chip)
+    assert "tpu_custom_call" not in text
+    assert pairs <= _aliases(text), (pairs, _aliases(text))
+    _no_copy_of(stores, text)
