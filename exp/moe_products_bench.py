@@ -1,0 +1,109 @@
+"""Microbenchmark of the routed-expert products on the chip (issue 33):
+the candidates of ``ops.nn`` at the Mellum-2 cell's two shapes, 16 x 8
+and 128 x 8 assignments over 64 experts of 2304 x 896, float32.
+
+    python3 exp/moe_products_bench.py [--tiny]
+
+Prints one JSON line a candidate and shape: milliseconds a call (median
+of ``reps`` timed calls that end in ``block_until_ready``), the bytes of
+expert weights the tokens' experts hold, and what share of 819 GB/s that
+is. ``--tiny`` runs small shapes on any platform (a rehearsal).
+"""
+import json
+import statistics
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+sys.path.insert(0, ".")
+from mxnet_tpu.ops import nn as ops  # noqa: E402
+
+HBM = 819e9
+
+
+def timed(fn, args, reps=20):
+    out = fn(*args)
+    jax.block_until_ready(out)
+    ms = []
+    for _ in range(reps):
+        t = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        ms.append((time.perf_counter() - t) * 1e3)
+    return statistics.median(ms), out
+
+
+def main():
+    tiny = "--tiny" in sys.argv
+    e, h, f, k = (8, 64, 32, 2) if tiny else (64, 2304, 896, 8)
+    key = jax.random.key(0)
+    ks = jax.random.split(key, 5)
+    gate = 0.02 * jax.random.normal(ks[0], (e, h, f), jnp.float32)
+    up = 0.02 * jax.random.normal(ks[1], (e, h, f), jnp.float32)
+    down = 0.02 * jax.random.normal(ks[2], (e, f, h), jnp.float32)
+    prec = jax.lax.Precision.HIGHEST
+    print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
+    for n in ((4, 16) if tiny else (16, 128)):
+        x = jax.random.normal(ks[3], (n, h), jnp.float32)
+        logits = jax.random.normal(jax.random.fold_in(ks[4], n), (n, e))
+        w, idx = ops.route_top_k(logits, k)
+        hit = int(np.unique(np.asarray(idx)).size)
+        nbytes = hit * 3 * h * f * 4
+
+        def combine(prod):
+            return jnp.sum(prod * w[:, :, None], axis=1)
+
+        # the weights are arguments: closed over, they would be constants
+        # of the executable (4 GB each)
+        cands = {}
+        for tile in (8, 32):
+            cands[f"grouped_tile{tile}"] = jax.jit(
+                lambda x, i, gate, up, down, t=tile: combine(
+                    ops.grouped_expert_products(x, i, gate, up, down, t)[0]))
+
+        def ragged(x, i, gate, up, down):
+            a = n * k
+            flat = i.reshape(a)
+            order = jnp.argsort(flat, stable=True)
+            gs = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+            xs = x[order // k]
+            hid = jax.nn.silu(jax.lax.ragged_dot(xs, gate, gs, precision=prec)) \
+                * jax.lax.ragged_dot(xs, up, gs, precision=prec)
+            ys = jax.lax.ragged_dot(hid, down, gs, precision=prec)
+            back = jnp.zeros((a,), jnp.int32).at[order].set(
+                jnp.arange(a, dtype=jnp.int32))
+            return combine(ys[back].reshape(n, k, h))
+
+        cands["ragged_dot"] = jax.jit(ragged)
+
+        def dense(x, i, gate, up, down):
+            cw = jnp.zeros((n, e), jnp.float32).at[
+                jnp.arange(n)[:, None], i].add(w)
+            return jnp.einsum("enh,ne->nh",
+                              ops.dense_expert_products(x, gate, up, down),
+                              cw, precision=prec)
+
+        cands["dense_masked"] = jax.jit(dense)
+        ref = None
+        for name, fn in cands.items():
+            try:
+                ms, out = timed(fn, (x, idx, gate, up, down), reps=10)
+            except Exception as exc:  # a candidate the compiler refuses
+                print(json.dumps({"candidate": name, "tokens": n,
+                                  "error": repr(exc)[:300]}), flush=True)
+                continue
+            out = np.asarray(out)
+            ref = out if ref is None else ref
+            print(json.dumps({
+                "candidate": name, "tokens": n, "assignments": n * k,
+                "experts_hit": hit, "ms": ms,
+                "expert_bytes": nbytes,
+                "share_of_hbm_peak": nbytes / HBM / (ms / 1e3),
+                "max_abs_diff_from_first": float(np.abs(out - ref).max()),
+                "out_scale": float(np.abs(ref).max())}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

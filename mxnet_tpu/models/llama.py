@@ -28,17 +28,52 @@ from ..ops import nn as _ops
 
 
 @functools.lru_cache(maxsize=64)
-def _rope_tables(t, dim, theta=10000.0):
+def _rope_tables(t, dim, theta=10000.0, scaling=None):
     # cached: the serving hot loop recomputes the same (t, dim) table
     # every decode step — one continuous-batching iteration calls this
     # num_layers times with identical args. Callers must not mutate the
     # returned arrays (they are shared across calls).
+    #
+    # ``scaling`` (hashable, so that the cache keys on it): None, or
+    # ``("yarn", factor, original_max_positions, beta_fast, beta_slow,
+    # attention_factor)``. YaRN leaves the frequencies that turn more
+    # than ``beta_fast`` times over the original context as they are,
+    # divides those that turn fewer than ``beta_slow`` times by
+    # ``factor``, blends linearly (by channel pair) between, and scales
+    # cos and sin by ``attention_factor``. Made in float64 on the host.
+    import math
+
     import numpy as onp
 
     pos = onp.arange(t)[:, None]
     freqs = 1.0 / (theta ** (onp.arange(0, dim, 2)[None] / dim))
+    mscale = 1.0
+    if scaling is not None:
+        kind, factor, orig, beta_fast, beta_slow, mscale = scaling
+        if kind != "yarn":
+            raise MXNetError(f"rope scaling {kind!r}: only 'yarn' is known")
+
+        def pair_of(turns):
+            return dim * math.log(orig / (2 * math.pi * turns)) \
+                / (2 * math.log(theta))
+
+        low = max(math.floor(pair_of(beta_fast)), 0)
+        high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+        ramp = onp.clip((onp.arange(dim // 2) - low) / max(high - low, 1e-3),
+                        0.0, 1.0)[None]
+        freqs = (1.0 - ramp) * freqs + ramp * freqs / factor
     ang = pos * freqs  # (T, dim/2)
-    return onp.cos(ang).astype("float32"), onp.sin(ang).astype("float32")
+    return (mscale * onp.cos(ang)).astype("float32"), \
+        (mscale * onp.sin(ang)).astype("float32")
+
+
+@functools.lru_cache(maxsize=16)
+def _band(t, window):
+    """(T, T) bool: query ``i`` sees key ``j`` iff ``0 <= i - j < window``."""
+    import numpy as onp
+
+    gap = onp.arange(t)[:, None] - onp.arange(t)[None, :]
+    return (gap >= 0) & (gap < window)
 
 
 def apply_rope(x, cos, sin):
@@ -61,8 +96,12 @@ def apply_rope(x, cos, sin):
 # of its recurrent state, one row a sequence (``state``: a tuple of
 # per-sequence shapes, empty for a layer that keeps none). ``serve.KVCache``
 # and ``serve.PagedKVPool`` size themselves from this and nothing else.
-LayerCache = collections.namedtuple("LayerCache",
-                                    ["kv_heads", "head_dim", "state"])
+# ``window``: a query sees the ``window`` newest keys alone (itself among
+# them), so the layer's K/V is bounded and the pool keeps it in a ring of
+# pages; None for a layer that sees every key.
+LayerCache = collections.namedtuple(
+    "LayerCache", ["kv_heads", "head_dim", "state", "window"],
+    defaults=(None,))
 
 
 def _serving_dense(x, weight, cache):
@@ -95,7 +134,8 @@ class LlamaAttention(HybridBlock):
     """Causal GQA attention with RoPE."""
 
     def __init__(self, units, num_heads, num_kv_heads=None, theta=10000.0,
-                 head_dim=None, key_multiplier=None, **kwargs):
+                 head_dim=None, key_multiplier=None, window=None,
+                 rope_scaling=None, **kwargs):
         super().__init__(**kwargs)
         num_kv_heads = num_kv_heads or num_heads
         if (head_dim is None and units % num_heads) \
@@ -113,6 +153,11 @@ class LlamaAttention(HybridBlock):
         # key_multiplier=None multiplies nothing (Llama): the traced
         # program is then the one it always was
         self._key_mult = key_multiplier
+        # window=None sees every key and rope_scaling=None turns at
+        # theta's own frequencies (Llama): nothing is masked or scaled
+        self._window = None if window is None else int(window)
+        self._rope_scaling = None if rope_scaling is None \
+            else tuple(rope_scaling)
         q_units = self._q_units = self._head_dim * num_heads
         kv_units = self._head_dim * num_kv_heads
         # explicit in_units: static shapes at construction, required by
@@ -131,6 +176,14 @@ class LlamaAttention(HybridBlock):
         """(kv_heads, head_dim): what a position of this layer's K/V
         cache holds."""
         return self._kv_heads, self._head_dim
+
+    @property
+    def window(self):
+        return self._window
+
+    def _tables(self, t):
+        return _rope_tables(t, self._head_dim, self._theta,
+                            self._rope_scaling)
 
     def _keys(self, k):
         return k if self._key_mult is None else k * self._key_mult
@@ -160,7 +213,7 @@ class LlamaAttention(HybridBlock):
             k = self._heads_split(self._keys(self.k_proj(x)),
                                   self._kv_heads)
             v = self._heads_split(self.v_proj(x), self._kv_heads)
-            cos_t, sin_t = _rope_tables(t, self._head_dim, self._theta)
+            cos_t, sin_t = self._tables(t)
             cos = mnp.array(cos_t)
             sin = mnp.array(sin_t)
             q = apply_rope(q, cos, sin)
@@ -168,7 +221,11 @@ class LlamaAttention(HybridBlock):
             if rep > 1:  # expand kv heads for the attention kernel
                 k = mnp.repeat(k, rep, axis=1)
                 v = mnp.repeat(v, rep, axis=1)
-            out = _ops.attention(q, k, v, causal=True)
+            if self._window is None:
+                out = _ops.attention(q, k, v, causal=True)
+            else:
+                out = _ops.attention(q, k, v,
+                                     mask=mnp.array(_band(t, self._window)))
         else:
             if start_pos is None:
                 raise MXNetError("cache= requires start_pos (the (B,) "
@@ -176,6 +233,10 @@ class LlamaAttention(HybridBlock):
             path = getattr(cache, "path", "baseline")
             if path != "baseline":
                 return self._forward_cached_fast(x, cache, start_pos, path)
+            if self._window is not None:
+                raise MXNetError(
+                    "a layer bounded by a window is served by the "
+                    "continuous engine's fast rungs alone")
             # stable_dense, not Dense: the whole cache path must be
             # shape-stable so T=1 decode bitwise-matches T=bucket prefill
             q = self._heads_split(
@@ -187,8 +248,7 @@ class LlamaAttention(HybridBlock):
             v = self._heads_split(
                 _ops.stable_dense(x, self.v_proj.weight.data()),
                 self._kv_heads)
-            cos_t, sin_t = _rope_tables(cache.max_seq, self._head_dim,
-                                        self._theta)
+            cos_t, sin_t = self._tables(cache.max_seq)
             cos, sin = _ops.rope_positions(mnp.array(cos_t),
                                            mnp.array(sin_t), start_pos, t)
             q = apply_rope(q, cos, sin)
@@ -219,8 +279,7 @@ class LlamaAttention(HybridBlock):
             self._kv_heads)
         v = self._heads_split(_serving_dense(x, self.v_proj.weight, cache),
                               self._kv_heads)
-        cos_t, sin_t = _rope_tables(cache.max_seq, self._head_dim,
-                                    self._theta)
+        cos_t, sin_t = self._tables(cache.max_seq)
         cos, sin = _ops.rope_positions(mnp.array(cos_t), mnp.array(sin_t),
                                        start_pos, t)
         q = apply_rope(q, cos, sin)
@@ -228,7 +287,15 @@ class LlamaAttention(HybridBlock):
         # a layer whose K/V are pages (the engine's step) says so with its
         # page table: rows are written into their pages and read there
         table = getattr(cache, "page_table", None)
-        if getattr(cache, "quant", None) == "int8":
+        quant = getattr(cache, "quant", None)
+        # with a window the table is the ring of this kind's pages
+        # (serve.kv_blocks); None bounds nothing
+        window = self._window
+        if window is not None and (table is None or quant is not None):
+            raise MXNetError(
+                "a layer bounded by a window is served from float32 page "
+                "pools alone (the continuous engine's in-place step)")
+        if quant == "int8":
             k_all, k_s = _ops.kv_cache_write_q(cache.k, cache.k_scale, k,
                                                start_pos, page_table=table)
             v_all, v_s = _ops.kv_cache_write_q(cache.v, cache.v_scale, v,
@@ -239,12 +306,13 @@ class LlamaAttention(HybridBlock):
                                         v_scale=v_s, page_table=table)
         else:
             k_all = _ops.kv_cache_write(cache.k, k, start_pos,
-                                        page_table=table)
+                                        page_table=table, window=window)
             v_all = _ops.kv_cache_write(cache.v, v, start_pos,
-                                        page_table=table)
+                                        page_table=table, window=window)
             cache.update(k_all, v_all)
             out = _ops.cached_attention(q, k_all, v_all, start_pos,
-                                        path=path, page_table=table)
+                                        path=path, page_table=table,
+                                        window=window)
         out = out.transpose(0, 2, 1, 3).reshape(b, t, self._q_units)
         return _serving_dense(out, self.o_proj.weight, cache)
 

@@ -16,6 +16,7 @@ dispatch layer for autograd.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Sequence
 
@@ -877,7 +878,7 @@ def attention(query, key, value, mask=None, causal=False, scale=None,
 # ---------------------------------------------------------------------------
 
 
-def kv_cache_write(cache, new, start_pos, page_table=None):
+def kv_cache_write(cache, new, start_pos, page_table=None, window=None):
     """Write ``new`` (B, H, T, D) into the ring ``cache`` (B, H, S, D) at
     per-row positions ``start_pos[b] + [0..T)``.
 
@@ -887,11 +888,21 @@ def kv_cache_write(cache, new, start_pos, page_table=None):
     payload bits survive untouched.
 
     With a ``page_table`` (B, N), ``cache`` is a page pool (P, H, page, D)
-    and the rows go straight into their pages (:func:`write_pages`).
+    and the rows go straight into their pages (:func:`write_pages`); with
+    a ``window`` besides, the table's N columns are a ring of pages
+    (logical page j in column j mod N).
     """
     if page_table is not None:
+        if window is not None:
+            return _apply(functools.partial(write_pages, ring=True),
+                          (cache, page_table, new, start_pos),
+                          name="paged_kv_write_window")
         return _apply(write_pages, (cache, page_table, new, start_pos),
                       name="paged_kv_write")
+    if window is not None:
+        raise MXNetError("a layer bounded by a window keeps its K/V in a "
+                         "ring of pages (the continuous engine's in-place "
+                         "step): it has no contiguous ring to write")
 
     def f(c, n, sp):
         jnp = _jnp()
@@ -1004,7 +1015,7 @@ def quantized_dense(data, qweight, scale, bias=None):
 
 def cached_attention(query, key, value, start_pos, scale=None,
                      path="baseline", k_scale=None, v_scale=None,
-                     page_table=None):
+                     page_table=None, window=None):
     """Causal attention of ``query`` (B, H, T, D) — absolute positions
     ``start_pos[b] + t`` — over a KV ring (B, H, S, D).
 
@@ -1023,10 +1034,17 @@ def cached_attention(query, key, value, start_pos, scale=None,
     tolerance (not bitwise) parity contract. With a ``page_table`` (B, N)
     (fast rungs only) key/value and their scales are page pools that
     already hold this call's rows, and the kernel reads them in place
-    (``decode_attention.paged_decode_attention``).
+    (``decode_attention.paged_decode_attention``). With a ``window``
+    besides, a query at position ``t`` sees the keys at ``t - window + 1
+    .. t`` alone and the table's columns are a ring of pages
+    (:func:`write_pages`, ``ring``); ``None`` bounds nothing and traces
+    what it always traced.
     """
     d = query.shape[-1]
     sc = float(scale) if scale is not None else 1.0 / math.sqrt(d)
+    if window is not None and (path == "baseline" or page_table is None):
+        raise MXNetError("a window bounds the keys of the paged fast rungs "
+                         "alone (the continuous engine's in-place step)")
 
     if path != "baseline":
         from .pallas import decode_attention as da
@@ -1044,7 +1062,8 @@ def cached_attention(query, key, value, start_pos, scale=None,
             ks, vs = extra if has_scales else (None, None)
             if paged:
                 return da.paged_decode_attention(q, k, v, table, sp, scale=sc,
-                                                 k_scale=ks, v_scale=vs)
+                                                 k_scale=ks, v_scale=vs,
+                                                 window=window)
             return da.decode_attention(q, k, v, sp, scale=sc,
                                        k_scale=ks, v_scale=vs)
 
@@ -1272,7 +1291,7 @@ def gather_pages(p, t):
     return g.reshape(b, kv, n * pg)
 
 
-def write_pages(p, t, new, sp):
+def write_pages(p, t, new, sp, ring=False):
     """``new`` (B, KV, T, D) — or (B, KV, T) scale rows — written
     straight into the pool ``p`` (P, KV, page[, D]) at positions
     ``sp[b] + [0..T)`` of each row's logical ring, through the page table
@@ -1291,15 +1310,25 @@ def write_pages(p, t, new, sp):
     prefix shares stay as they are); rows of all-null table rows (dead
     lanes) and positions past the ring's end land on page 0, which is
     zeroed again at the end.
+
+    ``ring``: the table's N columns are a ring of pages, logical page j
+    of a row in column ``j mod N`` (a layer whose keys are bounded by a
+    window): a position is written over the one N pages before it, and no
+    position is past the end.
     """
     jnp = _jnp()
     kv, page = p.shape[1], p.shape[2]
     n_pages, t_len = t.shape[1], new.shape[2]
     pos = sp.astype(jnp.int32)[:, None] \
         + jnp.arange(t_len, dtype=jnp.int32)[None, :]               # (B, T)
-    pid = jnp.take_along_axis(
-        t.astype(jnp.int32), jnp.clip(pos // page, 0, n_pages - 1), axis=1)
-    pid = jnp.where(pos < n_pages * page, pid, 0)
+    if ring:
+        pid = jnp.take_along_axis(
+            t.astype(jnp.int32), (pos // page) % n_pages, axis=1)
+    else:
+        pid = jnp.take_along_axis(
+            t.astype(jnp.int32), jnp.clip(pos // page, 0, n_pages - 1),
+            axis=1)
+        pid = jnp.where(pos < n_pages * page, pid, 0)
     head = jnp.arange(kv, dtype=jnp.int32)[None, :, None]
     row = (pid[:, None, :] * kv + head) * page + (pos % page)[:, None, :]
     rows = p.reshape((-1,) + p.shape[3:])                 # (P*KV*page[, D])
@@ -1549,6 +1578,168 @@ def ssd_scan(x, dt, a, b_mat, c_mat, d, state=None, start_pos=None,
 
     return _apply(f, (x, dt, a, b_mat, c_mat, d, state, start_pos,
                       valid_len, live), name="ssd_scan")
+
+
+# ---------------------------------------------------------------------------
+# Routed experts (the feed-forward of models/mellum.py)
+#
+# A router scores every token against all ``E`` experts; the token's ``k``
+# best (a tie goes to the lower index) share its softmax mass, renormalised
+# to 1, and the layer's output is the weighted sum of those experts'
+# SwiGLU products. There is no capacity: a token gets all k of its
+# experts whatever the load.
+#
+# The layer may hold a range of the experts (``held``: expert parallelism,
+# or the chip's share of a deployment). The router keeps its full width
+# and the result is the part of the sum that the held experts give; the
+# parts of all the ranges add up to the whole layer.
+#
+# Two formulations of the expert products, the same mathematics:
+# ``grouped`` sorts the (token, expert) assignments by expert and walks
+# tiles of ``tile`` sorted rows, each against the one expert it belongs to
+# (a while loop whose trip count is the tiles in use: an expert nobody
+# picked is never read, N x k rows of work whatever E is; 32 rows a tile
+# take half the time of 8 on a v5e at 1,024 assignments: PERF.md, PR 33);
+# ``dense`` multiplies every token with every held expert and weights the
+# products (differentiable, E / k times the work).
+# ---------------------------------------------------------------------------
+
+
+def route_top_k(logits, top_k, renormalize=True):
+    """``(weights (N, k), experts (N, k) int32)`` of router ``logits``
+    (N, E), on raw arrays: softmax over all E in float32, the k largest
+    (``lax.top_k``: of equal values the lower index first), renormalised
+    to sum to 1."""
+    import jax
+
+    jnp = _jnp()
+    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    w, idx = jax.lax.top_k(p, int(top_k))
+    if renormalize:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return w, idx.astype(jnp.int32)
+
+
+def _swiglu_rows(rows, g, u, d, prec):
+    import jax
+
+    jnp = _jnp()
+    h = jax.nn.silu(jnp.dot(rows, g, precision=prec)) \
+        * jnp.dot(rows, u, precision=prec)
+    return jnp.dot(h, d, precision=prec)
+
+
+def grouped_expert_products(x, local, gate, up, down, tile=32):
+    """``E_e(x_n)`` for every assignment, on raw arrays: ``x`` (N, H),
+    ``local`` (N, k) int32 expert of each assignment among the ``E`` held
+    (``E`` itself for one that is not computed: an expert held elsewhere,
+    a token that is not live), weights ``gate``/``up`` (E, H, F) and
+    ``down`` (E, F, H). Returns ``((N, k, H) products, (E,) int32 rows of
+    each expert)``; an assignment that is not computed gives zeros."""
+    import jax
+
+    jnp = _jnp()
+    n, k = local.shape
+    e_held, h = gate.shape[0], x.shape[1]
+    a = n * k
+    tile = int(tile)
+    flat = local.reshape(a)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    edges = jnp.searchsorted(
+        flat[order], jnp.arange(e_held + 1, dtype=jnp.int32),
+        side="left").astype(jnp.int32)                           # (E + 1,)
+    counts = edges[1:] - edges[:-1]
+    # tiles of ``tile`` sorted rows, none across two experts: expert e has
+    # ceil(count / tile), in expert order
+    ends = jnp.cumsum((counts + tile - 1) // tile).astype(jnp.int32)
+    prec = stored_precision(x, gate)
+    # slices never clamp: a tile may start on the last row
+    xs = jnp.concatenate([x[order // k], jnp.zeros((tile, h), x.dtype)])
+    row_in_tile = jnp.arange(tile, dtype=jnp.int32)
+    zero = jnp.int32(0)   # jax_enable_x64 is on: a bare 0 would be an i64
+
+    def body(t, ys):
+        e = jnp.searchsorted(ends, t, side="right").astype(jnp.int32)
+        first = edges[e] + (t - (ends[e] - (counts[e] + tile - 1) // tile)) \
+            * tile
+        rows = jax.lax.dynamic_slice(xs, (first, zero), (tile, h))
+        at = lambda w: jax.lax.dynamic_index_in_dim(w, e, 0, keepdims=False)  # noqa: E731
+        y = _swiglu_rows(rows, at(gate), at(up), at(down), prec)
+        mine = (first + row_in_tile < edges[e + 1])[:, None]
+        old = jax.lax.dynamic_slice(ys, (first, zero), (tile, h))
+        return jax.lax.dynamic_update_slice(
+            ys, jnp.where(mine, y, old), (first, zero))
+
+    ys = jax.lax.fori_loop(zero, ends[-1], body,
+                           jnp.zeros((a + tile, h), x.dtype))
+    back = jnp.zeros((a,), jnp.int32).at[order].set(
+        jnp.arange(a, dtype=jnp.int32))
+    return ys[back].reshape(n, k, h), counts
+
+
+def dense_expert_products(x, gate, up, down):
+    """``E_e(x_n)`` for every token and every held expert, (E, N, H), on
+    raw arrays."""
+    import jax
+
+    jnp = _jnp()
+    prec = stored_precision(x, gate)
+    hid = jax.nn.silu(jnp.einsum("nh,ehf->enf", x, gate, precision=prec)) \
+        * jnp.einsum("nh,ehf->enf", x, up, precision=prec)
+    return jnp.einsum("enf,efh->enh", hid, down, precision=prec)
+
+
+def routed_experts(data, router_weight, gate, up, down, top_k, held=None,
+                   token_live=None, renormalize=True, impl="grouped",
+                   tile=32):
+    """The routed-expert feed-forward of the section comment: ``data``
+    (B, T, H), ``router_weight`` (E_all, H), the held experts' ``gate`` /
+    ``up`` (E, H, F) and ``down`` (E, F, H); ``held`` is ``(first, count)``
+    of the experts these are among all ``E_all`` (None: all of them).
+    ``token_live`` (B, T) bool says which tokens are real (None: all); the
+    others are given to no expert and come back as zeros.
+
+    Returns ``(out (B, T, H), load (3,) int32)``: the held experts' part
+    of every token's sum, and ``[experts that got a live token, the most
+    tokens one expert got, assignments computed]``.
+    """
+    first, count = (0, gate.shape[0]) if held is None else \
+        (int(held[0]), int(held[1]))
+    if count != gate.shape[0]:
+        raise MXNetError(f"held {held} names {count} experts, the weights "
+                         f"hold {gate.shape[0]}")
+    if impl not in ("grouped", "dense"):
+        raise MXNetError(f"routed_experts impl {impl!r}")
+
+    def f(x, rw, g, u, d, live):
+        jnp = _jnp()
+        b, t, h = x.shape
+        xf = x.reshape(b * t, h)
+        logits = jnp.dot(xf, rw.T, precision=stored_precision(xf, rw))
+        w, idx = route_top_k(logits, top_k, renormalize)
+        local = idx - first
+        here = (local >= 0) & (local < count)
+        if live is not None:
+            here = here & live.reshape(b * t, 1)
+        local = jnp.where(here, local, count)
+        w = jnp.where(here, w, 0.0)
+        if impl == "grouped":
+            prod, counts = grouped_expert_products(xf, local, g, u, d, tile)
+            out = jnp.sum(prod * w[:, :, None].astype(prod.dtype), axis=1)
+        else:
+            combine = jnp.zeros((b * t, count + 1), w.dtype).at[
+                jnp.arange(b * t, dtype=jnp.int32)[:, None], local].add(w)
+            out = jnp.einsum("enh,ne->nh", dense_expert_products(xf, g, u, d),
+                             combine[:, :count].astype(xf.dtype),
+                             precision=stored_precision(xf))
+            counts = jnp.zeros((count + 1,), jnp.int32).at[
+                local.reshape(-1)].add(1)[:count]
+        load = jnp.stack([jnp.sum(counts > 0), jnp.max(counts),
+                          jnp.sum(counts)])
+        return out.reshape(b, t, h), load.astype(jnp.int32)
+
+    return _apply(f, (data, router_weight, gate, up, down, token_live),
+                  name="routed_experts")
 
 
 # ---------------------------------------------------------------------------

@@ -31,6 +31,15 @@ order are the ring kernel's. What it cannot tile (a page that is neither
 a multiple of the block nor smaller than it; T > 1) reads the pages
 gathered into rings through the ring paths below.
 
+A window (``paged_decode_attention(..., window=W)``): the query at
+position ``t`` sees the keys at ``t - W + 1 .. t`` alone, and the page
+table's N columns are a ring of pages, logical page ``j`` in column
+``j mod N`` (``serve.kv_blocks``). The kernel's grid then runs over the
+blocks that hold those positions and no others: the index maps start at
+the block of ``max(0, t - W + 1)``, pages before it are never fetched and
+the first visible block is masked inside. ``window=None`` compiles the
+kernel it always compiled.
+
 Introspection follows ``flash_attention``'s conventions: ``last_path()``
 reports which implementation the last call traced ("pallas_paged" |
 "pallas" | "xla"),
@@ -187,13 +196,15 @@ def _xla_decode(q, k, v, start_pos, scale, k_scale, v_scale):
 
 
 def _flash_block(q, k, v, k_scale, v_scale, first, sp, scale, prec,
-                 m_prev, l_prev, acc_prev):
+                 m_prev, l_prev, acc_prev, window=None):
     """One K/V block of one KV head into the flash accumulators: q
     (Gp, D) against k, v (bk, D) whose first position is ``first``, masked
     to positions <= ``sp``; int8 blocks are widened by their (bk,) scale
     rows right next to the dots. Returns the new (max, sum, acc). The
     ring kernel and the paged kernel share it, so a lane's result comes
-    from the same arithmetic whichever reads its blocks."""
+    from the same arithmetic whichever reads its blocks. With a
+    ``window`` the positions at or before ``sp - window`` are masked
+    too."""
     import jax
     import jax.numpy as jnp
 
@@ -207,7 +218,10 @@ def _flash_block(q, k, v, k_scale, v_scale, first, sp, scale, prec,
         qb, kb, (((1,), (1,)), ((), ())), precision=prec,
         preferred_element_type=jnp.float32) * scale   # (Gp, bk)
     kpos = first + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-    sc = jnp.where(kpos <= sp, sc, jnp.float32(_NEG_INF))
+    seen = kpos <= sp
+    if window is not None:
+        seen = seen & (kpos > sp - jnp.int32(window))
+    sc = jnp.where(seen, sc, jnp.float32(_NEG_INF))
 
     m_cur = jnp.max(sc, axis=-1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
@@ -398,11 +412,14 @@ def _supports_paged(q, k_pool):
             and (_INTERPRET or q.shape[-1] % _BLOCK == 0))
 
 
-def _paged_kernel(quant, kv, bk, n_blk, scale, prec,
+def _paged_kernel(quant, kv, bk, n_blk, scale, prec, window,
                   tbl_ref, sp_ref, q_ref, k_ref, v_ref, *rest):
     """One lane: stream its reached pages in ``bk`` blocks, every KV head
     of a block in turn through :func:`_flash_block` (the G grouped query
-    heads on the sublane axis, as in ``_decode_kernel``)."""
+    heads on the sublane axis, as in ``_decode_kernel``). With a
+    ``window`` grid step ``si`` is block ``lo + si`` of the lane, ``lo``
+    the block of its first visible position."""
+    import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -421,15 +438,21 @@ def _paged_kernel(quant, kv, bk, n_blk, scale, prec,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    blk = si
+    if window is not None:
+        blk = si + jax.lax.div(jnp.maximum(sp - jnp.int32(window - 1), 0),
+                               jnp.int32(bk))
+
     # block needed iff its first position is still <= start_pos
-    @pl.when(si * bk <= sp)
+    @pl.when(blk * bk <= sp)
     def _body():
         for n in range(kv):
             m_ref[n], l_ref[n], acc_ref[n] = _flash_block(
                 q_ref[0, n], k_ref[0, n], v_ref[0, n],
                 ks_ref[0, n] if quant else None,
                 vs_ref[0, n] if quant else None,
-                si * bk, sp, scale, prec, m_ref[n], l_ref[n], acc_ref[n])
+                blk * bk, sp, scale, prec, m_ref[n], l_ref[n], acc_ref[n],
+                window)
 
     @pl.when(si == n_blk - 1)
     def _finish():
@@ -440,7 +463,7 @@ def _paged_kernel(quant, kv, bk, n_blk, scale, prec,
 
 
 def _pallas_paged_decode(q, k_pool, v_pool, page_table, start_pos, scale,
-                         k_scale, v_scale):
+                         k_scale, v_scale, window=None):
     import functools
 
     import jax
@@ -458,6 +481,9 @@ def _pallas_paged_decode(q, k_pool, v_pool, page_table, start_pos, scale,
     bk = _paged_block(page)
     sub = page // bk                 # stream blocks a page
     n_blk = n_pages * sub
+    if window is not None:
+        # the blocks that W consecutive positions can lie in
+        n_blk = min(n_blk, (window + bk - 2) // bk + 1)
     gp = _round_up(g, 8)             # f32 sublane tile
 
     q4 = jnp.pad(q.reshape(b, kv, g, d), ((0, 0), (0, 0), (0, gp - g), (0, 0)))
@@ -469,8 +495,16 @@ def _pallas_paged_decode(q, k_pool, v_pool, page_table, start_pos, scale,
         return (i, jnp.int32(0), jnp.int32(0), jnp.int32(0))
 
     def _at(i, j, tbl, sp):
+        if window is not None:
+            # the lane's first visible block, and the ring's column of
+            # the page it lies in
+            j = j + jax.lax.div(
+                jnp.maximum(sp[i] - jnp.int32(window - 1), 0), jnp.int32(bk))
         jc = jnp.minimum(j, jax.lax.div(sp[i], jnp.int32(bk)))
-        pid = tbl[i * jnp.int32(n_pages) + jax.lax.div(jc, jnp.int32(sub))]
+        col = jax.lax.div(jc, jnp.int32(sub))
+        if window is not None:
+            col = jax.lax.rem(col, jnp.int32(n_pages))
+        pid = tbl[i * jnp.int32(n_pages) + col]
         return pid, jax.lax.rem(jc, jnp.int32(sub))
 
     def page_map(i, j, tbl, sp):
@@ -493,7 +527,7 @@ def _pallas_paged_decode(q, k_pool, v_pool, page_table, start_pos, scale,
         args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
 
     kernel = functools.partial(_paged_kernel, quant, kv, bk, n_blk, scale,
-                               stored_precision(q, k_pool, v_pool))
+                               stored_precision(q, k_pool, v_pool), window)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -513,8 +547,43 @@ def _pallas_paged_decode(q, k_pool, v_pool, page_table, start_pos, scale,
     return out[:, :, :g, :].reshape(b, h, 1, d)
 
 
+def _xla_window(q, k_pool, v_pool, page_table, start_pos, scale, window):
+    """The windowed layers' XLA path (a prefill chunk; a decode step the
+    kernel does not cover): each row's ring columns gathered in logical
+    order, from the page ``N - 1`` before the one its last query lies in,
+    and the keys outside ``t - window + 1 .. t`` masked."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..nn import gather_pages, stored_precision
+
+    b, h, t, d = q.shape
+    kv, page = k_pool.shape[1], k_pool.shape[2]
+    n_pages = page_table.shape[1]
+    g = h // kv
+    sp = start_pos.astype(jnp.int32)
+    first = jnp.maximum((sp + (t - 1)) // page - (n_pages - 1), 0)   # (B,)
+    logical = first[:, None] + jnp.arange(n_pages, dtype=jnp.int32)[None, :]
+    table = jnp.take_along_axis(page_table.astype(jnp.int32),
+                                logical % n_pages, axis=1)
+    k, v = gather_pages(k_pool, table), gather_pages(v_pool, table)
+    prec = stored_precision(q, k, v)
+    qg = q.reshape(b, kv, g, t, d)
+    scores = jnp.einsum("bngtd,bnsd->bngts", qg, k, precision=prec) * scale
+    pos = sp[:, None] + jnp.arange(t, dtype=jnp.int32)              # (B, T)
+    kpos = (first * page)[:, None] \
+        + jnp.arange(n_pages * page, dtype=jnp.int32)[None, :]      # (B, S)
+    seen = (kpos[:, None, :] <= pos[:, :, None]) \
+        & (kpos[:, None, :] > pos[:, :, None] - window)
+    scores = jnp.where(seen[:, None, None, :, :], scores, _NEG_INF)
+    w = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bngts,bnsd->bngtd", w, v, precision=prec)
+    return out.reshape(b, h, t, d).astype(q.dtype)
+
+
 def paged_decode_attention(q, k_pool, v_pool, page_table, start_pos,
-                           scale=None, k_scale=None, v_scale=None):
+                           scale=None, k_scale=None, v_scale=None,
+                           window=None):
     """:func:`decode_attention` over K/V that stay in their page pools.
 
     q: (B, H, T, D); k_pool/v_pool: (P, KV, page, D) (f32, or int8 with
@@ -524,14 +593,34 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, start_pos,
     call (T == 1) the paged kernel covers reads the pages in place;
     anything else gathers the rows' pages into rings and goes through
     :func:`decode_attention` (a decode-shaped one counts as a fallback).
+
+    ``window``: position ``s`` attends iff ``start_pos[b] + t - window <
+    s <= start_pos[b] + t``, and ``page_table``'s N columns are a ring of
+    pages (logical page ``j`` in column ``j mod N``; ``N`` pages must
+    hold a window and a page, ``N >= ceil(window / page) + 1``). float32
+    pools alone.
     """
     global _LAST_PATH
     sc = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     decode = q.ndim == 4 and q.shape[2] == 1
+    if window is not None:
+        page, n = k_pool.shape[2], page_table.shape[1]
+        if k_scale is not None or n * page < window + page:
+            raise ValueError(
+                f"a window of {window} needs float32 pools and a ring of "
+                f"ceil(window / page) + 1 pages of {page}; got {n}")
     if decode and _FORCE_PATH != "xla" and _supports_paged(q, k_pool):
         _LAST_PATH = "pallas_paged"
         return _pallas_paged_decode(q, k_pool, v_pool, page_table,
-                                    start_pos, sc, k_scale, v_scale)
+                                    start_pos, sc, k_scale, v_scale, window)
+    if window is not None:
+        # a ring of pages has no ring-shaped gather: its own XLA path
+        _LAST_PATH = "xla"
+        if decode:
+            _record_fallback("forced_xla" if _FORCE_PATH == "xla"
+                             else "unsupported_shape", q.shape)
+        return _xla_window(q, k_pool, v_pool, page_table, start_pos, sc,
+                           window)
     from ..nn import gather_pages
 
     k, v = gather_pages(k_pool, page_table), gather_pages(v_pool, page_table)

@@ -83,8 +83,22 @@ class _LayerKV:
         return self._cache.quant_weights
 
     @property
+    def window(self):
+        return self._cache._windows[self._idx]
+
+    @property
     def page_table(self):
+        """The table of this layer's kind of pages: the ring of a layer
+        bounded by a window, else the whole sequence's."""
+        if self.window is not None:
+            return self._cache.window_table
         return self._cache.page_table
+
+    def token_live(self, t_len):
+        return self._cache.token_live(t_len)
+
+    def note_route(self, load):
+        self._cache.route_loads.append(load)
 
     # the layer's recurrent state (one row a sequence) and the step's
     # lane contract for it (ops/nn.py, the recurrent-state section)
@@ -124,7 +138,11 @@ class CacheLayout:
     The flat order is a layer at a time: ``k, v`` (``k, k_scale, v,
     v_scale`` when quantized), then the layer's state arrays. ``kinds``
     names each flat position ``"kv"`` (indexed by position: rings or
-    pages) or ``"state"`` (one row a sequence, never paged).
+    pages) or ``"state"`` (one row a sequence, never paged). ``windows``
+    gives, for each flat position, the window that bounds its layer's
+    keys (None: unbounded, and for state): a bounded layer's K/V are pages
+    of a second kind, a ring of :meth:`window_columns` a sequence under a
+    table of their own.
     """
 
     def __init__(self, model, quant=None):
@@ -138,22 +156,46 @@ class CacheLayout:
             raise MXNetError(f"unknown KV cache quant {quant!r}")
         self.layers = list(spec())
         self.quant = quant
-        self.kinds = []
+        self.kinds, self.windows = [], []
         for lay in self.layers:
-            self.kinds += ["kv"] * (4 if quant else 2)
+            n_kv = 4 if quant else 2
+            self.kinds += ["kv"] * n_kv
             self.kinds += ["state"] * len(lay.state)
+            self.windows += [lay.window] * n_kv + [None] * len(lay.state)
         self.has_state = "state" in self.kinds
+        bounds = {lay.window for lay in self.layers} - {None}
+        if len(bounds) > 1:
+            raise MXNetError(
+                f"layers bounded by different windows ({sorted(bounds)}): "
+                "the pool keeps one ring table for all bounded layers")
+        self.window = bounds.pop() if bounds else None
+        if self.window is not None and (self.window < 1 or quant):
+            raise MXNetError(
+                "a layer bounded by a window is served from float32 page "
+                f"pools (window {self.window}, quant {quant!r})")
 
     def __len__(self):
         return len(self.kinds)
 
-    def alloc(self, zeros, kv_lead, kv_seq, state_rows, dtype="float32"):
+    def window_columns(self, page_size):
+        """Pages in a bounded layer's ring: a window of positions and the
+        page being written."""
+        return -(-self.window // int(page_size)) + 1
+
+    def alloc(self, zeros, kv_lead, kv_seq, state_rows, dtype="float32",
+              window_lead=None):
         """The zeroed flat arrays: K/V of shape ``(kv_lead, kv_heads,
         kv_seq, head_dim)`` (rings: batch and max_seq; pools: pages and
-        page size), state arrays with ``state_rows`` leading rows."""
+        page size; ``window_lead`` pages for a bounded layer), state
+        arrays with ``state_rows`` leading rows."""
         out = []
         for lay in self.layers:
-            shape = (int(kv_lead), lay.kv_heads, int(kv_seq), lay.head_dim)
+            lead = kv_lead if lay.window is None else window_lead
+            if lead is None:
+                raise MXNetError(
+                    "a layer bounded by a window keeps its K/V in page "
+                    "pools alone (serve.PagedKVPool)")
+            shape = (int(lead), lay.kv_heads, int(kv_seq), lay.head_dim)
             if self.quant:
                 # four arrays, not two listed twice: a step that consumes
                 # its stores cannot be handed one buffer at two positions
@@ -183,6 +225,23 @@ def require_kv_only(model, what, missing):
         raise MXNetError(
             f"{what} cannot serve {type(model).__name__}: its layers keep "
             f"recurrent state beside K/V, and {missing}")
+
+
+def require_unbounded(model, what, missing):
+    """``what`` cannot serve a model with a layer whose K/V is bounded by
+    a window: refuse loudly, naming what is ``missing``."""
+    if CacheLayout(model).window is not None:
+        raise MXNetError(
+            f"{what} cannot serve {type(model).__name__}: a layer's keys "
+            f"are bounded by a window, and {missing}")
+
+
+# what and missing of :func:`require_unbounded` for what shares or replays
+# cache positions
+WINDOW_PAGES_GO = (
+    "its K/V lives in a ring of pages that are written over as the "
+    "sequence grows: a shared or replayed page may already hold later "
+    "positions (no snapshots of bounded layers)")
 
 
 # what and missing of :func:`require_kv_only` for the prefix cache, which
@@ -253,6 +312,15 @@ class KVCache:
         self.path = "baseline"
         self.quant_weights = None
         self.page_table = None
+        # per layer, the window that bounds its keys (None: unbounded),
+        # and the ring table of the bounded layers' pages; both set by a
+        # step that serves such a model
+        self._windows = [None] * len(self._k)
+        self.window_table = None
+        # the step's last real position a row (``token_live``), and what
+        # the routed-expert layers noted of their load in this call
+        self.last_idx = None
+        self.route_loads = []
         # the step's lane contract for the recurrent state, set by the
         # serving step before the model forward like ``path``: valid
         # positions of each row in this call, and which rows are live
@@ -274,6 +342,20 @@ class KVCache:
     @property
     def num_layers(self):
         return len(self._k)
+
+    def token_live(self, t_len):
+        """(B, T) bool, which tokens of this call are real: a row whose
+        pages are the null page is a dead lane, a position past
+        ``last_idx`` is a chunk's padding. None outside the engine's
+        in-place step (every token is real)."""
+        if self.page_table is None or self.last_idx is None:
+            return None
+        from .. import numpy as mnp
+
+        lane = self.page_table[:, 0:1] != 0                       # (B, 1)
+        real = mnp.arange(t_len, dtype="int32").reshape(1, -1) \
+            <= self.last_idx.reshape(-1, 1)                       # (B, T)
+        return lane * real
 
     @property
     def batch(self):
@@ -325,8 +407,11 @@ class KVCache:
             return cls([r[0] for r in rings], [r[2] for r in rings], max_seq,
                        [r[1] for r in rings], [r[3] for r in rings], quant,
                        states=states)
-        return cls([r[0] for r in rings], [r[1] for r in rings], max_seq,
-                   states=states)
+        out = cls([r[0] for r in rings], [r[1] for r in rings], max_seq,
+                  states=states)
+        if layout is not None:
+            out._windows = [lay.window for lay in layout.layers]
+        return out
 
     def nbytes(self):
         return sum(_nbytes(a) for a in self.flat())
@@ -387,6 +472,15 @@ class _CacheForward(HybridBlock):
     count of real positions (``last_idx + 1``), past which a padded
     prefill chunk leaves the state alone. A model with K/V alone keeps
     the convention, and the traced program, it always had.
+
+    A model with a layer bounded by a window (in-place only) adds a
+    ``window_table`` (B, C) arg right after ``page_table``: the ring of
+    the bounded layers' pages, ``C`` columns a row
+    (``CacheLayout.window_columns``); each layer is handed its kind's
+    table. A model with routed-expert layers returns, right after the
+    logits, one (layers, 3) int32 array of what each such layer noted of
+    its load (experts hit, most tokens on one expert, assignments) over
+    the call's real tokens; a model with neither adds no argument and no output.
     """
 
     def __init__(self, model, max_seq, path="baseline", quant=None,
@@ -404,18 +498,28 @@ class _CacheForward(HybridBlock):
         if self._inplace and (not self._paged or path == "baseline"):
             raise MXNetError("the in-place step is the paged fast rungs'")
         self._layout = CacheLayout(model, quant)
+        self._windowed = self._layout.window is not None
+        if self._windowed and not self._inplace:
+            raise MXNetError(
+                f"{type(model).__name__} has a layer whose keys are bounded "
+                "by a window: its K/V lives in a ring of pages, which the "
+                "continuous engine's in-place step (decode_path 'pallas') "
+                "alone serves; ring caches and the strict rung do not")
         # the call's positions that the step consumes and returns (read
         # by CachedOp): the cache stores, after tokens, start_pos,
-        # last_idx, the page table and the lanes
-        first = 3 + int(self._paged) + int(self._layout.has_state)
+        # last_idx, the page table(s) and the lanes
+        first = 3 + int(self._paged) + int(self._windowed) \
+            + int(self._layout.has_state)
         self.donate_args = (tuple(range(first, first + len(self._layout)))
                             if self._inplace else ())
 
     def forward(self, tokens, start_pos, last_idx, *rest):
         layout = self._layout
-        page_table = lanes = None
+        page_table = window_table = lanes = None
         if self._paged:
             page_table, rest = rest[0], rest[1:]
+        if self._windowed:
+            window_table, rest = rest[0], rest[1:]
         if layout.has_state:
             lanes, rest = rest[0], rest[1:]
         stores = rest[:len(layout)]
@@ -433,6 +537,8 @@ class _CacheForward(HybridBlock):
         cache.path = self._path
         if self._inplace:
             cache.page_table = page_table
+            cache.window_table = window_table
+            cache.last_idx = last_idx
         if layout.has_state:
             # the lane contract of the recurrent-state ops: the real
             # positions of a row are 0 .. last_idx, and a row whose lane
@@ -464,6 +570,10 @@ class _CacheForward(HybridBlock):
             # the (k+1)-token block, not just the last real one
             return (logits,) + updated
         last = _ops.gather_positions(logits, last_idx)
+        if self._inplace and cache.route_loads:
+            from .. import numpy as mnp
+
+            return (last, mnp.stack(cache.route_loads)) + updated
         return (last,) + updated
 
 
@@ -573,6 +683,8 @@ class _MultiStepForward(HybridBlock):
         self._quant = quant
         self._qindex = list(qindex)
         self._paged = bool(paged)
+        require_unbounded(model, "multi-step decode (multistep=True)",
+                          WINDOW_PAGES_GO)
         require_kv_only(
             model, "multi-step decode (multistep=True)",
             "a lane that finished inside the compiled loop would have to "
@@ -824,6 +936,13 @@ class Generator:
             prefix_cache = bool(config.get("MXNET_SERVE_PREFIX_CACHE"))
         self._prefix_on = bool(prefix_cache)
         self._layout = CacheLayout(model, self._quant)
+        # rings hold a position for good: a bounded layer's K/V is a ring
+        # of pages, which the continuous engine alone serves
+        require_unbounded(
+            model, "serve.Generator",
+            "its ring caches keep every position of a sequence: the "
+            "continuous engine (serve.ContinuousEngine, decode_path "
+            "'pallas') serves such a model from rings of pages")
         if self._prefix_on:
             require_kv_only(model, *PREFIX_CACHE_NEEDS)
         if self._prefix_on and paged is False:
@@ -1542,6 +1661,9 @@ class SpeculativeGenerator:
         if self.k < 1:
             raise MXNetError("speculative decoding needs k >= 1")
         for m in (model, draft_model):
+            require_unbounded(
+                m, "speculative decoding (SpeculativeGenerator)",
+                WINDOW_PAGES_GO)
             require_kv_only(
                 m, "speculative decoding (SpeculativeGenerator)",
                 "a rejected proposal rolls a row's position back, which a "

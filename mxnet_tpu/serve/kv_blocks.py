@@ -50,6 +50,22 @@ read-only by construction (``paged_kv_scatter`` only writes rows at
 position starts past the shared boundary), so the first divergent
 token always lands in a slot-private page and no copy is ever needed.
 
+**Two kinds of pages.** A layer whose keys are bounded by a window
+(``LayerCache.window``) never needs more than a window of positions and
+the page being written, so its K/V does not grow with the sequence. Such
+layers share a second, narrower table, the *ring table* of
+``ceil(window / page) + 1`` columns a slot, and each has pools of
+``num_slots * columns + 1`` pages of its own (page 0 the null page, as
+above). Logical page ``j`` of a sequence lies in column ``j mod columns``:
+the page that position ``t`` is written to takes the place of the one
+``columns`` pages before it, every position of which is out of the window
+of ``t`` and of every later query, and not before. A slot is given
+``min(pages_for(budget), columns)`` ring pages with its other pages at
+admission and hands them back at release; the budget of the ring pools is
+fixed, so they are never what admission waits for. The prefix cache never
+sees them (``ContinuousEngine`` refuses it for such a model): a ring page
+is rewritten while its slot lives.
+
 Page size defaults to the Pallas decode kernel's natural block
 (``ops.pallas.decode_attention.natural_block()`` = 128, clamped to
 ``max_seq``), so the kernel's block-skip masking skips whole unreached
@@ -141,6 +157,11 @@ class PagedKVPool:
                 f"{self.num_pages}")
         self.layout = CacheLayout(model, quant)
         self.quant = quant
+        # the ring pages of the layers bounded by a window: a table of
+        # their own, a fixed budget (every slot's whole ring), a free list
+        self.window_columns = (self.layout.window_columns(self.page_size)
+                               if self.layout.window is not None else 0)
+        self.window_pages = self.num_slots * self.window_columns + 1
         # a layer at a time, in the layout's flat order (KVCache.flat()'s
         # too, so _CacheForward's calling convention is shared between
         # ring and paged steps): (P, KV, page, D) k/v pools, with
@@ -157,6 +178,10 @@ class PagedKVPool:
         self._table = _onp.zeros((self.num_slots, self.pages_per_slot),
                                  _onp.int32)
         self._table_nd = None
+        self._wfree = list(range(self.window_pages - 1, 0, -1))
+        self._wowned = [[] for _ in range(self.num_slots)]
+        self._wtable = _onp.zeros((self.num_slots, self.window_columns),
+                                  _onp.int32)
         self.high_water = 0
         self.exhausted_count = 0
 
@@ -192,13 +217,21 @@ class PagedKVPool:
 
         self._arrays = self.layout.alloc(
             functools.partial(mnp.zeros, ctx=self.ctx), self.num_pages,
-            self.page_size, self.num_slots)
+            self.page_size, self.num_slots, window_lead=self.window_pages)
 
     def table(self):
         """Copy of the canonical (num_slots, pages_per_slot) int32 page
         table. Rows of released slots are all-null (0)."""
         with self._lock:
             return self._table.copy()
+
+    def window_table(self):
+        """Copy of the (num_slots, window_columns) int32 ring table of
+        the layers bounded by a window (no column for a model that has
+        none). Column ``j mod window_columns`` of a row holds the
+        sequence's logical page ``j``."""
+        with self._lock:
+            return self._wtable.copy()
 
     def table_nd(self):
         """The canonical page table as a cached device NDArray — for
@@ -292,6 +325,14 @@ class PagedKVPool:
             self._owned[slot] = pages
             self._table[slot] = 0
             self._table[slot, :need] = pages
+            if self.window_columns:
+                # the slot's ring: never short (the budget is every
+                # slot's whole ring), never shared
+                ring = [self._wfree.pop()
+                        for _ in range(min(need, self.window_columns))]
+                self._wowned[slot] = ring
+                self._wtable[slot] = 0
+                self._wtable[slot, :len(ring)] = ring
             self._table_nd = None
             used = self.pages_used
             if used > self.high_water:
@@ -318,6 +359,9 @@ class PagedKVPool:
             self._decref_locked(pages)
             self._table[slot] = 0
             self._table_nd = None
+            ring, self._wowned[slot] = self._wowned[slot], []
+            self._wfree.extend(reversed(ring))
+            self._wtable[slot] = 0
             return len(pages)
 
     # -- reference counting (prefix-cache sharing) ---------------------------
@@ -391,6 +435,13 @@ class PagedKVPool:
         keeps K/V alone)."""
         return self.layout.state_nbytes(self._arrays)
 
+    def window_nbytes(self):
+        """The ring pools' part of :meth:`nbytes`: the K/V of the layers
+        bounded by a window (0 for a model that has none)."""
+        return sum(int(_onp.prod(a.shape)) * _onp.dtype(a.dtype).itemsize
+                   for a, w in zip(self._arrays, self.layout.windows)
+                   if w is not None)
+
     def stats(self):
         with self._lock:
             free = len(self._free)
@@ -405,4 +456,8 @@ class PagedKVPool:
                 "high_water": self.high_water,
                 "exhausted_count": self.exhausted_count,
                 "nbytes": self.nbytes(),
-                "state_nbytes": self.state_nbytes()}
+                "state_nbytes": self.state_nbytes(),
+                "window_nbytes": self.window_nbytes(),
+                "window_columns": self.window_columns,
+                "window_pages_used": self.window_pages - 1
+                - len(self._wfree)}

@@ -59,10 +59,10 @@ from ..resilience import faults as _faults
 from .batcher import DynamicBatcher
 from .engine import DeadlineExceeded, InferenceSession, PoolExhausted, \
     ServeError, ServiceUnavailable, on_block_context
-from .generate import PREFIX_CACHE_NEEDS, _CacheForward, _MultiStepForward, \
-    _STOP_WIDTH, _fresh_key_bits, _int8_weights_enabled, \
+from .generate import PREFIX_CACHE_NEEDS, WINDOW_PAGES_GO, _CacheForward, \
+    _MultiStepForward, _STOP_WIDTH, _fresh_key_bits, _int8_weights_enabled, \
     _quantize_serving_weights, _stop_matrix, gather_rings, require_kv_only, \
-    resolve_decode_path, sample_tokens, scatter_rings
+    require_unbounded, resolve_decode_path, sample_tokens, scatter_rings
 from .kv_blocks import PagedKVPool
 from .prefix_cache import PrefixCache
 
@@ -132,7 +132,14 @@ class ContinuousEngine:
         comes back as it went in, a padded prefill position does not
         advance it, a request starts from zero), and ``prefix_cache``
         and ``multistep`` raise: neither can be done to a state without
-        snapshots of it.
+        snapshots of it. A layer whose keys are bounded by a window
+        (``LayerCache.window``) keeps its K/V in a ring of pages of a
+        second kind, under a table of its own that the step is handed
+        beside the first; ``prefix_cache``, ``multistep`` and the strict
+        rung raise for such a model (a ring page is written over while
+        its request lives), and the prefill chunk must lie inside one
+        page. Routed-expert layers have their load read back once a
+        decode step (``stats()["moe"]``).
     max_seq : per-request logical ring length (prompt + generated tokens
         must fit); must be a whole number of KV pages.
     num_slots : decode lanes — the ONE compiled decode width
@@ -185,6 +192,19 @@ class ContinuousEngine:
             prefix_cache = bool(config.get("MXNET_SERVE_PREFIX_CACHE"))
         if prefix_cache:
             require_kv_only(model, *PREFIX_CACHE_NEEDS)
+            require_unbounded(model, PREFIX_CACHE_NEEDS[0], WINDOW_PAGES_GO)
+        layout = self.pool.layout
+        self._windowed = layout.window is not None
+        if self._windowed and self.pool.page_size % self.prefill_chunk:
+            raise MXNetError(
+                f"prefill_chunk ({self.prefill_chunk}) must divide the KV "
+                f"page ({self.pool.page_size}) for a model with a layer "
+                "bounded by a window: a chunk writes inside one column of "
+                "the ring of pages and reads the columns before it")
+        # layers of each kind, for the decode span's kv_positions_* stats
+        self._n_window_layers = sum(lay.window is not None
+                                    for lay in layout.layers)
+        self._n_full_layers = len(layout.layers) - self._n_window_layers
         self.prefix = (PrefixCache(self.pool, name=f"{name}_prefix")
                        if prefix_cache else None)
         # fast rungs fuse the paging brackets into the step executable;
@@ -201,6 +221,15 @@ class ContinuousEngine:
             inplace=self._fused_paged)
         self._inplace_steps = 0       # step calls that consumed the pool
         self._pool_reallocations = 0  # pools lost to a failed call
+        # routed-expert layers: the load arrays of the calls not read
+        # back yet, and the totals of those that were
+        self._route_pending = []
+        self._moe = None
+        self._window_recycled = 0     # ring columns written over
+        if self._windowed:
+            for kind, n in self._kv_pool_bytes().items():
+                _prof.set_counter(f"serve.kv_pool_bytes_{kind}", n,
+                                  cat="serve")
         # exactly two live signatures: (1, chunk) chunked prefill and
         # (num_slots, 1) decode — the whole point of the design
         self.session = InferenceSession(
@@ -408,13 +437,15 @@ class ContinuousEngine:
             toks = mnp.array(_onp.asarray(tokens, _onp.int32))
             sp = mnp.array(_onp.asarray(start_pos, _onp.int32))
             li = mnp.array(_onp.asarray(last_idx, _onp.int32))
-            tab = mnp.array(_onp.asarray(table, _onp.int32))
+            tab = [mnp.array(_onp.asarray(table, _onp.int32))]
+            if self._windowed:
+                tab.append(mnp.array(self._window_rows(lanes)))
             ln = ([mnp.array(_onp.asarray(lanes, _onp.int32))]
                   if stateful else [])
         with host_span("mxnet_tpu.serve.dispatch"):
             if self._fused_paged:
                 try:
-                    out = self.session.run(toks, sp, li, tab, *ln,
+                    out = self.session.run(toks, sp, li, *tab, *ln,
                                            *self.pool.flat(), *self._qflat)
                 except Exception as exc:  # pylint: disable=broad-except
                     # a call that failed before dispatch left the pool
@@ -424,7 +455,14 @@ class ContinuousEngine:
                     if self.pool.lost():
                         self._recover_pool(exc)
                     raise
-                flat = out[1:]
+                # a model with routed-expert layers hands their load back
+                # right after the logits (_CacheForward)
+                flat = out[len(out) - len(self.pool.layout):]
+                if len(out) - len(flat) > 1:
+                    # its copy to the host starts now and rides behind the
+                    # step, so that the read after the fetch waits for none
+                    out[1]._data.copy_to_host_async()
+                    self._route_pending.append(out[1])
                 if self._step_block.donate_args:
                     self._inplace_steps += 1
                     _prof.incr_counter("serve.pool_inplace_steps",
@@ -434,11 +472,11 @@ class ContinuousEngine:
                 # ops around the unchanged ring executable (bitwise
                 # contract)
                 layout = self.pool.layout
-                rings = gather_rings(layout, self.pool.flat(), tab)
+                rings = gather_rings(layout, self.pool.flat(), tab[0])
                 out = self.session.run(toks, sp, li, *ln, *rings,
                                        *self._qflat)
-                flat = scatter_rings(layout, self.pool.flat(), tab, out[1:],
-                                     sp, toks.shape[1])
+                flat = scatter_rings(layout, self.pool.flat(), tab[0],
+                                     out[1:], sp, toks.shape[1])
         with host_span("mxnet_tpu.serve.pool_update"):
             # the pages' and the state rows' swap alike
             self.pool.update_from_flat(flat)
@@ -446,6 +484,69 @@ class ContinuousEngine:
             live = int((_onp.asarray(lanes) >= 0).sum())
             _prof.incr_counter("serve.state_lane_steps", live, cat="serve")
         return out[0]
+
+    def _kv_pool_bytes(self):
+        """Bytes of the K/V page pools by kind of layer: unbounded
+        (``full``) and bounded by a window (``window``)."""
+        win = self.pool.window_nbytes()
+        return {"full": self.pool.nbytes() - win - self.pool.state_nbytes(),
+                "window": win}
+
+    def _window_rows(self, lanes):
+        """The ring table of a call: row ``r`` is slot ``lanes[r]``'s
+        ring, a row that is not live (-1) all null."""
+        lanes = _onp.asarray(lanes)
+        live = lanes >= 0
+        out = _onp.zeros((len(lanes), self.pool.window_columns), _onp.int32)
+        out[live] = self.pool.window_table()[lanes[live]]
+        return out
+
+    def _note_recycled(self, first_pos, n):
+        """Count the ring columns that writing positions ``first_pos ..
+        first_pos + n - 1`` of one sequence starts over: a page past the
+        ring's first lap takes the place of the one a lap before it."""
+        page, cols = self.pool.page_size, self.pool.window_columns
+        first = -(-first_pos // page)           # first page started here
+        last = (first_pos + n - 1) // page
+        k = max(0, last - max(first, cols) + 1)
+        if k:
+            self._window_recycled += k
+            _prof.incr_counter("serve.window_pages_recycled", k, cat="serve")
+
+    def _read_routes(self):
+        """Read back the expert loads of the calls since the last read,
+        where a step's result has just been fetched and waited for (the
+        arrays are results of calls the device has finished): one
+        ``mxnet_tpu.serve.route`` span a read, with the calls' totals."""
+        if not self._route_pending:
+            return
+        pending, self._route_pending = self._route_pending, []
+        with host_span("mxnet_tpu.serve.route") as span:
+            # (calls, layers, [experts hit, most on one, assignments])
+            load = _onp.stack([_onp.asarray(a.asnumpy(), _onp.int64)
+                               for a in pending])
+            hit = int(load[..., 0].sum())
+            top = int(load[..., 1].max())
+            assignments = int(load[..., 2].sum())
+            # the most loaded expert over the mean of those hit, at worst
+            skew = float((load[..., 1] * load[..., 0]
+                          / _onp.maximum(load[..., 2], 1)).max())
+            span.set_metadata(experts_hit=hit, assignments=assignments,
+                              max_load=top, calls=len(pending))
+        m = self._moe
+        if m is None:
+            m = self._moe = {"reads": 0, "calls": 0, "assignments": 0,
+                             "experts_hit": 0, "max_load": 0,
+                             "load_max_over_mean": 0.0}
+        m["reads"] += 1
+        m["calls"] += len(pending)
+        m["assignments"] += assignments
+        m["experts_hit"] += hit
+        m["max_load"] = max(m["max_load"], top)
+        m["load_max_over_mean"] = skew
+        _prof.incr_counter("serve.moe_assignments", assignments, cat="serve")
+        _prof.incr_counter("serve.moe_experts_hit", hit, cat="serve")
+        _prof.set_counter("serve.moe_load_max_over_mean", skew, cat="serve")
 
     def _recover_pool(self, error):
         """The pool's buffers went with a failed or timed-out call (it
@@ -498,6 +599,8 @@ class ContinuousEngine:
                                            cat="serve")
                     logits = self._run_step(toks, [s.consumed], [n - 1],
                                             table, [i])
+                    if self._windowed:
+                        self._note_recycled(s.consumed, n)
                 except Exception as e:
                     pf_args["error"] = type(e).__name__
                     raise
@@ -521,6 +624,7 @@ class ContinuousEngine:
         with host_span("mxnet_tpu.serve.sample"):
             tid = int(sample_tokens(logits, temperature=s.temperature,
                                     top_k=s.top_k)[0])
+        self._read_routes()
         with host_span("mxnet_tpu.serve.settle", tokens=1):
             now = time.monotonic()
             s.ttft_ms = (now - s.p.t_enq) * 1e3
@@ -543,7 +647,17 @@ class ContinuousEngine:
             # the next decode step's ITL restarts from its own window
             self._last_emit_t = None
             return
-        with host_span("mxnet_tpu.serve.decode", live=len(decoding)):
+        stats = {}
+        if self._windowed:
+            # K/V positions the step's attention reads, by kind of layer
+            # (the host knows every lane's position)
+            w = self.pool.layout.window
+            at = [self._slots[i].pos + 1 for i in decoding]
+            stats = {"kv_positions_full": sum(at) * self._n_full_layers,
+                     "kv_positions_window": sum(min(t, w) for t in at)
+                     * self._n_window_layers}
+        with host_span("mxnet_tpu.serve.decode", live=len(decoding),
+                       **stats):
             self._decode_step(decoding)
 
     def _decode_step(self, decoding):
@@ -581,6 +695,9 @@ class ContinuousEngine:
                 logits = self._run_step(toks, pos,
                                         _onp.zeros(S, _onp.int32), table,
                                         lanes)
+                if self._windowed:
+                    for i in decoding:
+                        self._note_recycled(self._slots[i].pos, 1)
                 t2 = time.perf_counter()
                 w2 = _attr.thread_wait_ns() if attributing else 0
                 with host_span("mxnet_tpu.serve.sample"):
@@ -600,6 +717,7 @@ class ContinuousEngine:
                             sampled[i] = int(sample_tokens(
                                 arr[i:i + 1], temperature=s.temperature,
                                 top_k=s.top_k)[0])
+                self._read_routes()
                 with host_span("mxnet_tpu.serve.settle",
                                tokens=len(decoding)):
                     now = time.monotonic()
@@ -999,6 +1117,12 @@ class ContinuousEngine:
         # from the model, and those that made no RNG key (CachedOp)
         for k in ("fast_calls", "keys_skipped"):
             out[k] = sum(c[k] for c in caches)
+        if self._windowed:
+            for kind, n in self._kv_pool_bytes().items():
+                out[f"kv_pool_bytes_{kind}"] = n
+            out["window_pages_recycled"] = self._window_recycled
+        if self._moe is not None:
+            out["moe"] = dict(self._moe)
         out["slots_live"] = len(self._live())
         out["slots_total"] = self.num_slots
         out["admit_wait_steps_max"] = self._admit_wait_max

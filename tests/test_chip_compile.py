@@ -186,15 +186,32 @@ def test_dropout_partitions_over_four_chips(topo):
 # 32 heads over 8 KV heads, rings of 2,048; falcon_h1_34b, 32 lanes of 20
 # heads over 4, rings of 512, a Mamba-2 state beside them. Pages of 128.
 
+# mellum2_12b_a2_5b (PR 33): 16 lanes of 32 heads over 4, rings of 3,584;
+# three layers bounded by a window of 1,024 (a ring of 9 pages a lane
+# under a table of its own) to one full layer, 64 routed experts of
+# 2304 x 896 in every block.
+
 _PAGE = 128
 _CELLS = {"mistral": dict(lanes=8, heads=32, kv=8, max_seq=2048),
-          "falcon": dict(lanes=32, heads=20, kv=4, max_seq=512)}
+          "falcon": dict(lanes=32, heads=20, kv=4, max_seq=512),
+          "mellum": dict(lanes=16, heads=32, kv=4, max_seq=3584)}
+_WINDOW = 1024
 
 
 def _cell_model(cell, layers=1):
     from mxnet_tpu.models.falcon_h1 import FalconH1Model
     from mxnet_tpu.models.llama import LlamaModel
+    from mxnet_tpu.models.mellum import MellumModel
 
+    if cell == "mellum":
+        yarn = ("yarn", 16, 8192, 32, 1, 1.2772588722239782)
+        return MellumModel(
+            vocab_size=98304, units=2304, num_heads=32, num_kv_heads=4,
+            head_dim=128, sliding_window=_WINDOW, expert_size=896,
+            num_experts=64, num_experts_per_tok=8,
+            layer_types=["sliding_attention", "full_attention"][:layers + 1],
+            rope={"sliding_attention": (500000.0, None),
+                  "full_attention": (500000.0, yarn)})
     if cell == "mistral":
         return LlamaModel(vocab_size=32000, units=4096, hidden_size=14336,
                           num_heads=32, num_kv_heads=8, num_layers=layers)
@@ -232,6 +249,27 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, cell, int8):
     assert "tpu_custom_call" in text
 
 
+def test_windowed_paged_decode_kernel_compiles_for_v5e(one_chip):
+    """The paged kernel over a ring of 9 pages under a window of 1,024,
+    at the Mellum-2 cell's widths."""
+    c = _CELLS["mellum"]
+    cols = _WINDOW // _PAGE + 1
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    k = sds((c["lanes"] * cols + 1, c["kv"], _PAGE, 128), jnp.float32)
+
+    def fn(q, k, v, table, sp):
+        return da._pallas_paged_decode(q, k, v, table, sp, 128 ** -0.5,
+                                       None, None, _WINDOW)
+
+    text = _compile(fn, sds((c["lanes"], c["heads"], 1, 128), jnp.float32),
+                    k, k, sds((c["lanes"], cols), jnp.int32),
+                    sds((c["lanes"],), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
 def _inplace_step(cell, rows, t_len, path, one_chip):
     """The engine's in-place step over ``cell``'s model (one layer, every
     width real, parameters never materialized), compiled for the
@@ -254,9 +292,12 @@ def _inplace_step(cell, rows, t_len, path, one_chip):
 
     layout = CacheLayout(net, quant)
     n_pages = c["max_seq"] // _PAGE
-    stores = layout.alloc(sds, c["lanes"] * n_pages + 1, _PAGE, c["lanes"])
+    cols = layout.window_columns(_PAGE) if layout.window else 0
+    stores = layout.alloc(sds, c["lanes"] * n_pages + 1, _PAGE, c["lanes"],
+                          window_lead=c["lanes"] * cols + 1)
     args = [sds((rows, t_len), "int32"), sds((rows,), "int32"),
             sds((rows,), "int32"), sds((rows, n_pages), "int32")]
+    args += [sds((rows, cols), "int32")] * bool(cols)
     args += [sds((rows,), "int32")] * layout.has_state
     first = len(args)
     assert step.donate_args == tuple(range(first, first + len(stores)))
@@ -265,7 +306,9 @@ def _inplace_step(cell, rows, t_len, path, one_chip):
     fn = jax.jit(lambda p, *a: apply_fn(p, *a, rng_key=key),
                  donate_argnums=tuple(1 + i for i in step.donate_args))
     text = fn.lower(params, *args, *stores).compile().as_text()
-    pairs = {(1 + j, len(params) + first + j) for j in range(len(stores))}
+    # the stores follow the logits and, for a model that routes, its load
+    out0 = 1 + (cell == "mellum")
+    pairs = {(out0 + j, len(params) + first + j) for j in range(len(stores))}
     return text, stores, pairs
 
 
@@ -294,7 +337,8 @@ def _no_copy_of(stores, text):
 
 
 @pytest.mark.parametrize("cell,path", [
-    ("mistral", "pallas"), ("mistral", "int8"), ("falcon", "pallas")])
+    ("mistral", "pallas"), ("mistral", "int8"), ("falcon", "pallas"),
+    ("mellum", "pallas")])
 def test_decode_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell,
                                                path):
     """The (lanes, 1) decode step: every cache store is an input-output
@@ -314,7 +358,7 @@ def test_decode_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell,
     _no_copy_of(stores, text)
 
 
-@pytest.mark.parametrize("cell", ["mistral", "falcon"])
+@pytest.mark.parametrize("cell", ["mistral", "falcon", "mellum"])
 def test_prefill_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell):
     """The (1, 128) prefill chunk: no Mosaic call (the benchmark tells the
     two step executables apart by it), every store aliased, no copy of a
