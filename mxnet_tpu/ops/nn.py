@@ -1463,6 +1463,43 @@ def state_rows_scatter(store, lanes, rows):
     return _apply(f, (store, lanes, rows), name="state_rows_scatter")
 
 
+# ---------------------------------------------------------------------------
+# The greedy ids a serving step keeps on the device. ``ids`` (R,) int32
+# holds, one row a slot, the argmax of the last logits a call made for
+# that slot; ``rows`` (B,) int32 names each batch row's row of ``ids``,
+# negative for a batch row that is bound to none in this call.
+# ---------------------------------------------------------------------------
+
+
+def carried_tokens(tokens, ids, rows):
+    """``tokens`` (B, T) with every negative entry replaced by its batch
+    row's carried id: the per-row choice between a token the host sent
+    and the one the call before left on the device."""
+
+    def f(tok, kept, at):
+        jnp = _jnp()
+        mine = jnp.take(kept, jnp.maximum(at.astype(jnp.int32), 0))
+        return jnp.where(tok < 0, mine[:, None].astype(tok.dtype), tok)
+
+    return _apply(f, (tokens, ids, rows), name="carried_tokens")
+
+
+def keep_greedy_ids(ids, rows, logits):
+    """``ids`` with the argmax of each bound batch row's ``logits``
+    (B, V) written at its row, as int32, ties to the lowest index (what
+    ``serve.generate.sample_tokens`` picks from the same row); a batch
+    row bound to none is dropped."""
+
+    def f(kept, at, lg):
+        jnp = _jnp()
+        at = at.astype(jnp.int32)
+        best = jnp.argmax(lg, axis=-1).astype(kept.dtype)
+        at = jnp.where(at >= 0, at, kept.shape[0])   # out of range: dropped
+        return kept.at[at].set(best, mode="drop")
+
+    return _apply(f, (ids, rows, logits), name="keep_greedy_ids")
+
+
 def grouped_rms_norm(data, gamma, groups=1, eps=1e-6):
     """RMSNorm over each of ``groups`` equal slices of the last axis
     (Mamba-2's gated norm with ``n_groups`` > 1), then the gain."""

@@ -12,11 +12,14 @@ exclusive phases:
   admit/retire work between device calls (the *schedule* bucket);
 * **dispatch** — issuing the step executable (async: the call returns
   before the device finishes);
-* **device**   — the delta around the blocking fetch of the step's
-  logits (argmax/sample + device->host copy): a host-clock estimate.
-  The device's own time is in a ``jax.profiler`` capture, where the
-  engine's host spans (``core.host_span``) lie on the same clock
-  (OBSERVABILITY.md, "Host spans on the device trace's clock");
+* **device**   — the time the host waited at the blocking fetch of a
+  step's result: a host-clock estimate. Where the engine enqueued the
+  visit ahead (one decode visit in flight) the fetch is that of the
+  visit before, with the device busy behind it, so the phase is what
+  the host could not hide. The device's own time is in a
+  ``jax.profiler`` capture, where the engine's host spans
+  (``core.host_span``) lie on the same clock (OBSERVABILITY.md, "Host
+  spans on the device trace's clock");
 * **wait**     — ``engine:wait`` stalls *outside* the sanctioned
   blocking fetch, fed by the (now phase-tagged) wait hooks in
   ``engine.py``.

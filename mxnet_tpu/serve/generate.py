@@ -481,6 +481,19 @@ class _CacheForward(HybridBlock):
     logits, one (layers, 3) int32 array of what each such layer noted of
     its load (experts hit, most tokens on one expert, assignments) over
     the call's real tokens; a model with neither adds no argument and no output.
+
+    The in-place step also samples greedily inside itself and keeps the
+    ids on the device. It takes two more arguments before the cache
+    stores, ``keep`` (B,) int32 and ``ids`` (rows,) int32, and hands
+    ``ids`` back right before them: the argmax of each batch row's last
+    logits (``jnp.argmax`` over the same float32 row, ties to the lowest
+    index: the token ``sample_tokens`` picks), written at row
+    ``keep[b]`` of ``ids``, a row whose ``keep`` is negative writing
+    none. A negative entry of ``tokens`` stands for "this row's carried
+    id", ``ids[keep[b]]``: so the call that consumes a token can be
+    enqueued before the call that made it has been fetched. ``ids`` is
+    **not** donated: the host reads a call's ``ids`` after later calls
+    have been handed them.
     """
 
     def __init__(self, model, max_seq, path="baseline", quant=None,
@@ -507,9 +520,9 @@ class _CacheForward(HybridBlock):
                 "alone serves; ring caches and the strict rung do not")
         # the call's positions that the step consumes and returns (read
         # by CachedOp): the cache stores, after tokens, start_pos,
-        # last_idx, the page table(s) and the lanes
+        # last_idx, the page table(s), the lanes, and keep and ids
         first = 3 + int(self._paged) + int(self._windowed) \
-            + int(self._layout.has_state)
+            + int(self._layout.has_state) + 2 * int(self._inplace)
         self.donate_args = (tuple(range(first, first + len(self._layout)))
                             if self._inplace else ())
 
@@ -522,6 +535,9 @@ class _CacheForward(HybridBlock):
             window_table, rest = rest[0], rest[1:]
         if layout.has_state:
             lanes, rest = rest[0], rest[1:]
+        if self._inplace:
+            keep, ids, rest = rest[0], rest[1], rest[2:]
+            tokens = _ops.carried_tokens(tokens, ids, keep)
         stores = rest[:len(layout)]
         qflat = rest[len(layout):]
         ringed = self._paged and not self._inplace
@@ -570,11 +586,14 @@ class _CacheForward(HybridBlock):
             # the (k+1)-token block, not just the last real one
             return (logits,) + updated
         last = _ops.gather_positions(logits, last_idx)
-        if self._inplace and cache.route_loads:
+        if not self._inplace:
+            return (last,) + updated
+        head = (last,)
+        if cache.route_loads:
             from .. import numpy as mnp
 
-            return (last, mnp.stack(cache.route_loads)) + updated
-        return (last,) + updated
+            head += (mnp.stack(cache.route_loads),)
+        return head + (_ops.keep_greedy_ids(ids, keep, last),) + updated
 
 
 def sample_tokens(logits, temperature=0.0, top_k=None):

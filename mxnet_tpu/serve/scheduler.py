@@ -25,6 +25,13 @@ lanes, and between any two decode steps it
   through without ever stalling live decodes for more than one chunk;
 * **decodes** every live slot in ONE fixed ``(num_slots, 1)`` step.
 
+On the in-place step (the fast rungs) the engine keeps one decode visit
+in flight: the step samples greedily inside itself and leaves each
+lane's id on the device, so visit N+1 is enqueued before visit N's ids
+are fetched and the host's part of a step runs behind the device. A
+sampled lane or the strict rung makes the same loop fetch first (see
+:meth:`ContinuousEngine.step`).
+
 KV state lives in a :class:`~.kv_blocks.PagedKVPool`: per-layer page
 pools plus a per-slot page table, gathered/scattered around the unchanged
 model cache path (fused into the step executable on the fast rungs,
@@ -77,7 +84,7 @@ class _Slot:
     __slots__ = ("p", "prompt", "consumed", "pos", "decoding", "pending",
                  "tokens", "max_new", "temperature", "top_k", "stop",
                  "finished", "expired", "t_admit", "admit_wait_steps",
-                 "ttft_ms", "decode_steps", "seed", "token_ms")
+                 "ttft_ms", "decode_steps", "seed", "token_ms", "inflight")
 
     def __init__(self, p, steps_now, seed=0):
         payload = p.payload
@@ -87,6 +94,7 @@ class _Slot:
         self.pos = 0               # ring write position once decoding
         self.decoding = False      # prefill complete, pending token live
         self.pending = 0           # next token id to feed the decode step
+        self.inflight = 0          # tokens dispatched for, not fetched yet
         self.tokens = []           # emitted output ids
         self.token_ms = []         # each kept token's ms since enqueue
         self.max_new = payload["max_new"]
@@ -117,6 +125,38 @@ class _Slot:
             self.finished = True
         else:
             self.pending = tid
+
+
+class _Flight:
+    """One call of the step whose tokens the host has not read yet: a
+    decode visit, or the last chunk of a prompt. ``riders`` are the
+    ``(slot index, _Slot)`` the call made a token for; a token is given
+    to its rider by identity, because the slot may have a new tenant by
+    the time it arrives. The tokens are the rows of ``ids`` that the
+    call wrote, or, where ``logits`` is kept, what the host's sampler
+    makes of the call's logits (the strict rung, a rider that samples
+    with a temperature)."""
+
+    __slots__ = ("ids", "logits", "riders", "decode", "t_dispatch",
+                 "routes")
+
+    def __init__(self, ids, logits, riders, decode, t_dispatch, routes):
+        self.ids = ids
+        self.logits = logits
+        self.riders = riders
+        self.decode = decode
+        self.t_dispatch = t_dispatch
+        self.routes = routes       # expert loads of the calls up to this one
+
+
+def fetch_ids(ids):
+    """The blocking read of a call's greedy ids: a host (rows,) int32
+    array. Its copy was started when the call was dispatched."""
+    return _onp.asarray(ids.asnumpy(), _onp.int32)
+
+
+def _greedy(temperature):
+    return temperature is None or temperature <= 0.0
 
 
 class ContinuousEngine:
@@ -171,6 +211,10 @@ class ContinuousEngine:
         if self.num_slots < 1:
             raise ServeError(f"num_slots must be >= 1, got {num_slots}")
         self.pad_id = int(pad_id)
+        if self.pad_id < 0:
+            # a negative token tells the in-place step to take the lane's
+            # carried id instead
+            raise ServeError(f"pad_id must be >= 0, got {pad_id}")
         self.decode_path = resolve_decode_path(decode_path)
         self._quant = "int8" if self.decode_path == "int8" else None
         self._qindex, self._qflat = [], []
@@ -219,6 +263,19 @@ class ContinuousEngine:
             model, self.max_seq, path=self.decode_path, quant=self._quant,
             qindex=self._qindex, paged=self._fused_paged,
             inplace=self._fused_paged)
+        # ... and samples greedily inside itself: each lane's id stays on
+        # the device in one (num_slots,) array that both executables take
+        # and hand back (never donated: the host reads a call's ids after
+        # later calls were handed them)
+        self._carries = bool(self._step_block.donate_args)
+        self._ids = None
+        # calls whose ids the host has not read yet, oldest first: at most
+        # one decode visit, and the last chunks of prompts around it
+        self._flights = []
+        self._pipeline = {"visits_ahead": 0, "visits_drained": 0,
+                          "drained_by": {"sampled": 0, "strict": 0,
+                                         "failure": 0},
+                          "overrun_lane_steps": 0}
         self._inplace_steps = 0       # step calls that consumed the pool
         self._pool_reallocations = 0  # pools lost to a failed call
         # routed-expert layers: the load arrays of the calls not read
@@ -238,6 +295,7 @@ class ContinuousEngine:
             seq_buckets=tuple(sorted({1, self.prefill_chunk})),
             pad_value=self.pad_id, name=name)
         self.ctx = self.session.ctx  # the model's device: inputs go there
+        self._fresh_ids()
         self.metrics = self.session.metrics
         self.metrics.set_decode_path(self.decode_path)
         self.metrics.set_kv_cache_bytes(self.pool.nbytes(),
@@ -286,6 +344,14 @@ class ContinuousEngine:
             # each slot's admission seed (and position) into it in-trace
             self._key_bits = _fresh_key_bits()
 
+    def _fresh_ids(self):
+        """The carried ids from zeros (the in-place step alone has them)."""
+        from .. import numpy as mnp
+
+        if self._carries:
+            self._ids = mnp.zeros((self.num_slots,), dtype="int32",
+                                  ctx=self.ctx)
+
     # -- admission -----------------------------------------------------------
     def submit(self, prompt, max_new_tokens=32, temperature=0.0,
                top_k=None, stop_ids=(), priority="interactive",
@@ -305,6 +371,8 @@ class ContinuousEngine:
         prompt = [int(t) for t in prompt]
         if not prompt:
             raise MXNetError("empty prompt (need >= 1 token)")
+        if min(prompt) < 0:
+            raise MXNetError(f"negative token id in prompt: {min(prompt)}")
         max_new = int(max_new_tokens)
         if max_new < 1:
             raise MXNetError("max_new_tokens must be >= 1")
@@ -333,6 +401,7 @@ class ContinuousEngine:
         request sharing this prompt prefix skips that much prefill."""
         s = self._slots[i]
         self._slots[i] = None
+        s.finished = True   # a call still in flight drops this rider's row
         if self.prefix is not None and error is None and s.decoding:
             self.prefix.insert(s.prompt, self.pool.table()[i])
         self.pool.release(i)
@@ -423,13 +492,19 @@ class ContinuousEngine:
                 raise
             return self.pool.assign_with_prefix(i, budget, pages)
 
-    def _run_step(self, tokens, start_pos, last_idx, table, lanes):
-        """One call of the step executable. ``lanes`` names, for each
-        row of the call, the slot whose recurrent state it reads and
-        writes, -1 for a row that is not live (an empty slot, a slot
-        still prefilling while its neighbours decode): the step hands
-        such a row's state back untouched. Only a model that keeps
-        recurrent state is given it."""
+    def _run_step(self, tokens, start_pos, last_idx, table, lanes, keep):
+        """One call of the step executable; returns the last-position
+        logits. ``lanes`` names, for each row of the call, the slot
+        whose recurrent state it reads and writes, -1 for a row that is
+        not live (an empty slot, a slot still prefilling while its
+        neighbours decode): the step hands such a row's state back
+        untouched. Only a model that keeps recurrent state is given it.
+        ``keep`` names the slot whose carried id each row writes (the
+        argmax of its logits) and, where its token is negative, reads;
+        -1 for a row that writes none (a dead lane, a chunk that is not
+        its prompt's last). The in-place step alone is given it, with
+        the ids of the call before, and ``self._ids`` is rebound to what
+        it hands back."""
         from .. import numpy as mnp
 
         stateful = self.pool.layout.has_state
@@ -442,10 +517,12 @@ class ContinuousEngine:
                 tab.append(mnp.array(self._window_rows(lanes)))
             ln = ([mnp.array(_onp.asarray(lanes, _onp.int32))]
                   if stateful else [])
+            kp = ([mnp.array(_onp.asarray(keep, _onp.int32)), self._ids]
+                  if self._carries else [])
         with host_span("mxnet_tpu.serve.dispatch"):
             if self._fused_paged:
                 try:
-                    out = self.session.run(toks, sp, li, *tab, *ln,
+                    out = self.session.run(toks, sp, li, *tab, *ln, *kp,
                                            *self.pool.flat(), *self._qflat)
                 except Exception as exc:  # pylint: disable=broad-except
                     # a call that failed before dispatch left the pool
@@ -455,10 +532,15 @@ class ContinuousEngine:
                     if self.pool.lost():
                         self._recover_pool(exc)
                     raise
-                # a model with routed-expert layers hands their load back
-                # right after the logits (_CacheForward)
+                # before the stores come the logits, the load of a model's
+                # routed-expert layers, and the in-place step's ids
+                # (_CacheForward)
                 flat = out[len(out) - len(self.pool.layout):]
-                if len(out) - len(flat) > 1:
+                head = len(out) - len(flat)
+                if self._carries:
+                    head -= 1
+                    self._ids = out[head]
+                if head > 1:
                     # its copy to the host starts now and rides behind the
                     # step, so that the read after the fetch waits for none
                     out[1]._data.copy_to_host_async()
@@ -513,14 +595,13 @@ class ContinuousEngine:
             self._window_recycled += k
             _prof.incr_counter("serve.window_pages_recycled", k, cat="serve")
 
-    def _read_routes(self):
-        """Read back the expert loads of the calls since the last read,
-        where a step's result has just been fetched and waited for (the
-        arrays are results of calls the device has finished): one
+    def _read_routes(self, pending):
+        """Read back the expert loads ``pending`` of calls whose result
+        has just been fetched and waited for (the arrays are results of
+        calls the device has finished, never of the one in flight): one
         ``mxnet_tpu.serve.route`` span a read, with the calls' totals."""
-        if not self._route_pending:
+        if not pending:
             return
-        pending, self._route_pending = self._route_pending, []
         with host_span("mxnet_tpu.serve.route") as span:
             # (calls, layers, [experts hit, most on one, assignments])
             load = _onp.stack([_onp.asarray(a.asnumpy(), _onp.int64)
@@ -558,6 +639,10 @@ class ContinuousEngine:
         for i, s in enumerate(self._slots):
             if s is not None:
                 self._settle_slot(i, error=error)
+        # every rider of a call in flight was a live lane: settled above
+        self._flights = []
+        self._route_pending = []
+        self._fresh_ids()
         if self.prefix is not None:
             self.prefix.clear()
         self.pool.reallocate()
@@ -567,7 +652,8 @@ class ContinuousEngine:
     def _prefill_once(self):
         """Advance ONE prefilling slot by one chunk (round-robin), at the
         fixed (1, chunk) signature. The final chunk samples the first
-        token — that's the request's TTFT."""
+        token — that's the request's TTFT (stamped when the token
+        reaches the host)."""
         waiting = [i for i, s in enumerate(self._slots)
                    if s is not None and not s.decoding and not s.finished]
         if not waiting:
@@ -587,6 +673,7 @@ class ContinuousEngine:
             toks[0, :n] = s.prompt[s.consumed:s.consumed + n]
             table = _onp.zeros((1, self.pool.pages_per_slot), _onp.int32)
             table[0] = self.pool.table()[i]
+            last = s.consumed + n >= len(s.prompt)
         try:
             pf_args = {"slot": i, "n": n}
             with _attr.phase_scope("prefill"):
@@ -597,8 +684,9 @@ class ContinuousEngine:
                         # start_pos 0: whatever its last tenant left
                         _prof.incr_counter("serve.state_resets",
                                            cat="serve")
+                    t_dispatch = time.perf_counter()
                     logits = self._run_step(toks, [s.consumed], [n - 1],
-                                            table, [i])
+                                            table, [i], [i if last else -1])
                     if self._windowed:
                         self._note_recycled(s.consumed, n)
                 except Exception as e:
@@ -615,36 +703,177 @@ class ContinuousEngine:
                 self._settle_slot(i, error=exc)
             return
         s.consumed += n
-        if s.consumed < len(s.prompt):
+        if not last:
             return
-        # prompt fully written: sample the first token off the last real
-        # position's logits (exactly Generator._generate's step-0 sample)
+        # prompt fully written: the first token is sampled off the last
+        # real position's logits (exactly Generator._generate's step-0
+        # sample)
         s.decoding = True
         s.pos = len(s.prompt)
-        with host_span("mxnet_tpu.serve.sample"):
-            tid = int(sample_tokens(logits, temperature=s.temperature,
-                                    top_k=s.top_k)[0])
-        self._read_routes()
-        with host_span("mxnet_tpu.serve.settle", tokens=1):
+        riders = [(i, s)]
+        # ... by the step itself where the loop runs ahead: the id stays
+        # on the device for the decode visit that consumes it, and the
+        # host reads it behind that visit
+        host_draws = self._fetch_first(riders) is not None
+        self._enqueue(riders, False, t_dispatch,
+                      logits if host_draws else None)
+        if host_draws:
+            try:
+                self._land()
+            except Exception:  # pylint: disable=broad-except
+                pass   # a fetch that failed has settled every lane
+
+    def _enqueue(self, riders, decode, t_dispatch, logits):
+        """Put a call that has just been dispatched for ``riders`` in
+        flight: each has one more token coming. ``logits`` where the
+        host's sampler makes the tokens, ``None`` where the call's ids
+        are the tokens; the copy of the ids to the host starts now."""
+        for _, s in riders:
+            s.inflight += 1
+        if self._carries:
+            self._ids._data.copy_to_host_async()
+        # with it go the expert loads of the calls since the last one
+        # in flight: its fetch will show that the device is done with them
+        routes, self._route_pending = self._route_pending, []
+        self._flights.append(_Flight(self._ids, logits, riders, decode,
+                                     t_dispatch, routes))
+
+    def _riders(self):
+        """The slots the next decode visit carries: decoding, not ended,
+        and with budget left once what is in flight for them has arrived
+        (a request that ends by count is left out without waiting for
+        its last token)."""
+        return [(i, s) for i, s in enumerate(self._slots)
+                if s is not None and s.decoding and not s.finished
+                and len(s.tokens) + s.inflight < s.max_new]
+
+    def _fetch_first(self, riders):
+        """Why the next visit cannot be enqueued ahead of the fetch of
+        what is in flight, or ``None``: every rider's next token has to
+        be on the device, so the step must keep ids (the in-place step
+        does; the strict rung's ring executable does not) and every
+        rider must be greedy (a sampled token is drawn on the host, from
+        ``mxnet_tpu.random``'s key stream)."""
+        if not self._carries or self._multistep:
+            # (the multi-step visit is handed its tokens by the host too)
+            return "strict"
+        if not all(_greedy(s.temperature) for _, s in riders):
+            return "sampled"
+        return None
+
+    @staticmethod
+    def _read(f):
+        """The blocking read of the tokens of call ``f``, by slot row."""
+        if f.logits is None:
+            return fetch_ids(f.ids)
+        if not f.decode:
+            # exactly Generator._generate's step-0 sample
+            (i, s), = f.riders
+            return {i: int(sample_tokens(f.logits, temperature=s.temperature,
+                                         top_k=s.top_k)[0])}
+        if all(_greedy(s.temperature) for _, s in f.riders):
+            # one greedy argmax for all rows; blocks on the device
+            return sample_tokens(f.logits)
+        arr = f.logits.asnumpy()   # blocking device fetch
+        return {i: int(sample_tokens(arr[i:i + 1], temperature=s.temperature,
+                                     top_k=s.top_k)[0]) for i, s in f.riders}
+
+    def _land(self, leave=0):
+        """Fetch, emit and settle every call in flight but the newest
+        ``leave``, oldest first. Returns ``(seconds, wait ns)`` spent
+        blocked in the fetches. A fetch that fails means a dispatched
+        call's result never came back, and the pool's arrays descend
+        from that call: every lane
+        (those of the calls enqueued behind it too) is settled with the
+        error, the pool starts from zeros, and the error goes on to the
+        caller."""
+        blocked, waited = 0.0, 0
+        while len(self._flights) > leave:
+            f = self._flights.pop(0)
+            t0 = time.perf_counter()
+            w0 = _attr.thread_wait_ns() if _attr.ENABLED else 0
+            try:
+                with host_span("mxnet_tpu.serve.sample"):
+                    got = self._read(f)
+            except Exception as exc:
+                self._recover_pool(exc)
+                raise
+            t1 = time.perf_counter()
+            blocked += t1 - t0
+            if _attr.ENABLED:
+                waited += _attr.thread_wait_ns() - w0
+            self._read_routes(f.routes)
+            self._settle_flight(f, got, t1)
+        return blocked, waited
+
+    def _settle_flight(self, f, got, t_fetched):
+        """Account the tokens ``got`` (by slot row) of one fetched call.
+        A rider whose request has ended since the call was dispatched is
+        dropped, by the identity of the request; a decode visit's row
+        lost to a stop id that was learned one visit late is counted."""
+        kept = []
+        for i, s in f.riders:
+            s.inflight -= 1
+            if not s.finished:
+                kept.append((i, s))
+            elif f.decode and not s.expired:
+                self._count("overrun_lane_steps")
+        with host_span("mxnet_tpu.serve.settle", tokens=len(kept)):
             now = time.monotonic()
-            s.ttft_ms = (now - s.p.t_enq) * 1e3
-            self.metrics.observe_ttft(s.ttft_ms, s.p.priority)
-            s.emit(tid, now)
+            for i, s in kept:
+                if f.decode:
+                    s.decode_steps += 1
+                else:
+                    s.ttft_ms = (now - s.p.t_enq) * 1e3
+                    self.metrics.observe_ttft(s.ttft_ms, s.p.priority)
+                s.emit(int(got[i]), now)
+            if f.decode:
+                # ITL is the token-to-token gap, not just the device
+                # window: in steady state it runs from the PREVIOUS
+                # visit's arrival, so scheduler stalls between steps
+                # (admissions, prefill chunks, an injected serve:decode
+                # delay) land in the stream-stall number the SLO monitor
+                # judges. First visit after idle has no waiting stream;
+                # it falls back to its own dispatch.
+                prev = self._last_emit_t
+                self._last_emit_t = t_fetched
+                start = prev if prev is not None else f.t_dispatch
+                self.metrics.observe_itl((t_fetched - start) * 1e3,
+                                         live=len(f.riders))
+
+    def _count(self, what, reason=None):
+        """One more of ``stats()["pipeline"][what]`` and of the counter
+        ``serve.<what>``; a drained visit also by its ``reason``."""
+        self._pipeline[what] += 1
+        _prof.incr_counter(f"serve.{what}", cat="serve")
+        if reason is not None:
+            self._pipeline["drained_by"][reason] += 1
+            _prof.incr_counter(f"serve.{what}.{reason}", cat="serve")
 
     def _decode_once(self):
-        """One fixed-width decode step over every decoding slot. Slots
+        """One fixed-width decode visit over every decoding slot. Slots
         that are empty or still prefilling ride along as dead lanes:
         all-null page-table rows route their writes to the null page
         (re-zeroed in the scatter op), so they can neither corrupt live
         state nor feed garbage back to themselves. A recurrent state has
         no null page: a dead lane's ``lanes`` entry is -1 and the step
         returns its state row bit for bit (a slot in the middle of its
-        prefill keeps what its chunks have built)."""
-        decoding = [i for i, s in enumerate(self._slots)
-                    if s is not None and s.decoding and not s.finished]
-        if not decoding:
+        prefill keeps what its chunks have built).
+
+        The visit is enqueued BEFORE what was in flight is fetched
+        wherever every rider's next token is on the device
+        (:meth:`_fetch_first`), and is itself left in flight; otherwise
+        the loop fetches first, and fetches the visit before it goes on
+        (a drain, then a visit as it ever was)."""
+        riders = self._riders()
+        if riders and self._flights and self._fetch_first(riders):
+            self._land()     # may end a rider: a stop id, a last token
+            riders = self._riders()
+        if not riders:
+            # nothing to enqueue: what is in flight is all there is. An
             # idle gap, not a stall: no live token stream is waiting, so
-            # the next decode step's ITL restarts from its own window
+            # the next visit's ITL restarts from its own window
+            self._land()
             self._last_emit_t = None
             return
         stats = {}
@@ -652,16 +881,17 @@ class ContinuousEngine:
             # K/V positions the step's attention reads, by kind of layer
             # (the host knows every lane's position)
             w = self.pool.layout.window
-            at = [self._slots[i].pos + 1 for i in decoding]
+            at = [s.pos + 1 for _, s in riders]
             stats = {"kv_positions_full": sum(at) * self._n_full_layers,
                      "kv_positions_window": sum(min(t, w) for t in at)
                      * self._n_window_layers}
-        with host_span("mxnet_tpu.serve.decode", live=len(decoding),
+        with host_span("mxnet_tpu.serve.decode", live=len(riders),
                        **stats):
-            self._decode_step(decoding)
+            self._decode_step(riders)
 
-    def _decode_step(self, decoding):
-        """The classic decode visit over the ``decoding`` slots."""
+    def _decode_step(self, riders):
+        """The classic decode visit over ``riders``, (slot, request)
+        pairs."""
         _faults.fault_point("serve:decode",
                             {"session": self.session.name})
         t_build = time.perf_counter()
@@ -672,21 +902,23 @@ class ContinuousEngine:
             lanes = _onp.full(S, -1, _onp.int32)
             table = _onp.zeros((S, self.pool.pages_per_slot), _onp.int32)
             live_table = self.pool.table()
-            for i in decoding:
-                s = self._slots[i]
-                toks[i, 0] = s.pending
+            for i, s in riders:
+                # a token still in flight is on the device: the step
+                # takes the lane's carried id
+                toks[i, 0] = -1 if s.inflight else s.pending
                 pos[i] = s.pos
                 lanes[i] = i
                 table[i] = live_table[i]
-            temps = [self._slots[i].temperature for i in decoding]
         # the iteration's four-way attribution (host/dispatch/device/
         # wait partitions the span wall exactly; the pre-span input
         # assembly above lands in the ledger's schedule bucket): the
-        # span covers dispatch, the blocking logits fetch (the ONE
-        # sanctioned device sync — that delta is the device phase), and
-        # the host-side sampling/emit bookkeeping
+        # span covers dispatch, the blocking reads (the ONE sanctioned
+        # device sync: the device phase is the time the host spent in
+        # them, for the visit before this one where this one was
+        # enqueued ahead, behind which the device is busy), and the
+        # host-side emit bookkeeping
         attributing = _attr.ENABLED
-        args = {"live": len(decoding)}
+        args = {"live": len(riders)}
         with _attr.phase_scope("decode"):
             t1 = time.perf_counter()
             w1 = _attr.thread_wait_ns() if attributing else 0
@@ -694,74 +926,49 @@ class ContinuousEngine:
             try:
                 logits = self._run_step(toks, pos,
                                         _onp.zeros(S, _onp.int32), table,
-                                        lanes)
-                if self._windowed:
-                    for i in decoding:
-                        self._note_recycled(self._slots[i].pos, 1)
+                                        lanes, lanes)
+                # positions, ring columns and budgets advance at dispatch
+                for _, s in riders:
+                    if self._windowed:
+                        self._note_recycled(s.pos, 1)
+                    s.pos += 1
                 t2 = time.perf_counter()
                 w2 = _attr.thread_wait_ns() if attributing else 0
-                with host_span("mxnet_tpu.serve.sample"):
-                    if all(t is None or t <= 0.0 for t in temps):
-                        # one greedy argmax for all rows; blocks on device
-                        ids = sample_tokens(logits)
-                        t3 = time.perf_counter()
-                        w3 = _attr.thread_wait_ns() if attributing else 0
-                        sampled = {i: int(ids[i]) for i in decoding}
-                    else:
-                        arr = logits.asnumpy()  # blocking device fetch
-                        t3 = time.perf_counter()
-                        w3 = _attr.thread_wait_ns() if attributing else 0
-                        sampled = {}
-                        for i in decoding:
-                            s = self._slots[i]
-                            sampled[i] = int(sample_tokens(
-                                arr[i:i + 1], temperature=s.temperature,
-                                top_k=s.top_k)[0])
-                self._read_routes()
-                with host_span("mxnet_tpu.serve.settle",
-                               tokens=len(decoding)):
-                    now = time.monotonic()
-                    for i in decoding:
-                        s = self._slots[i]
-                        s.pos += 1
-                        s.decode_steps += 1
-                        s.emit(sampled[i], now)
-                    if attributing:
-                        t4 = time.perf_counter()
-                        w4 = _attr.thread_wait_ns()
-                        dispatch_ms = max(
-                            0.0, (t2 - t1) * 1e3 - (w2 - w1) / 1e6)
-                        device_ms = (t3 - t2) * 1e3
-                        host_ms = max(
-                            0.0, (t4 - t3) * 1e3 - (w4 - w3) / 1e6)
-                        wait_ms = max(0.0, ((w2 - w1) + (w4 - w3)) / 1e6)
-                        args.update(host_ms=round(host_ms, 4),
-                                    dispatch_ms=round(dispatch_ms, 4),
-                                    device_ms=round(device_ms, 4),
-                                    wait_ms=round(wait_ms, 4))
-                        self.ledger.observe_step(host_ms, dispatch_ms,
-                                                 device_ms, wait_ms,
-                                                 live=len(decoding))
-                        self.ledger.observe_schedule((t1 - t_build) * 1e3)
-                    # ITL is the token-to-token gap, not just the device
-                    # window: in steady state it runs from the PREVIOUS
-                    # step's emission, so scheduler stalls between steps
-                    # (admissions, prefill chunks, an injected
-                    # serve:decode delay) land in the stream-stall number
-                    # the SLO monitor judges. First step after idle has
-                    # no waiting stream; it falls back to its own decode
-                    # window.
-                    prev = self._last_emit_t
-                    self._last_emit_t = t3
-                    itl_start = prev if prev is not None else t1
-                    self.metrics.observe_itl((t3 - itl_start) * 1e3,
-                                             live=len(decoding))
+                why = self._fetch_first(riders)
+                if why is not None:
+                    self._count("visits_drained", why)
+                elif any(f.decode for f in self._flights):
+                    self._count("visits_ahead")
+                self._enqueue(riders, True, t1, logits if why else None)
+                # enqueued ahead, the visit stays in flight and what was
+                # in flight before it is read behind it; else it is read
+                # with the rest
+                blocked, waited = self._land(leave=int(why is None))
+                if attributing:
+                    t4 = time.perf_counter()
+                    w4 = _attr.thread_wait_ns()
+                    dispatch_ms = max(
+                        0.0, (t2 - t1) * 1e3 - (w2 - w1) / 1e6)
+                    device_ms = blocked * 1e3
+                    wait_ms = max(0.0, (w4 - w1 - waited) / 1e6)
+                    host_ms = max(
+                        0.0, (t4 - t2 - blocked) * 1e3
+                        - (w4 - w2 - waited) / 1e6)
+                    args.update(host_ms=round(host_ms, 4),
+                                dispatch_ms=round(dispatch_ms, 4),
+                                device_ms=round(device_ms, 4),
+                                wait_ms=round(wait_ms, 4))
+                    self.ledger.observe_step(host_ms, dispatch_ms,
+                                             device_ms, wait_ms,
+                                             live=len(riders))
+                    self.ledger.observe_schedule((t1 - t_build) * 1e3)
             except Exception as e:
                 args["error"] = type(e).__name__
                 raise
             finally:
                 self._span_fanout("serve::decode_step", s0_ns,
-                                  time.perf_counter_ns(), args, decoding)
+                                  time.perf_counter_ns(), args,
+                                  [i for i, _ in riders])
 
     def _run_multi(self, toks, pos, table, limit, remaining, seeds,
                    temps, top_ks, stops):
@@ -951,8 +1158,17 @@ class ContinuousEngine:
 
     @on_block_context
     def step(self):
-        """One scheduler iteration: retire -> admit -> one prefill chunk
-        -> one decode step -> gauges. Execution failures (an injected
+        """One scheduler iteration: retire -> admit (on what the host
+        knows) -> dispatch one prefill chunk -> dispatch one decode
+        visit -> fetch, emit and settle what was in flight BEFORE that
+        visit (the visit before it, and the first token of a prompt
+        whose last chunk went out in this step) -> gauges. At most one
+        decode visit stays in flight. Positions, pages, ring columns
+        and ``max_new`` ends advance at dispatch; token values, stop
+        ids, ``ttft_ms``, ``token_ms``, the ITL sample and the route
+        loads at fetch. Where a rider samples with a temperature, or on
+        the strict rung, the visit is fetched before the loop goes on
+        (:meth:`_fetch_first`). Execution failures (an injected
         ``serve:execute``/``serve:decode`` fault, a watchdog timeout)
         fail the requests that were inside the failing call — the
         scheduler itself keeps serving, exactly like the batcher's
@@ -975,9 +1191,7 @@ class ContinuousEngine:
                 else:
                     self._decode_once()
             except Exception as exc:  # pylint: disable=broad-except
-                for i, s in enumerate(self._slots):
-                    if s is not None and s.decoding:
-                        self._settle_slot(i, error=exc)
+                self._fail_decoding(exc)
             self._steps += 1
             with host_span("mxnet_tpu.serve.gauges"):
                 self.metrics.set_kv_pages(self.pool.pages_used,
@@ -993,8 +1207,26 @@ class ContinuousEngine:
                                                    self.prefix.pages_held,
                                                    self.prefix.evictions)
 
+    def _fail_decoding(self, exc):
+        """A decode visit failed. Before its dispatch: what was in flight
+        before it is whole and is settled first (a request that had
+        ended by count gets its last token), then the lanes that would
+        have ridden are settled with ``exc``. At a fetch:
+        :meth:`_recover_pool` has settled every lane, and nothing is
+        left to do here. Either way the pipeline is empty after it."""
+        self._count("visits_drained", "failure")
+        try:
+            self._land()
+        except Exception:  # pylint: disable=broad-except
+            return   # a fetch that failed has settled every lane
+        for i, s in enumerate(self._slots):
+            if s is not None and s.decoding and not s.finished:
+                self._settle_slot(i, error=exc)
+
     def _idle(self):
-        return not self._live() and self._batcher.queue_depth() == 0
+        # a call in flight is work: its ids are still to be settled
+        return (not self._live() and not self._flights
+                and self._batcher.queue_depth() == 0)
 
     def _run_loop(self):
         _prof.register_thread_name()
@@ -1023,7 +1255,7 @@ class ContinuousEngine:
         # warm-up rows are not live: they leave the state rows alone
         self._run_step(
             _onp.zeros((1, self.prefill_chunk), _onp.int32), [0], [0],
-            _onp.zeros((1, n), _onp.int32), [-1])
+            _onp.zeros((1, n), _onp.int32), [-1], [-1])
         if self._multistep:
             # remaining=0: zero runtime iterations, full trace/compile
             self._run_multi(
@@ -1038,7 +1270,8 @@ class ContinuousEngine:
                 _onp.zeros((S, 1), _onp.int32),
                 _onp.zeros(S, _onp.int32),
                 _onp.zeros(S, _onp.int32),
-                _onp.zeros((S, n), _onp.int32), _onp.full(S, -1))
+                _onp.zeros((S, n), _onp.int32), _onp.full(S, -1),
+                _onp.full(S, -1))
         self.session.freeze_signatures()
         sigs = self.session.signature_count()
         if self._msession is not None:
@@ -1072,6 +1305,9 @@ class ContinuousEngine:
                 self._settle_slot(i, error=ServiceUnavailable(
                     f"continuous engine {self.session.name!r} shut down "
                     f"mid-request ({len(s.tokens)} tokens generated)"))
+        # every rider of a call in flight was a live slot: settled above
+        self._flights = []
+        self._route_pending = []
         self._batcher.close(timeout)
 
     def drain(self, timeout=30.0):
@@ -1108,6 +1344,12 @@ class ContinuousEngine:
         # again from zeros
         out["pool_inplace_steps"] = self._inplace_steps
         out["pool_reallocations"] = self._pool_reallocations
+        # decode visits enqueued while the visit before was unfetched,
+        # those that had to fetch first (and why), and lane-steps whose
+        # result was dropped because the request had ended meanwhile
+        out["pipeline"] = {**self._pipeline,
+                           "drained_by": dict(self._pipeline["drained_by"]),
+                           "in_flight": len(self._flights)}
         caches = [out["cache"]]
         if self._msession is not None:
             out["multistep"] = self._msession.stats()

@@ -299,6 +299,9 @@ def _inplace_step(cell, rows, t_len, path, one_chip):
             sds((rows,), "int32"), sds((rows, n_pages), "int32")]
     args += [sds((rows, cols), "int32")] * bool(cols)
     args += [sds((rows,), "int32")] * layout.has_state
+    # keep, and the greedy ids the step carries: one row a lane whatever
+    # the call's width, handed back and never donated
+    args += [sds((rows,), "int32"), sds((c["lanes"],), "int32")]
     first = len(args)
     assert step.donate_args == tuple(range(first, first + len(stores)))
     params = {n: sds(s.shape, s.dtype) for n, s in structs.items()}
@@ -306,9 +309,11 @@ def _inplace_step(cell, rows, t_len, path, one_chip):
     fn = jax.jit(lambda p, *a: apply_fn(p, *a, rng_key=key),
                  donate_argnums=tuple(1 + i for i in step.donate_args))
     text = fn.lower(params, *args, *stores).compile().as_text()
-    # the stores follow the logits and, for a model that routes, its load
-    out0 = 1 + (cell == "mellum")
+    # the stores follow the logits, for a model that routes its load, and
+    # the ids
+    out0 = 2 + (cell == "mellum")
     pairs = {(out0 + j, len(params) + first + j) for j in range(len(stores))}
+    assert not any(o == out0 - 1 for o, _ in _aliases(text)), "ids aliased"
     return text, stores, pairs
 
 
@@ -351,6 +356,9 @@ def test_decode_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell,
     text, stores, pairs = _inplace_step(cell, c["lanes"], 1, path, one_chip)
     assert da.last_path() == "pallas_paged"
     assert "tpu_custom_call" in text
+    # the greedy ids are an output of the executable itself: no second one
+    assert f"s32[{c['lanes']}]" in text.split("entry_computation_layout")[1] \
+        .split("\n", 1)[0]
     assert pairs <= _aliases(text), (pairs, _aliases(text))
     for kind in ("f32", "s8"):
         assert f"{kind}[{c['lanes']},{c['kv']},{c['max_seq']}," not in text

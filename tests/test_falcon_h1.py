@@ -27,14 +27,12 @@ from mxnet_tpu import serve
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.models.llama import LlamaModel, get_llama
 from mxnet_tpu.ops import nn as ops
-from mxnet_tpu.serve import scheduler as sched
 from mxnet_tpu.serve.generate import CacheLayout, KVCache
 from mxnet_tpu.serve.kv_blocks import PagedKVPool
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOL, SAME = 1e-4, 1e-6
 SEED = 5
-SAMPLE = sched.sample_tokens
 
 
 def _harness():
@@ -284,22 +282,25 @@ def drive(eng, waves, monkeypatch, check_dead_lanes=False):
     token]}`` as the scheduler saw them, and how many dead lanes' states
     were held to their bytes."""
     seen = {}
+    real = eng._run_step
 
-    def spy(logits, **kw):
+    def spy(tokens, start_pos, last_idx, table, lanes, keep):
+        # the in-place step samples inside itself: the logits a token is
+        # sampled from are read off the call that made them
+        logits = real(tokens, start_pos, last_idx, table, lanes, keep)
         arr = logits.asnumpy()
-        live = [j for j, s in enumerate(eng._slots)
-                if s is not None and s.decoding and not s.finished]
-        if arr.shape[0] != eng.num_slots:
-            # a prefill's last chunk: the one slot with no token yet
-            (j,) = [j for j in live if eng._slots[j].ttft_ms is None]
-            rows = [(j, arr[0])]
+        if np.shape(tokens)[1] == 1:
+            rows = [(j, arr[j]) for j in lanes if j >= 0]
         else:
-            rows = [(j, arr[j]) for j in live]
+            # a prefill chunk: its one slot, if it is the prompt's last
+            (j,) = lanes
+            done = start_pos[0] + last_idx[0] + 1
+            rows = ([(j, arr[0])] if j >= 0
+                    and done == len(eng._slots[j].prompt) else [])
         for j, row in rows:
             seen.setdefault(tuple(eng._slots[j].prompt), []).append(row)
-        return SAMPLE(logits, **kw)
+        return logits
 
-    monkeypatch.setattr(sched, "sample_tokens", spy)
     held = [0]
     if check_dead_lanes:
         decode = eng._decode_once
@@ -320,6 +321,7 @@ def drive(eng, waves, monkeypatch, check_dead_lanes=False):
                     held[0] += bool(np.any(b[j]))
         monkeypatch.setattr(eng, "_decode_once", checked)
     eng.warmup()
+    monkeypatch.setattr(eng, "_run_step", spy)
     futs = []
     for wave in waves:
         futs += [eng.submit(p, max_new_tokens=n) for p, n in wave]

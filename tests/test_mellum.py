@@ -124,8 +124,8 @@ class Spy:
     def __init__(self, eng):
         self.calls, real = [], eng._run_step
 
-        def run(tokens, start_pos, last_idx, table, lanes):
-            out = real(tokens, start_pos, last_idx, table, lanes)
+        def run(tokens, start_pos, last_idx, table, lanes, keep):
+            out = real(tokens, start_pos, last_idx, table, lanes, keep)
             self.calls.append((np.asarray(tokens).shape[1],
                                np.array(start_pos), np.array(last_idx),
                                [int(s) for s in lanes if s >= 0],
@@ -494,8 +494,10 @@ def test_dense_unbounded_models_keep_their_step(model):
 
     eng.session.run = run
     serve_all(eng, tokens_of(8, 11, 19, vocab=64), 6)
-    n_args = 4 + int(layout.has_state) + len(layout)
-    assert set(seen) == {(n_args, 1 + len(layout))}
+    # tokens, start_pos, last_idx, the page table, lanes for a state, and
+    # the in-place step's keep and ids (the ids come back after the logits)
+    n_args = 6 + int(layout.has_state) + len(layout)
+    assert set(seen) == {(n_args, 2 + len(layout))}
     st = eng.stats()
     assert not {"moe", "kv_pool_bytes_window", "kv_pool_bytes_full",
                 "window_pages_recycled"} & set(st)

@@ -250,27 +250,38 @@ def _gathered_step(monkeypatch):
     monkeypatch.setattr(sched, "_CacheForward", step)
 
 
+def _logits_spy(eng, seen):
+    """``eng._run_step`` that files, under each request's prompt, the
+    logits of every call a token of its is sampled from: a decode
+    visit's row of each live lane, and the row of a prompt's last chunk.
+    (The in-place step samples inside itself, so nothing on the host
+    sees these logits unless it looks.)"""
+    real = eng._run_step
+
+    def run(tokens, start_pos, last_idx, table, lanes, keep):
+        logits = real(tokens, start_pos, last_idx, table, lanes, keep)
+        arr = logits.asnumpy()
+        if arr.shape[0] == eng.num_slots and np.shape(tokens)[1] == 1:
+            rows = [(j, arr[j]) for j in lanes if j >= 0]
+        else:
+            (j,) = lanes
+            s = eng._slots[j]
+            last = start_pos[0] + last_idx[0] + 1 == len(s.prompt)
+            rows = [(j, arr[0])] if last else []
+        for j, row in rows:
+            seen.setdefault(tuple(eng._slots[j].prompt), []).append(row)
+        return logits
+
+    return run
+
+
 def _drive(eng, waves, monkeypatch):
     """Each wave of (prompt, max_new) submitted a few steps apart, then
     stepped to the end: ``({prompt: [logits of each sampled token]},
     results)``."""
-    seen, sample = {}, sched.sample_tokens
-
-    def spy(logits, **kw):
-        arr = logits.asnumpy()
-        live = [j for j, s in enumerate(eng._slots)
-                if s is not None and s.decoding and not s.finished]
-        if arr.shape[0] != eng.num_slots:
-            (j,) = [j for j in live if eng._slots[j].ttft_ms is None]
-            rows = [(j, arr[0])]
-        else:
-            rows = [(j, arr[j]) for j in live]
-        for j, row in rows:
-            seen.setdefault(tuple(eng._slots[j].prompt), []).append(row)
-        return sample(logits, **kw)
-
-    monkeypatch.setattr(sched, "sample_tokens", spy)
+    seen = {}
     eng.warmup()
+    monkeypatch.setattr(eng, "_run_step", _logits_spy(eng, seen))
     futs = []
     for wave in waves:
         futs += [eng.submit(p, max_new_tokens=n) for p, n in wave]
@@ -282,7 +293,6 @@ def _drive(eng, waves, monkeypatch):
         eng.step()
     out = [f.result(0) for f in futs]
     eng.assert_no_recompiles()
-    monkeypatch.setattr(sched, "sample_tokens", sample)
     return seen, out
 
 

@@ -68,15 +68,15 @@ def build(config):
     return net
 
 
-def serve_all(config):
+def serve_all(config, decode_path="pallas"):
     """Tokens of REQUESTS, the step executable's calls after warm-up, and
     the engine's counters before and after them."""
     da.use_interpret(True)
     net = build(config)
     mx.random.seed(11)
     eng = serve.ContinuousEngine(net, max_seq=64, num_slots=3, page_size=8,
-                                 prefill_chunk=8, decode_path="pallas",
-                                 name="warm_" + config)
+                                 prefill_chunk=8, decode_path=decode_path,
+                                 name=f"warm_{decode_path}_{config}")
     eng.warmup()
     before = eng.stats()
     calls = [0]
@@ -111,3 +111,16 @@ def test_every_step_after_warmup_is_a_fast_call(config):
         == calls
     assert after["cache"]["signatures"] == 2
     assert tokens == PARENT_TOKENS[config]
+
+
+@pytest.mark.parametrize("config", ["mistral_7b_v01", "falcon_h1_34b"])
+def test_the_strict_rung_draws_its_sampled_rows(config):
+    """The strict rung keeps no ids on the device, so every visit is
+    fetched first, and a row with a temperature is drawn by the host's
+    sampler there as on the fast rungs: the tokens commit 82d78c8 printed
+    on the ``baseline`` rung, which are those above."""
+    tokens, _, _, after = serve_all(config, decode_path="baseline")
+    assert tokens == PARENT_TOKENS[config]
+    pipe = after["pipeline"]
+    assert pipe["visits_ahead"] == 0
+    assert pipe["visits_drained"] == pipe["drained_by"]["strict"] > 0
