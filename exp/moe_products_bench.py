@@ -1,8 +1,11 @@
 """Microbenchmark of the routed-expert products on the chip (issue 33):
 the candidates of ``ops.nn`` at the Mellum-2 cell's two shapes, 16 x 8
-and 128 x 8 assignments over 64 experts of 2304 x 896, float32.
+and 128 x 8 assignments over 64 experts of 2304 x 896, float32; with
+``--cell command_a_plus`` (issue 35) at that cell's: 8 x 8 and 128 x 8
+assignments over 128 experts of 4096 x 4096 of which the first 8 are
+held, so that 15 of 16 assignments are computed by nobody here.
 
-    python3 exp/moe_products_bench.py [--tiny]
+    python3 exp/moe_products_bench.py [--tiny] [--cell command_a_plus]
 
 Prints one JSON line a candidate and shape: milliseconds a call (median
 of ``reps`` timed calls that end in ``block_until_ready``), the bytes of
@@ -37,7 +40,14 @@ def timed(fn, args, reps=20):
 
 def main():
     tiny = "--tiny" in sys.argv
-    e, h, f, k = (8, 64, 32, 2) if tiny else (64, 2304, 896, 8)
+    share = "command_a_plus" in sys.argv
+    # e experts are held of e_all that the router scores
+    e_all, h, f, k = (8, 64, 32, 2) if tiny else (64, 2304, 896, 8)
+    e, rows = e_all, (4, 16) if tiny else (16, 128)
+    if share:
+        e_all, e, h, f, k = (16, 4, 64, 64, 4) if tiny \
+            else (128, 8, 4096, 4096, 8)
+        rows = (4, 16) if tiny else (8, 128)
     key = jax.random.key(0)
     ks = jax.random.split(key, 5)
     gate = 0.02 * jax.random.normal(ks[0], (e, h, f), jnp.float32)
@@ -45,11 +55,16 @@ def main():
     down = 0.02 * jax.random.normal(ks[2], (e, f, h), jnp.float32)
     prec = jax.lax.Precision.HIGHEST
     print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
-    for n in ((4, 16) if tiny else (16, 128)):
+    for n in rows:
         x = jax.random.normal(ks[3], (n, h), jnp.float32)
-        logits = jax.random.normal(jax.random.fold_in(ks[4], n), (n, e))
-        w, idx = ops.route_top_k(logits, k)
-        hit = int(np.unique(np.asarray(idx)).size)
+        logits = jax.random.normal(jax.random.fold_in(ks[4], n), (n, e_all))
+        w, idx = ops.route_top_k(logits, k,
+                                 score="sigmoid" if share else "softmax")
+        # an assignment to an expert held elsewhere is computed by nobody
+        # here: index e, weight 0 (ops.nn.routed_experts does the same)
+        w = jnp.where(idx < e, w, 0.0)
+        idx = jnp.where(idx < e, idx, e)
+        hit = int(np.unique(np.asarray(idx)[np.asarray(idx) < e]).size)
         nbytes = hit * 3 * h * f * 4
 
         def combine(prod):
@@ -67,7 +82,7 @@ def main():
             a = n * k
             flat = i.reshape(a)
             order = jnp.argsort(flat, stable=True)
-            gs = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+            gs = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
             xs = x[order // k]
             hid = jax.nn.silu(jax.lax.ragged_dot(xs, gate, gs, precision=prec)) \
                 * jax.lax.ragged_dot(xs, up, gs, precision=prec)
@@ -79,11 +94,11 @@ def main():
         cands["ragged_dot"] = jax.jit(ragged)
 
         def dense(x, i, gate, up, down):
-            cw = jnp.zeros((n, e), jnp.float32).at[
+            cw = jnp.zeros((n, e + 1), jnp.float32).at[
                 jnp.arange(n)[:, None], i].add(w)
             return jnp.einsum("enh,ne->nh",
                               ops.dense_expert_products(x, gate, up, down),
-                              cw, precision=prec)
+                              cw[:, :e], precision=prec)
 
         cands["dense_masked"] = jax.jit(dense)
         ref = None

@@ -131,7 +131,8 @@ def _dense_on(cache):
 
 
 class LlamaAttention(HybridBlock):
-    """Causal GQA attention with RoPE."""
+    """Causal GQA attention with RoPE; ``theta=None`` is a layer that
+    applies no rotation at all (its queries and keys carry no position)."""
 
     def __init__(self, units, num_heads, num_kv_heads=None, theta=10000.0,
                  head_dim=None, key_multiplier=None, window=None,
@@ -181,9 +182,20 @@ class LlamaAttention(HybridBlock):
     def window(self):
         return self._window
 
-    def _tables(self, t):
-        return _rope_tables(t, self._head_dim, self._theta,
-                            self._rope_scaling)
+    def _rotate(self, q, k, t, start_pos=None):
+        """``q`` and ``k`` turned by this layer's table: positions ``0 ..
+        t - 1``, or with ``start_pos`` the rows' own ``start_pos[b] + i``
+        out of a table of ``t``. No table, no turn."""
+        from .. import numpy as mnp
+
+        if self._theta is None:
+            return q, k
+        cos_t, sin_t = _rope_tables(t, self._head_dim, self._theta,
+                                    self._rope_scaling)
+        cos, sin = mnp.array(cos_t), mnp.array(sin_t)
+        if start_pos is not None:
+            cos, sin = _ops.rope_positions(cos, sin, start_pos, q.shape[2])
+        return apply_rope(q, cos, sin), apply_rope(k, cos, sin)
 
     def _keys(self, k):
         return k if self._key_mult is None else k * self._key_mult
@@ -213,11 +225,7 @@ class LlamaAttention(HybridBlock):
             k = self._heads_split(self._keys(self.k_proj(x)),
                                   self._kv_heads)
             v = self._heads_split(self.v_proj(x), self._kv_heads)
-            cos_t, sin_t = self._tables(t)
-            cos = mnp.array(cos_t)
-            sin = mnp.array(sin_t)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+            q, k = self._rotate(q, k, t)
             if rep > 1:  # expand kv heads for the attention kernel
                 k = mnp.repeat(k, rep, axis=1)
                 v = mnp.repeat(v, rep, axis=1)
@@ -248,11 +256,7 @@ class LlamaAttention(HybridBlock):
             v = self._heads_split(
                 _ops.stable_dense(x, self.v_proj.weight.data()),
                 self._kv_heads)
-            cos_t, sin_t = self._tables(cache.max_seq)
-            cos, sin = _ops.rope_positions(mnp.array(cos_t),
-                                           mnp.array(sin_t), start_pos, t)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+            q, k = self._rotate(q, k, cache.max_seq, start_pos)
             k_all = _ops.kv_cache_write(cache.k, k, start_pos)
             v_all = _ops.kv_cache_write(cache.v, v, start_pos)
             cache.update(k_all, v_all)
@@ -269,8 +273,6 @@ class LlamaAttention(HybridBlock):
         """Serving fast rungs ("pallas"/"int8"): gemm (or int8) projections
         and the fused decode-attention kernel, which consumes the GQA K/V
         rings *unexpanded* — tolerance parity, not the bitwise contract."""
-        from .. import numpy as mnp
-
         b, t, _ = x.shape
         q = self._heads_split(_serving_dense(x, self.q_proj.weight, cache),
                               self._heads)
@@ -279,11 +281,7 @@ class LlamaAttention(HybridBlock):
             self._kv_heads)
         v = self._heads_split(_serving_dense(x, self.v_proj.weight, cache),
                               self._kv_heads)
-        cos_t, sin_t = self._tables(cache.max_seq)
-        cos, sin = _ops.rope_positions(mnp.array(cos_t), mnp.array(sin_t),
-                                       start_pos, t)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        q, k = self._rotate(q, k, cache.max_seq, start_pos)
         # a layer whose K/V are pages (the engine's step) says so with its
         # page table: rows are written into their pages and read there
         table = getattr(cache, "page_table", None)

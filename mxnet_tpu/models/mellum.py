@@ -43,13 +43,29 @@ from .llama import LayerCache, LlamaAttention, _dense_on
 WINDOW, FULL = "sliding_attention", "full_attention"
 
 
-class MellumMoE(HybridBlock):
+def windowed_cache_spec(blocks):
+    """``cache_spec()`` of a model whose blocks each hold a
+    :class:`~.llama.LlamaAttention` as ``attention``: every layer keeps
+    K/V rows; a window layer's are bounded."""
+    return [LayerCache(*blk.attention.cache_geometry(), (),
+                       blk.attention.window) for blk in blocks]
+
+
+class RoutedFFN(HybridBlock):
     """Routed SwiGLU experts with no dropped token. ``gate_weight`` and
     ``up_weight`` are (held, units, expert_size), ``down_weight`` (held,
-    expert_size, units); the router is as wide as all the experts."""
+    expert_size, units); the router is as wide as all the experts and
+    scores them by ``score`` (``"softmax"`` over all, or each expert's own
+    ``"sigmoid"``).
+
+    ``num_shared`` (0: none) adds shared experts of the same size, which
+    every token takes and every share of the routed experts computes
+    alike: their mean is added to the routed sum. They are stored stacked
+    as the routed ones are, ``shared_*_weight`` (num_shared, ...)."""
 
     def __init__(self, units, expert_size, num_experts, top_k,
-                 experts_held=None, norm_topk_prob=True, **kwargs):
+                 experts_held=None, norm_topk_prob=True, score="softmax",
+                 num_shared=0, **kwargs):
         super().__init__(**kwargs)
         first, count = (0, num_experts) if experts_held is None \
             else (int(experts_held[0]), int(experts_held[1]))
@@ -59,7 +75,7 @@ class MellumMoE(HybridBlock):
                 f"experts: top {top_k} of {num_experts}, holding "
                 f"{count} from {first}")
         self._top_k, self._held = int(top_k), (first, count)
-        self._renorm = bool(norm_topk_prob)
+        self._renorm, self._score = bool(norm_topk_prob), score
         self.router = nn.Dense(num_experts, flatten=False, use_bias=False,
                                in_units=units)
         self.gate_weight = Parameter("gate_weight",
@@ -68,6 +84,15 @@ class MellumMoE(HybridBlock):
                                    shape=(count, units, expert_size))
         self.down_weight = Parameter("down_weight",
                                      shape=(count, expert_size, units))
+        self._shared = int(num_shared)
+        if self._shared:
+            n = self._shared
+            self.shared_gate_weight = Parameter(
+                "shared_gate_weight", shape=(n, units, expert_size))
+            self.shared_up_weight = Parameter(
+                "shared_up_weight", shape=(n, units, expert_size))
+            self.shared_down_weight = Parameter(
+                "shared_down_weight", shape=(n, expert_size, units))
 
     def forward(self, x, cache=None):
         # a prefill chunk walks its sorted assignments in tiles of rows
@@ -82,9 +107,13 @@ class MellumMoE(HybridBlock):
             x, self.router.weight.data(), self.gate_weight.data(),
             self.up_weight.data(), self.down_weight.data(), self._top_k,
             held=self._held, token_live=live, renormalize=self._renorm,
-            impl="grouped" if grouped else "dense")
+            impl="grouped" if grouped else "dense", score=self._score)
         if cache is not None:
             cache.note_route(load)
+        if self._shared:
+            out = out + _ops.shared_experts(
+                x, self.shared_gate_weight.data(),
+                self.shared_up_weight.data(), self.shared_down_weight.data())
         return out
 
 
@@ -98,7 +127,7 @@ class MellumBlock(HybridBlock):
             units, num_heads, num_kv_heads, theta=theta, head_dim=head_dim,
             window=window, rope_scaling=rope_scaling)
         self.ffn_norm = nn.RMSNorm(epsilon=norm_eps, in_channels=units)
-        self.ffn = MellumMoE(units, **moe)
+        self.ffn = RoutedFFN(units, **moe)
 
     def forward(self, x, cache=None, start_pos=None):
         x = x + self.attention(self.attn_norm(x), cache=cache,
@@ -139,9 +168,7 @@ class MellumModel(HybridBlock):
                                 in_units=units)
 
     def cache_spec(self):
-        """Every layer keeps K/V rows; a window layer's are bounded."""
-        return [LayerCache(*blk.attention.cache_geometry(), (),
-                           blk.attention.window) for blk in self._blocks]
+        return windowed_cache_spec(self._blocks)
 
     def forward(self, input_ids, cache=None, start_pos=None):
         x = self.embed(input_ids)

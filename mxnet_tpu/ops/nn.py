@@ -481,18 +481,21 @@ def batch_norm(x, gamma, beta, running_mean, running_var, eps=1e-5,
     return out
 
 
-def layer_norm(data, gamma, beta, axis=-1, eps=1e-5):
+def layer_norm(data, gamma, beta=None, axis=-1, eps=1e-5):
+    """``beta=None``: a gain and no bias."""
     jnp = _jnp()
 
-    def f(x, g, b):
+    def f(x, g, *b):
         mean = jnp.mean(x, axis=axis, keepdims=True)
         var = jnp.var(x, axis=axis, keepdims=True)
         out = (x - mean) / jnp.sqrt(var + eps)
         shape = [1] * x.ndim
         shape[axis] = x.shape[axis]
-        return out * g.reshape(shape) + b.reshape(shape)
+        out = out * g.reshape(shape)
+        return out + b[0].reshape(shape) if b else out
 
-    return _apply(f, (data, gamma, beta), name="layer_norm")
+    args = (data, gamma) if beta is None else (data, gamma, beta)
+    return _apply(f, args, name="layer_norm")
 
 
 def rms_norm(data, gamma, axis=-1, eps=1e-6):
@@ -1618,10 +1621,12 @@ def ssd_scan(x, dt, a, b_mat, c_mat, d, state=None, start_pos=None,
 
 
 # ---------------------------------------------------------------------------
-# Routed experts (the feed-forward of models/mellum.py)
+# Routed experts (the feed-forward of models/mellum.py and
+# models/command_a_plus.py)
 #
-# A router scores every token against all ``E`` experts; the token's ``k``
-# best (a tie goes to the lower index) share its softmax mass, renormalised
+# A router scores every token against all ``E`` experts (``score``: the
+# softmax over them, or each expert's own sigmoid); the token's ``k`` best
+# (a tie goes to the lower index) share their scores' sum, renormalised
 # to 1, and the layer's output is the weighted sum of those experts'
 # SwiGLU products. There is no capacity: a token gets all k of its
 # experts whatever the load.
@@ -1642,15 +1647,20 @@ def ssd_scan(x, dt, a, b_mat, c_mat, d, state=None, start_pos=None,
 # ---------------------------------------------------------------------------
 
 
-def route_top_k(logits, top_k, renormalize=True):
+def route_top_k(logits, top_k, renormalize=True, score="softmax"):
     """``(weights (N, k), experts (N, k) int32)`` of router ``logits``
-    (N, E), on raw arrays: softmax over all E in float32, the k largest
-    (``lax.top_k``: of equal values the lower index first), renormalised
-    to sum to 1."""
+    (N, E), on raw arrays: the scores in float32 (``score``: ``"softmax"``
+    over all E, or ``"sigmoid"`` of each expert's own logit), the k
+    largest (``lax.top_k``: of equal values the lower index first),
+    renormalised to sum to 1."""
     import jax
 
     jnp = _jnp()
-    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    if score not in ("softmax", "sigmoid"):
+        raise MXNetError(f"router score {score!r}")
+    z = logits.astype(jnp.float32)
+    p = jax.nn.softmax(z, axis=-1) if score == "softmax" \
+        else jax.nn.sigmoid(z)
     w, idx = jax.lax.top_k(p, int(top_k))
     if renormalize:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
@@ -1726,9 +1736,24 @@ def dense_expert_products(x, gate, up, down):
     return jnp.einsum("enf,efh->enh", hid, down, precision=prec)
 
 
+def shared_experts(data, gate, up, down):
+    """The mean of ``S`` SwiGLU experts that every token takes: ``data``
+    (B, T, H), ``gate`` / ``up`` (S, H, F), ``down`` (S, F, H), stacked as
+    the routed ones are (the same three products, every token with every
+    expert), so that a trace tells the two branches apart by their
+    weights' leading size."""
+    def f(x, g, u, d):
+        jnp = _jnp()
+        b, t, h = x.shape
+        y = dense_expert_products(x.reshape(b * t, h), g, u, d)
+        return jnp.mean(y, axis=0).reshape(b, t, h)
+
+    return _apply(f, (data, gate, up, down), name="shared_experts")
+
+
 def routed_experts(data, router_weight, gate, up, down, top_k, held=None,
                    token_live=None, renormalize=True, impl="grouped",
-                   tile=32):
+                   tile=32, score="softmax"):
     """The routed-expert feed-forward of the section comment: ``data``
     (B, T, H), ``router_weight`` (E_all, H), the held experts' ``gate`` /
     ``up`` (E, H, F) and ``down`` (E, F, H); ``held`` is ``(first, count)``
@@ -1736,9 +1761,11 @@ def routed_experts(data, router_weight, gate, up, down, top_k, held=None,
     ``token_live`` (B, T) bool says which tokens are real (None: all); the
     others are given to no expert and come back as zeros.
 
-    Returns ``(out (B, T, H), load (3,) int32)``: the held experts' part
-    of every token's sum, and ``[experts that got a live token, the most
-    tokens one expert got, assignments computed]``.
+    Returns ``(out (B, T, H), load (5,) int32)``: the held experts' part
+    of every token's sum, and ``[held experts that got a live token, the
+    most tokens one of them got, assignments computed (the live tokens'
+    that fell on a held expert), the live tokens' assignments (k each,
+    held here or not), experts held]``.
     """
     first, count = (0, gate.shape[0]) if held is None else \
         (int(held[0]), int(held[1]))
@@ -1753,11 +1780,13 @@ def routed_experts(data, router_weight, gate, up, down, top_k, held=None,
         b, t, h = x.shape
         xf = x.reshape(b * t, h)
         logits = jnp.dot(xf, rw.T, precision=stored_precision(xf, rw))
-        w, idx = route_top_k(logits, top_k, renormalize)
+        w, idx = route_top_k(logits, top_k, renormalize, score)
         local = idx - first
         here = (local >= 0) & (local < count)
+        asked = b * t * idx.shape[1]
         if live is not None:
             here = here & live.reshape(b * t, 1)
+            asked = jnp.sum(live) * idx.shape[1]
         local = jnp.where(here, local, count)
         w = jnp.where(here, w, 0.0)
         if impl == "grouped":
@@ -1772,7 +1801,8 @@ def routed_experts(data, router_weight, gate, up, down, top_k, held=None,
             counts = jnp.zeros((count + 1,), jnp.int32).at[
                 local.reshape(-1)].add(1)[:count]
         load = jnp.stack([jnp.sum(counts > 0), jnp.max(counts),
-                          jnp.sum(counts)])
+                          jnp.sum(counts), jnp.asarray(asked),
+                          jnp.asarray(count)])
         return out.reshape(b, t, h), load.astype(jnp.int32)
 
     return _apply(f, (data, router_weight, gate, up, down, token_live),

@@ -194,8 +194,12 @@ def test_dropout_partitions_over_four_chips(topo):
 _PAGE = 128
 _CELLS = {"mistral": dict(lanes=8, heads=32, kv=8, max_seq=2048),
           "falcon": dict(lanes=32, heads=20, kv=4, max_seq=512),
-          "mellum": dict(lanes=16, heads=32, kv=4, max_seq=3584)}
+          "mellum": dict(lanes=16, heads=32, kv=4, max_seq=3584),
+          "command_a": dict(lanes=8, heads=128, kv=8, max_seq=7168,
+                            window=4096)}
 _WINDOW = 1024
+# the cells whose model hands back its routed layers' load after the logits
+_ROUTED = ("mellum", "command_a")
 
 
 def _cell_model(cell, layers=1):
@@ -203,6 +207,15 @@ def _cell_model(cell, layers=1):
     from mxnet_tpu.models.llama import LlamaModel
     from mxnet_tpu.models.mellum import MellumModel
 
+    if cell == "command_a":
+        from mxnet_tpu.models.command_a_plus import CommandAPlusModel
+
+        return CommandAPlusModel(
+            vocab_size=32768, units=4096, num_heads=128, num_kv_heads=8,
+            head_dim=128, sliding_window=_CELLS[cell]["window"],
+            rope_theta=50000.0, expert_size=4096, num_experts=128,
+            num_experts_per_tok=8, num_shared_experts=4, experts_held=(0, 8),
+            layer_types=["sliding_attention", "full_attention"][:layers + 1])
     if cell == "mellum":
         yarn = ("yarn", 16, 8192, 32, 1, 1.2772588722239782)
         return MellumModel(
@@ -249,11 +262,14 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, cell, int8):
     assert "tpu_custom_call" in text
 
 
-def test_windowed_paged_decode_kernel_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize("cell", ["mellum", "command_a"])
+def test_windowed_paged_decode_kernel_compiles_for_v5e(one_chip, cell):
     """The paged kernel over a ring of 9 pages under a window of 1,024,
-    at the Mellum-2 cell's widths."""
-    c = _CELLS["mellum"]
-    cols = _WINDOW // _PAGE + 1
+    at the Mellum-2 cell's widths, and over a ring of 33 under 4,096 with
+    16 query heads to a KV head, at the Command A+ cell's."""
+    c = _CELLS[cell]
+    window = c.get("window", _WINDOW)
+    cols = window // _PAGE + 1
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -262,7 +278,7 @@ def test_windowed_paged_decode_kernel_compiles_for_v5e(one_chip):
 
     def fn(q, k, v, table, sp):
         return da._pallas_paged_decode(q, k, v, table, sp, 128 ** -0.5,
-                                       None, None, _WINDOW)
+                                       None, None, window)
 
     text = _compile(fn, sds((c["lanes"], c["heads"], 1, 128), jnp.float32),
                     k, k, sds((c["lanes"], cols), jnp.int32),
@@ -311,7 +327,7 @@ def _inplace_step(cell, rows, t_len, path, one_chip):
     text = fn.lower(params, *args, *stores).compile().as_text()
     # the stores follow the logits, for a model that routes its load, and
     # the ids
-    out0 = 2 + (cell == "mellum")
+    out0 = 2 + (cell in _ROUTED)
     pairs = {(out0 + j, len(params) + first + j) for j in range(len(stores))}
     assert not any(o == out0 - 1 for o, _ in _aliases(text)), "ids aliased"
     return text, stores, pairs
@@ -343,7 +359,7 @@ def _no_copy_of(stores, text):
 
 @pytest.mark.parametrize("cell,path", [
     ("mistral", "pallas"), ("mistral", "int8"), ("falcon", "pallas"),
-    ("mellum", "pallas")])
+    ("mellum", "pallas"), ("command_a", "pallas")])
 def test_decode_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell,
                                                path):
     """The (lanes, 1) decode step: every cache store is an input-output
@@ -366,7 +382,8 @@ def test_decode_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell,
     _no_copy_of(stores, text)
 
 
-@pytest.mark.parametrize("cell", ["mistral", "falcon", "mellum"])
+@pytest.mark.parametrize("cell", ["mistral", "falcon", "mellum",
+                                  "command_a"])
 def test_prefill_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell):
     """The (1, 128) prefill chunk: no Mosaic call (the benchmark tells the
     two step executables apart by it), every store aliased, no copy of a

@@ -31,7 +31,7 @@ import mxnet_tpu as mx
 from mxnet_tpu import serve
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.models import llama
-from mxnet_tpu.models.mellum import MellumModel, MellumMoE
+from mxnet_tpu.models.mellum import MellumModel, RoutedFFN
 from mxnet_tpu.ops import nn as ops
 from mxnet_tpu.ops.pallas import decode_attention as da
 from mxnet_tpu.profiler import core as prof
@@ -55,12 +55,11 @@ def _harness():
 class Bundle:
     """The program's model and the reference over the same weights."""
 
-    def __init__(self):
+    def __init__(self, config="mellum2_12b_a2_5b.json"):
         import jax.numpy as jnp
 
-        h = _harness()
-        with open(os.path.join(ROOT, "chipbench", "configs",
-                               "mellum2_12b_a2_5b.json")) as f:
+        self.h = h = _harness()
+        with open(os.path.join(ROOT, "chipbench", "configs", config)) as f:
             self.published = json.load(f)
         self.cfg = cfg = h.merged(self.published, self.published["rehearse"])
         self.ref = h.load_module("reference", cfg["reference"])
@@ -415,7 +414,7 @@ def test_every_token_to_the_same_experts_loses_none(impl):
     x[..., 0] = 1.0 + np.abs(x[..., 0])         # every token: 2, then 5
     x = mx.np.array(x)
     out, load = _routed(x, p, 2, impl)
-    assert load.tolist() == [2, 40, 80]
+    assert load.tolist() == [2, 40, 80, 80, 8]
     close(out.reshape(-1, 16), _by_hand(x, p, 2), 1e-5)
 
 
@@ -427,7 +426,7 @@ def test_tokens_that_are_not_live_go_to_no_expert(impl):
     live = np.zeros((2, 6), bool)
     live[0, :4] = True
     out, load = _routed(x, p, 2, impl, token_live=mx.np.array(live))
-    assert load[2] == 8 and not out[1].any() and not out[0, 4:].any()
+    assert load[2] == load[3] == 8 and not out[1].any() and not out[0, 4:].any()
     close(out[0, :4], _by_hand(x, p, 2)[:4], 1e-5)
 
 
@@ -450,11 +449,11 @@ def test_the_four_quarters_add_up_to_the_whole_layer(impl):
 
 
 def test_a_block_that_holds_a_quarter():
-    moe = MellumMoE(16, 12, 8, 3, experts_held=(2, 2))
+    moe = RoutedFFN(16, 12, 8, 3, experts_held=(2, 2))
     assert moe.gate_weight.shape == (2, 16, 12)
     assert moe.router.weight.shape == (8, 16)
     with pytest.raises(MXNetError, match="holding"):
-        MellumMoE(16, 12, 8, 3, experts_held=(7, 2))
+        RoutedFFN(16, 12, 8, 3, experts_held=(7, 2))
 
 
 # -- (h) a model with no window and no routed layer is served as it was ----------
