@@ -95,19 +95,22 @@ class RoutedFFN(HybridBlock):
                 "shared_down_weight", shape=(n, expert_size, units))
 
     def forward(self, x, cache=None):
-        # a prefill chunk walks its sorted assignments in tiles of rows
-        # (N x k expert-rows of work); the normal path
-        # and a decode step multiply every token with every held expert
-        # and weight the products: differentiable, and at a decode step's
-        # few rows the faster of the two on the chip, where either way
-        # the time goes to reading the experts (PERF.md, PR 33)
+        # the normal path multiplies every token with every held expert
+        # (differentiable); a cached call takes the form its shapes ask
+        # for (``ops.nn.expert_form``): a chunk walks tiles of its sorted
+        # assignments, and so does a decode step whose few rows can pick
+        # a minority of the held experts (one nobody picked is not read);
+        # a decode step whose rows pick nearly all of them multiplies with
+        # the whole stack at once (PERF.md, PR 33 and PR 36)
         live = None if cache is None else cache.token_live(x.shape[1])
-        grouped = cache is not None and x.shape[1] > 1
+        impl = "dense" if cache is None else _ops.expert_form(
+            x.shape[0] * x.shape[1], x.shape[1], self._top_k,
+            self.router.weight.shape[0])
         out, load = _ops.routed_experts(
             x, self.router.weight.data(), self.gate_weight.data(),
             self.up_weight.data(), self.down_weight.data(), self._top_k,
             held=self._held, token_live=live, renormalize=self._renorm,
-            impl="grouped" if grouped else "dense", score=self._score)
+            impl=impl, score=self._score)
         if cache is not None:
             cache.note_route(load)
         if self._shared:

@@ -1636,15 +1636,50 @@ def ssd_scan(x, dt, a, b_mat, c_mat, d, state=None, start_pos=None,
 # and the result is the part of the sum that the held experts give; the
 # parts of all the ranges add up to the whole layer.
 #
-# Two formulations of the expert products, the same mathematics:
-# ``grouped`` sorts the (token, expert) assignments by expert and walks
-# tiles of ``tile`` sorted rows, each against the one expert it belongs to
-# (a while loop whose trip count is the tiles in use: an expert nobody
-# picked is never read, N x k rows of work whatever E is; 32 rows a tile
-# take half the time of 8 on a v5e at 1,024 assignments: PERF.md, PR 33);
-# ``dense`` multiplies every token with every held expert and weights the
-# products (differentiable, E / k times the work).
+# Two formulations of the expert products, the same mathematics; which
+# one a cached call takes is ``expert_form``'s to say, from the call's
+# shapes. ``grouped`` sorts the (token, expert) assignments by expert and
+# walks tiles of ``tile`` sorted rows, each against the one expert it
+# belongs to (a while loop whose trip count is the tiles in use: an expert
+# nobody picked is never read, N x k rows of work whatever E is; 32 rows a
+# tile take half the time of 8 on a v5e at 1,024 assignments: PERF.md,
+# PR 33): a call of many positions, and a decode step whose few rows can
+# hit a minority of the held experts. ``dense`` multiplies every token
+# with every held expert in one product over the stack and weights the
+# products (differentiable, E / k times the work): the normal path, and a
+# decode step whose rows hit nearly all the held experts.
 # ---------------------------------------------------------------------------
+
+# The share of the held experts a decode step can expect to hit under
+# which it walks the tiles. A property of the two formulations on a v5e,
+# from the row sweep of exp/moe_products_bench.py (PERF.md, PR 36): an
+# expert read costs the tiles 1.5 times what it costs the one product over
+# the stack at 64 experts of 2304 x 896 (0.054 against 0.035 ms: a tie at
+# 40 of 64 hit, 8 rows, 0.66 reckoned) and 1.09 times at 8 experts of
+# 4096 x 4096 (0.30 against 0.277 ms: the tiles ahead up to 0.9). The
+# lower crossover stands for both; the cells sit far to either side of it
+# (8 rows, 8 of 128: 0.40; 16 rows, 8 of 64: 0.88).
+GROUPED_UNDER_SHARE = 0.7
+
+
+def expected_hit_share(rows, top_k, num_experts):
+    """The share of the held experts that ``rows`` tokens can expect to
+    hit when each takes ``top_k`` of ``num_experts`` and routing is even
+    (an expert is missed by one token with probability 1 - k / E)."""
+    return 1.0 - (1.0 - top_k / num_experts) ** rows
+
+
+def expert_form(rows, positions, top_k, num_experts):
+    """Which formulation of the expert products (the section comment) a
+    cached call of ``rows`` tokens (lanes x positions) takes, from its
+    static shapes alone. ``"grouped"`` for a call of more than one
+    position (N x k rows of work against N x E), and for a decode step
+    whose ``expected_hit_share`` is under ``GROUPED_UNDER_SHARE``; else
+    ``"dense"``."""
+    if positions > 1 or \
+            expected_hit_share(rows, top_k, num_experts) < GROUPED_UNDER_SHARE:
+        return "grouped"
+    return "dense"
 
 
 def route_top_k(logits, top_k, renormalize=True, score="softmax"):
@@ -1761,11 +1796,12 @@ def routed_experts(data, router_weight, gate, up, down, top_k, held=None,
     ``token_live`` (B, T) bool says which tokens are real (None: all); the
     others are given to no expert and come back as zeros.
 
-    Returns ``(out (B, T, H), load (5,) int32)``: the held experts' part
+    Returns ``(out (B, T, H), load (6,) int32)``: the held experts' part
     of every token's sum, and ``[held experts that got a live token, the
     most tokens one of them got, assignments computed (the live tokens'
     that fell on a held expert), the live tokens' assignments (k each,
-    held here or not), experts held]``.
+    held here or not), experts held, held experts whose weights the call
+    read (all of them for ``dense``, those hit for ``grouped``)]``.
     """
     first, count = (0, gate.shape[0]) if held is None else \
         (int(held[0]), int(held[1]))
@@ -1800,9 +1836,10 @@ def routed_experts(data, router_weight, gate, up, down, top_k, held=None,
                              precision=stored_precision(xf))
             counts = jnp.zeros((count + 1,), jnp.int32).at[
                 local.reshape(-1)].add(1)[:count]
-        load = jnp.stack([jnp.sum(counts > 0), jnp.max(counts),
-                          jnp.sum(counts), jnp.asarray(asked),
-                          jnp.asarray(count)])
+        hit = jnp.sum(counts > 0)
+        load = jnp.stack([hit, jnp.max(counts), jnp.sum(counts),
+                          jnp.asarray(asked), jnp.asarray(count),
+                          jnp.asarray(count) if impl == "dense" else hit])
         return out.reshape(b, t, h), load.astype(jnp.int32)
 
     return _apply(f, (data, router_weight, gate, up, down, token_live),

@@ -605,7 +605,7 @@ class ContinuousEngine:
         with host_span("mxnet_tpu.serve.route") as span:
             # (calls, layers, [held experts hit, most on one, assignments
             # that fell on a held expert, the live tokens' assignments,
-            # experts held])
+            # experts held, held experts whose weights the call read])
             load = _onp.stack([_onp.asarray(a.asnumpy(), _onp.int64)
                                for a in pending])
             hit = int(load[..., 0].sum())
@@ -613,19 +613,22 @@ class ContinuousEngine:
             on_held = int(load[..., 2].sum())
             assignments = int(load[..., 3].sum())
             held = int(load[..., 4].sum())
+            read = int(load[..., 5].sum())
             # the most loaded expert over the mean of those hit, at worst
             skew = float((load[..., 1] * load[..., 0]
                           / _onp.maximum(load[..., 2], 1)).max())
             span.set_metadata(experts_hit=hit, assignments=assignments,
                               max_load=top, calls=len(pending),
-                              experts_held=held, assignments_held=on_held)
+                              experts_held=held, assignments_held=on_held,
+                              experts_read=read)
         m = self._moe
         if m is None:
             m = self._moe = {"reads": 0, "calls": 0, "assignments": 0,
                              "experts_hit": 0, "max_load": 0,
                              "load_max_over_mean": 0.0, "experts_held": 0,
                              "assignments_held": 0,
-                             "assignments_held_share": 0.0}
+                             "assignments_held_share": 0.0,
+                             "experts_read": 0, "picked_share": 0.0}
         m["reads"] += 1
         m["calls"] += len(pending)
         m["assignments"] += assignments
@@ -634,11 +637,14 @@ class ContinuousEngine:
         m["assignments_held"] += on_held
         m["assignments_held_share"] = m["assignments_held"] \
             / max(m["assignments"], 1)
+        m["experts_read"] += read
+        m["picked_share"] = m["experts_hit"] / max(m["experts_read"], 1)
         m["max_load"] = max(m["max_load"], top)
         m["load_max_over_mean"] = skew
         _prof.incr_counter("serve.moe_assignments", assignments, cat="serve")
         _prof.incr_counter("serve.moe_assignments_held", on_held, cat="serve")
         _prof.incr_counter("serve.moe_experts_hit", hit, cat="serve")
+        _prof.incr_counter("serve.moe_experts_read", read, cat="serve")
         _prof.set_counter("serve.moe_load_max_over_mean", skew, cat="serve")
 
     def _recover_pool(self, error):

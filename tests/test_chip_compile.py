@@ -380,6 +380,31 @@ def test_decode_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell,
         assert f"{kind}[{c['lanes']},{c['kv']},{c['max_seq']}," not in text
         assert f"{kind}[{c['lanes']},{c['kv']},{c['max_seq']}]" not in text
     _no_copy_of(stores, text)
+    _expert_form_of(cell, text)
+
+
+def _expert_form_of(cell, text):
+    """Which form of the expert products the decode step took
+    (``ops.nn.expert_form``). Command A+'s 8 lanes can hit 0.40 of its 8
+    held experts of 128: the tiles' loop is in the step, and every array
+    of an expert's width has one of the types the benchmark's
+    ``held_expert_roofline`` (the routed stack, one expert of it) and
+    ``shared_expert_roofline`` (the shared stack) tell the branches by: a
+    reshape of the stack to another type would take its products out of
+    those readings. Mellum-2's 16 lanes can hit 0.88 of its 64: one
+    product over the stack, no loop and no condition."""
+    import re
+
+    loops = re.findall(r" (while|conditional)\(", text)
+    if cell == "command_a":
+        assert "while" in loops
+        assert set(re.findall(r"f32\[(?:\d+,)*4096,4096\]", text)) <= {
+            "f32[8,4096,4096]", "f32[1,4096,4096]", "f32[4096,4096]",
+            "f32[4,4096,4096]"}
+        # a tile's expert, sliced out of each of the three stacks
+        assert text.count("dynamic_slice_sizes={1,4096,4096}") >= 3
+    else:
+        assert not loops
 
 
 @pytest.mark.parametrize("cell", ["mistral", "falcon", "mellum",

@@ -309,7 +309,7 @@ def test_route_loads_report_what_is_held(bundle, monkeypatch):
     assignments each, those that fell on one of the 4 of 16 experts held
     here, and the experts held, a call and a layer."""
     for name in ("serve.moe_assignments", "serve.moe_assignments_held",
-                 "serve.moe_experts_hit"):
+                 "serve.moe_experts_hit", "serve.moe_experts_read"):
         prof.set_counter(name, 0)
     import mxnet_tpu.serve.scheduler as sched
 
@@ -348,14 +348,93 @@ def test_route_loads_report_what_is_held(bundle, monkeypatch):
     assert 0 < moe["experts_hit"] <= moe["experts_held"]
     assert spans and all(
         {"experts_hit", "experts_held", "assignments", "assignments_held",
-         "max_load", "calls"} <= set(s) for s in spans)
+         "max_load", "calls", "experts_read"} <= set(s) for s in spans)
     assert sum(s["assignments_held"] for s in spans) \
         == moe["assignments_held"]
     assert sum(s["experts_held"] for s in spans) == moe["experts_held"]
     assert prof.get_counter("serve.moe_assignments") == moe["assignments"]
     assert prof.get_counter("serve.moe_assignments_held") \
         == moe["assignments_held"]
+    # two lanes of 4 of 16 at top 4 can hit 0.44 of the held experts: the
+    # decode step walks the tiles as the chunk does, and no call reads an
+    # expert that none of its tokens picked
+    assert sum(s["experts_read"] for s in spans) == moe["experts_read"] \
+        == moe["experts_hit"] == prof.get_counter("serve.moe_experts_read")
+    assert moe["picked_share"] == 1.0
     eng.close()
+
+
+# -- the side of ``ops.nn.expert_form`` a served model lands on -----------------------
+
+def _command_like():
+    """2 of 16 experts held at top 4, four lanes: a decode step can hit
+    0.68 of the held experts."""
+    h = _harness()
+    with open(os.path.join(ROOT, "chipbench", "configs",
+                           "command_a_plus_05_2026.json")) as f:
+        pub = json.load(f)
+    cfg = dict(h.merged(pub, pub["rehearse"]), num_experts=2,
+               experts_held=(0, 2))
+    return h.load_module("adapters", cfg["adapter"]).build(cfg, False), 4
+
+
+def _mellum_like():
+    """All 8 experts held at top 2, six lanes: 0.82."""
+    return _mellum()[0], 6
+
+
+@pytest.mark.parametrize("build,form", [(_command_like, "grouped"),
+                                        (_mellum_like, "dense")],
+                         ids=["command_like", "mellum_like"])
+def test_a_served_model_lands_on_its_side_of_the_rule(monkeypatch, build,
+                                                      form):
+    """Through ``ContinuousEngine``: the decode step's form is the one the
+    rule names for the engine's lanes and the model's router (the chunk
+    walks tiles on both sides), ``picked_share`` says how much of what
+    was read had been picked, and the served tokens are those of the same
+    model with its decode step forced to the other form."""
+    da.use_interpret(True)
+    try:
+        net, slots = build()
+        net.initialize(mx.init.Normal(0.1))
+        prompts = tokens_of(21, 11, 19, 9, 14, 17, 12)[:slots]
+
+        def served(force=None):
+            seen, real = set(), ops.expert_form
+
+            def rule(rows, positions, k, e_all):
+                got = real(rows, positions, k, e_all)
+                if positions == 1:
+                    seen.add(got)
+                    return force or got
+                return got
+
+            monkeypatch.setattr(ops, "expert_form", rule)
+            eng = serve.ContinuousEngine(
+                net, max_seq=64, num_slots=slots, page_size=PAGE,
+                prefill_chunk=PAGE, decode_path="pallas")
+            eng.warmup()
+            res = serve_all(eng, prompts, 7)
+            moe = eng.stats()["moe"]
+            eng.close()
+            return [r["tokens"] for r in res], moe, seen
+
+        tokens, moe, seen = served()
+        assert seen == {form}
+        other = "dense" if form == "grouped" else "grouped"
+        forced, theirs, _ = served(force=other)
+        assert forced == tokens
+        assert theirs["experts_hit"] == moe["experts_hit"] > 0
+        tiles, whole = (moe, theirs) if form == "grouped" else (theirs, moe)
+        assert tiles["picked_share"] == 1.0
+        assert tiles["experts_read"] == tiles["experts_hit"]
+        # the one product reads every held expert in every decode call
+        assert whole["experts_hit"] < whole["experts_read"] \
+            <= whole["experts_held"]
+        assert whole["picked_share"] == pytest.approx(
+            whole["experts_hit"] / whole["experts_read"])
+    finally:
+        da.use_interpret(False)
 
 
 # -- what refuses a bounded layer ------------------------------------------------------
@@ -412,7 +491,7 @@ def test_the_other_models_step_arguments_and_results(build, routed):
     hands back what it did: tokens, start_pos, last_idx, the page table
     (a ring table for a model with a window, lanes for one with a state),
     keep and ids, the stores; logits, the routed layers' load for a model
-    that has them (softmax scores, no shared branch: five numbers a layer
+    that has them (softmax scores, no shared branch: six numbers a layer
     now, the first three what they were), ids, the stores."""
     da.use_interpret(True)
     try:
@@ -439,7 +518,7 @@ def test_the_other_models_step_arguments_and_results(build, routed):
         n_args = 6 + int(windowed) + int(layout.has_state) + len(layout)
         assert set(seen) == {(n_args, 2 + int(routed) + len(layout))}
         # one row a layer (the tiny Mellum-2 has four)
-        assert set(loads) == ({(4, 5)} if routed else set())
+        assert set(loads) == ({(4, 6)} if routed else set())
         assert ("moe" in eng.stats()) == routed
         if routed:
             moe = eng.stats()["moe"]

@@ -383,11 +383,13 @@ def _routed(x, p, k, impl, held=None, **kw):
     return out.asnumpy(), load.asnumpy()
 
 
-def _by_hand(x, p, k):
-    """Every token's k experts, one token and one expert at a time."""
+def _by_hand(x, p, k, held=None):
+    """Every token's k experts, one token and one expert at a time; with
+    ``held`` (first, count) the part of the sum those experts give."""
     x = x.asnumpy().reshape(-1, x.shape[-1]).astype(np.float64)
     r, g, u, d = (p[n].asnumpy().astype(np.float64)
                   for n in ("router", "gate", "up", "down"))
+    first, count = held or (0, len(g))
     out = np.zeros_like(x)
     for n, row in enumerate(x):
         z = r @ row
@@ -395,6 +397,8 @@ def _by_hand(x, p, k):
         prob /= prob.sum()
         pick = sorted(range(len(prob)), key=lambda e: (-prob[e], e))[:k]
         for e in pick:
+            if not first <= e < first + count:
+                continue
             a = row @ g[e]
             out[n] += prob[e] / prob[pick].sum() \
                 * ((a / (1 + np.exp(-a)) * (row @ u[e])) @ d[e])
@@ -414,7 +418,8 @@ def test_every_token_to_the_same_experts_loses_none(impl):
     x[..., 0] = 1.0 + np.abs(x[..., 0])         # every token: 2, then 5
     x = mx.np.array(x)
     out, load = _routed(x, p, 2, impl)
-    assert load.tolist() == [2, 40, 80, 80, 8]
+    # the tiles read the two experts hit, the one product all eight
+    assert load.tolist() == [2, 40, 80, 80, 8, 2 if impl == "grouped" else 8]
     close(out.reshape(-1, 16), _by_hand(x, p, 2), 1e-5)
 
 
@@ -454,6 +459,133 @@ def test_a_block_that_holds_a_quarter():
     assert moe.router.weight.shape == (8, 16)
     with pytest.raises(MXNetError, match="holding"):
         RoutedFFN(16, 12, 8, 3, experts_held=(7, 2))
+
+
+# -- (g') a decode step's rows: one position a lane ------------------------------
+
+def _steered(seed, first_choice, e=16):
+    """Experts whose router sends every token with a positive first
+    feature to ``first_choice`` before any other."""
+    p = _experts(seed, e=e)
+    router = 0.05 * np.random.RandomState(seed).randn(e, 16).astype("float32")
+    router[:, 0] = 0.0
+    router[first_choice, 0] = 6.0
+    p["router"] = mx.np.array(router)
+    return p
+
+
+def _lanes(seed, n=8):
+    x = np.random.RandomState(seed).randn(n, 1, 16).astype("float32")
+    x[..., 0] = 1.0 + np.abs(x[..., 0])
+    return mx.np.array(x)
+
+
+@pytest.mark.parametrize("impl", ["grouped", "dense"])
+@pytest.mark.parametrize("case", ["nobody_picked_a_held_expert",
+                                  "dead_lanes", "one_expert_takes_every_row"])
+def test_a_decode_steps_rows(impl, case):
+    """(8, 1) calls, the shape the tiles had never run at: both forms
+    against the sum computed a token and an expert at a time, and the
+    load's sixth number: the held experts whose weights the call read
+    (those hit for the tiles, all of them for the one product)."""
+    held, k = (4, 4), 1
+    read = (lambda hit: hit) if impl == "grouped" else (lambda hit: held[1])
+    x = _lanes(11)
+    if case == "nobody_picked_a_held_expert":
+        # every row's one expert is held elsewhere: no tile, zeros
+        out, load = _routed(x, _steered(12, 9), k, impl, held=held)
+        assert not out.any()
+        assert load.tolist() == [0, 0, 0, 8, 4, read(0)]
+    elif case == "dead_lanes":
+        p = _experts(13, e=16)
+        live = np.array([1, 0, 1, 0, 0, 1, 0, 0], bool).reshape(8, 1)
+        out, load = _routed(x, p, 4, impl, held=held,
+                            token_live=mx.np.array(live))
+        want = _by_hand(x, p, 4, held=held)
+        assert not out[~live[:, 0]].any()
+        close(out[live[:, 0], 0], want[live[:, 0]], 1e-5)
+        on_held = int(load[2])
+        assert 0 < on_held <= 12 and load[3] == 12 and load[4] == 4
+        assert 0 < load[0] <= min(4, on_held) and load[5] == read(load[0])
+    else:
+        p = _steered(14, 6)
+        out, load = _routed(x, p, k, impl, held=held)
+        assert load.tolist() == [1, 8, 8, 8, 4, read(1)]
+        close(out[:, 0], _by_hand(x, p, k, held=held), 1e-5)
+
+
+@pytest.mark.parametrize("impl", ["grouped", "dense"])
+def test_a_decode_steps_forms_agree(impl):
+    """Eight rows over 4 held of 16 experts at top 4 (about eight
+    assignments fall on a held expert): the form against the plain loop,
+    and the two forms' loads alike but for the sixth number."""
+    p, x, held = _experts(15, e=16), _lanes(16), (8, 4)
+    out, load = _routed(x, p, 4, impl, held=held)
+    close(out[:, 0], _by_hand(x, p, 4, held=held), 1e-5)
+    other, theirs = _routed(x, p, 4, "dense" if impl == "grouped"
+                            else "grouped", held=held)
+    close(out, other, 1e-5)
+    assert load[:5].tolist() == theirs[:5].tolist()
+    assert load[5] == (load[0] if impl == "grouped" else 4)
+
+
+# -- (g'') which form a cached call takes ------------------------------------------
+
+@pytest.mark.parametrize("rows,positions,k,e_all,form", [
+    (8, 1, 8, 128, "grouped"),       # Command A+'s decode step: 0.40 hit
+    (16, 1, 8, 64, "dense"),         # Mellum-2's: 0.88
+    (128, 128, 8, 128, "grouped"),   # both cells' prefill chunks
+    (128, 128, 8, 64, "grouped"),
+    (16, 2, 8, 8, "grouped"),        # more than one position, whatever else
+    (1, 1, 8, 8, "dense"),           # one row that takes every expert
+    (1, 1, 8, 64, "grouped"),        # one row that takes an eighth
+], ids=["command_decode", "mellum2_decode", "command_chunk", "mellum2_chunk",
+        "two_positions", "one_row_every_expert", "one_row_an_eighth"])
+def test_the_form_a_cached_call_takes(rows, positions, k, e_all, form):
+    assert ops.expert_form(rows, positions, k, e_all) == form
+
+
+@pytest.mark.parametrize("k,e_all", [(8, 128), (8, 64), (2, 8), (4, 16),
+                                     (1, 256)])
+def test_more_rows_never_turn_a_decode_step_back_to_the_tiles(k, e_all):
+    forms = [ops.expert_form(n, 1, k, e_all) for n in range(1, 513)]
+    turn = forms.index("dense")
+    assert turn > 0 and set(forms[:turn]) == {"grouped"}
+    assert set(forms[turn:]) == {"dense"}
+
+
+@pytest.mark.parametrize("shape,num,cached,form", [
+    ((8, 1), 128, True, "grouped"), ((16, 1), 64, True, "dense"),
+    ((1, 5), 64, True, "grouped"), ((16, 1), 128, False, "dense"),
+    ((1, 5), 128, False, "dense")],
+    ids=["few_rows_of_many_experts", "many_rows_of_few_experts", "a_chunk",
+         "no_cache_one_position", "no_cache_a_sequence"])
+def test_the_block_asks_the_rule(monkeypatch, shape, num, cached, form):
+    """``RoutedFFN.forward`` hands ``routed_experts`` the form that
+    ``expert_form`` names for the call's shapes, and ``dense`` (the
+    differentiable one) on the normal path whatever the shapes."""
+    seen, real = [], ops.routed_experts
+
+    def spy(*args, **kw):
+        seen.append(kw["impl"])
+        return real(*args, **kw)
+
+    class View:
+        """What a layer's cache view is asked by the feed-forward."""
+        def token_live(self, t_len):
+            return None
+
+        def note_route(self, load):
+            assert load.shape == (6,)
+
+    monkeypatch.setattr(ops, "routed_experts", spy)
+    moe = RoutedFFN(16, 12, num, 8, experts_held=(0, 8))
+    moe.initialize(mx.init.Normal(0.1))
+    x = mx.np.array(np.random.RandomState(3).randn(*shape, 16)
+                    .astype("float32"))
+    with mx.autograd.predict_mode():
+        moe(x, cache=View()) if cached else moe(x)
+    assert seen == [form]
 
 
 # -- (h) a model with no window and no routed layer is served as it was ----------
@@ -537,7 +669,8 @@ def test_what_cannot_serve_a_bounded_layer_says_so(bundle):
 # -- the spans, counters and stats of the routed layers ---------------------------
 
 def test_route_loads_are_read_back_and_counted(bundle):
-    for name in ("serve.moe_assignments", "serve.moe_experts_hit"):
+    for name in ("serve.moe_assignments", "serve.moe_experts_hit",
+                 "serve.moe_experts_read"):
         prof.set_counter(name, 0)
     eng = bundle.engine(slots=2)
     eng.warmup()
@@ -554,6 +687,11 @@ def test_route_loads_are_read_back_and_counted(bundle):
     assert moe["load_max_over_mean"] >= 1.0
     assert prof.get_counter("serve.moe_assignments") == moe["assignments"]
     assert prof.get_counter("serve.moe_experts_hit") == moe["experts_hit"]
+    # two lanes of 8 experts at top 2 can hit 0.44 of them: tiles in both
+    # executables, so what a call read is what its tokens picked
+    assert prof.get_counter("serve.moe_experts_read") \
+        == moe["experts_read"] == moe["experts_hit"]
+    assert moe["picked_share"] == 1.0
     assert prof.get_counter("serve.moe_load_max_over_mean") \
         == pytest.approx(moe["load_max_over_mean"])
     assert not eng._route_pending
