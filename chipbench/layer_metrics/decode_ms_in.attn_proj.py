@@ -1,0 +1,9 @@
+"""Mean device time of one decode step (the executable whose operations stand
+under ``serve_step.decode``) in the attention block outside its core: the q,
+k, v and o products and the rotation (``attn.rope``); self times of the
+device events by their scope path, ``device_scopes.py``."""
+import device_scopes
+
+
+def read(trace, counters, record):
+    return device_scopes.metric(trace, "decode", "attn_proj")

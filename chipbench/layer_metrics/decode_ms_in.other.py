@@ -1,0 +1,10 @@
+"""Mean device time of one decode step (the executable whose operations stand
+under ``serve_step.decode``) in what no part of this cell's list takes: the
+layers' norms, residual adds, anything under no scope the reader's tables
+know; self times of the device events by their scope path,
+``device_scopes.py``."""
+import device_scopes
+
+
+def read(trace, counters, record):
+    return device_scopes.metric(trace, "decode", "other")

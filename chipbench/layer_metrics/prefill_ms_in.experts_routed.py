@@ -1,0 +1,10 @@
+"""Mean device time of one prefill chunk (the executable whose operations stand
+under ``serve_step.prefill``) in the routed experts: scores, top-k, the sort
+and the combine (``experts.router``) and the experts' products
+(``experts.routed``); self times of the device events by their scope path,
+``device_scopes.py``."""
+import device_scopes
+
+
+def read(trace, counters, record):
+    return device_scopes.metric(trace, "prefill", "experts_routed")

@@ -1,0 +1,8 @@
+"""Mean device time of one training step in the encoder's blocks, forward and
+backward; self times of the device events by their scope path, a chip's
+mean, ``device_scopes.py``."""
+import device_scopes
+
+
+def read(trace, counters, record):
+    return device_scopes.metric(trace, "train", "encoder")

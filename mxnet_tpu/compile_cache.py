@@ -23,7 +23,9 @@ How it composes with the in-memory signature cache:
   explicit ``path`` then have no say). Otherwise an explicit
   ``enable(path)`` or ``MXNET_COMPILE_CACHE_DIR``.
 * Disk keys are **content** keys (JAX fingerprints the lowered HLO +
-  compile options + backend), so they are process-independent exactly
+  compile options + backend; since PR 37 with its metadata, the scope
+  paths and source lines every instruction carries: see :func:`enable`),
+  so they are process-independent exactly
   when the traced computation is — which is what
   :func:`mxnet_tpu.cachedop.stable_signature_key` pins for the
   signature-level contract (two processes, same model + bucket lattice
@@ -100,6 +102,16 @@ def enable(path=None):
         # everything so the second process compiles literally nothing
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        # an executable's names are part of what it is: the scopes the
+        # program writes while tracing (profiler.core.DEVICE_SCOPES) live
+        # in HLO metadata, which JAX leaves out of the key by default, so
+        # a directory warmed by a program from before a scope existed, or
+        # with another path to a block, would hand back an executable
+        # whose device events carry the old names or none. With metadata
+        # in the key such an entry is a miss: compiled once, written
+        # beside the old one, a hit ever after
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
         if not _listener_on:
             monitoring.register_event_listener(_on_event)
             _listener_on = True

@@ -14,6 +14,7 @@ definition — the role of ``model-symbol.json`` + ``model-0000.params``.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import re
 from collections import OrderedDict
@@ -26,8 +27,15 @@ from ..ndarray.ndarray import NDArray
 from .parameter import Parameter, ParameterDict
 
 
+_NO_SCOPE = contextlib.nullcontext()
+
+
 class Block:
     """Base class for all neural-network layers and models."""
+
+    # the name the parent registered this block under: its scope in a
+    # compiled program's metadata (``__call__``); None for a root
+    _scope_name = None
 
     def __init__(self):
         self._children = OrderedDict()
@@ -42,6 +50,7 @@ class Block:
             existing = self.__dict__.get("_children")
             if existing is not None:
                 existing[name] = value
+                value._scope_name = name
                 params_changed()
         elif isinstance(value, Parameter):
             reg = self.__dict__.get("_reg_params")
@@ -55,6 +64,7 @@ class Block:
         if name is None:
             name = str(len(self._children))
         self._children[name] = block
+        block._scope_name = name
         super().__setattr__(f"_child_{name}", block)
         params_changed()
         return block
@@ -170,10 +180,24 @@ class Block:
     def __call__(self, *args, **kwargs):
         for hook in self._forward_pre_hooks.values():
             hook(self, args)
-        out = self.forward(*args, **kwargs)
+        with self.trace_scope():
+            out = self.forward(*args, **kwargs)
         for hook in self._forward_hooks.values():
             hook(self, args, out)
         return out
+
+    def trace_scope(self):
+        """What this block's work is named in a compiled program: under a
+        trace (a ``CachedOp``'s, or a ``functionalize``d replay's) the
+        scope of the name its parent registered it under, so that a device
+        event reads ``layer3/attention/...``; eagerly, and for a root,
+        nothing. A caller that computes with a child's parameters without
+        calling the child enters it for the child."""
+        if self._scope_name is None or not in_trace():
+            return _NO_SCOPE
+        from jax import named_scope
+
+        return named_scope(self._scope_name)
 
     def forward(self, *args):
         raise NotImplementedError

@@ -125,9 +125,16 @@ def _dense_on(cache):
     quant side table (:func:`_serving_dense`)."""
     if cache is None:
         return lambda h, layer: layer(h)
-    if getattr(cache, "path", "baseline") == "baseline":
-        return lambda h, layer: _ops.stable_dense(h, layer.weight.data())
-    return lambda h, layer: _serving_dense(h, layer.weight, cache)
+    baseline = getattr(cache, "path", "baseline") == "baseline"
+
+    def dense(h, layer):
+        # the layer is not called, so its scope is entered for it
+        with layer.trace_scope():
+            if baseline:
+                return _ops.stable_dense(h, layer.weight.data())
+            return _serving_dense(h, layer.weight, cache)
+
+    return dense
 
 
 class LlamaAttention(HybridBlock):
@@ -247,15 +254,11 @@ class LlamaAttention(HybridBlock):
                     "continuous engine's fast rungs alone")
             # stable_dense, not Dense: the whole cache path must be
             # shape-stable so T=1 decode bitwise-matches T=bucket prefill
-            q = self._heads_split(
-                _ops.stable_dense(x, self.q_proj.weight.data()),
-                self._heads)
-            k = self._heads_split(
-                self._keys(_ops.stable_dense(x, self.k_proj.weight.data())),
-                self._kv_heads)
-            v = self._heads_split(
-                _ops.stable_dense(x, self.v_proj.weight.data()),
-                self._kv_heads)
+            dense = _dense_on(cache)
+            q = self._heads_split(dense(x, self.q_proj), self._heads)
+            k = self._heads_split(self._keys(dense(x, self.k_proj)),
+                                  self._kv_heads)
+            v = self._heads_split(dense(x, self.v_proj), self._kv_heads)
             q, k = self._rotate(q, k, cache.max_seq, start_pos)
             k_all = _ops.kv_cache_write(cache.k, k, start_pos)
             v_all = _ops.kv_cache_write(cache.v, v, start_pos)
@@ -265,7 +268,7 @@ class LlamaAttention(HybridBlock):
                 v_all = mnp.repeat(v_all, rep, axis=1)
             out = _ops.cached_attention(q, k_all, v_all, start_pos)
             out = out.transpose(0, 2, 1, 3).reshape(b, t, self._q_units)
-            return _ops.stable_dense(out, self.o_proj.weight.data())
+            return dense(out, self.o_proj)
         out = out.transpose(0, 2, 1, 3).reshape(b, t, self._q_units)
         return self.o_proj(out)
 
@@ -274,13 +277,11 @@ class LlamaAttention(HybridBlock):
         and the fused decode-attention kernel, which consumes the GQA K/V
         rings *unexpanded* — tolerance parity, not the bitwise contract."""
         b, t, _ = x.shape
-        q = self._heads_split(_serving_dense(x, self.q_proj.weight, cache),
-                              self._heads)
-        k = self._heads_split(
-            self._keys(_serving_dense(x, self.k_proj.weight, cache)),
-            self._kv_heads)
-        v = self._heads_split(_serving_dense(x, self.v_proj.weight, cache),
+        dense = _dense_on(cache)
+        q = self._heads_split(dense(x, self.q_proj), self._heads)
+        k = self._heads_split(self._keys(dense(x, self.k_proj)),
                               self._kv_heads)
+        v = self._heads_split(dense(x, self.v_proj), self._kv_heads)
         q, k = self._rotate(q, k, cache.max_seq, start_pos)
         # a layer whose K/V are pages (the engine's step) says so with its
         # page table: rows are written into their pages and read there
@@ -312,7 +313,7 @@ class LlamaAttention(HybridBlock):
                                         path=path, page_table=table,
                                         window=window)
         out = out.transpose(0, 2, 1, 3).reshape(b, t, self._q_units)
-        return _serving_dense(out, self.o_proj.weight, cache)
+        return dense(out, self.o_proj)
 
 
 class LlamaFFN(HybridBlock):
@@ -431,12 +432,8 @@ class LlamaModel(HybridBlock):
                     # the fence exists for the bitwise contract; the fast
                     # rungs want cross-layer fusion
                     x = _ops.fusion_fence(x)
-            x = self.norm(x)
-            w_param = (self.embed.weight if self._tie
-                       else self.lm_head.weight)
-            if fast:
-                return _serving_dense(x, w_param, cache)
-            return _ops.stable_dense(x, w_param.data())
+            return _dense_on(cache)(
+                self.norm(x), self.embed if self._tie else self.lm_head)
         if self._remat and in_trace():
             # only under a functionalized trace (ShardedTrainer/CachedOp):
             # the eager tape records per-op and cannot see through

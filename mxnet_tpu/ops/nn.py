@@ -25,6 +25,7 @@ import numpy as _onp
 from .. import autograd
 from .. import random as _rng
 from ..base import MXNetError
+from ..profiler.core import device_scope as _device_scope
 from .registry import apply as _apply
 from .registry import register as _register
 
@@ -39,6 +40,21 @@ def _lax():
     import jax.lax as lax
 
     return lax
+
+
+def _scoped(name):
+    """Decorator: a function on raw arrays whose work a compiled program
+    names ``name`` (``profiler.core.OP_SCOPES``). A plain closure, so the
+    eager jit cache keys the result as it keys the function."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with _device_scope(name):
+                return fn(*args, **kwargs)
+
+        return scoped
+
+    return wrap
 
 
 def _tup(v, n):
@@ -485,6 +501,7 @@ def layer_norm(data, gamma, beta=None, axis=-1, eps=1e-5):
     """``beta=None``: a gain and no bias."""
     jnp = _jnp()
 
+    @_scoped("norm")
     def f(x, g, *b):
         mean = jnp.mean(x, axis=axis, keepdims=True)
         var = jnp.var(x, axis=axis, keepdims=True)
@@ -502,6 +519,7 @@ def rms_norm(data, gamma, axis=-1, eps=1e-6):
     """RMSNorm (no reference analog; required by the Llama model family)."""
     jnp = _jnp()
 
+    @_scoped("norm")
     def f(x, g):
         ms = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=axis, keepdims=True)
         out = x * (1.0 / jnp.sqrt(ms + eps)).astype(x.dtype)
@@ -606,6 +624,7 @@ def embedding(data, weight, input_dim=None, output_dim=None, dtype=None,
     """
     jnp = _jnp()
 
+    @_scoped("embed")
     def f(idx, w):
         return jnp.take(w, idx.astype(jnp.int32), axis=0)
 
@@ -907,6 +926,7 @@ def kv_cache_write(cache, new, start_pos, page_table=None, window=None):
                          "ring of pages (the continuous engine's in-place "
                          "step): it has no contiguous ring to write")
 
+    @_scoped("kv.write")
     def f(c, n, sp):
         jnp = _jnp()
         s_len = c.shape[2]
@@ -944,6 +964,7 @@ def kv_cache_write_q(cache_q, cache_scale, new, start_pos, page_table=None):
     ``kv_cache_write``'s.
     """
     if page_table is not None:
+        @_scoped("kv.write")
         def paged(pq, ps, t, n, sp):
             nq, scale = _quantize_rows(n)
             return write_pages(pq, t, nq, sp), write_pages(ps, t, scale, sp)
@@ -951,6 +972,7 @@ def kv_cache_write_q(cache_q, cache_scale, new, start_pos, page_table=None):
         return _apply(paged, (cache_q, cache_scale, page_table, new,
                               start_pos), name="paged_kv_write_q")
 
+    @_scoped("kv.write")
     def f(cq, cs, n, sp):
         jnp = _jnp()
         s_len = cq.shape[2]
@@ -1077,6 +1099,7 @@ def cached_attention(query, key, value, start_pos, scale=None,
             args = args + (page_table,)
         return _apply(f, args, name="cached_attention_fast")
 
+    @_scoped("attn.scores")
     def f(q, k, v, sp):
         jnp = _jnp()
         t_len = q.shape[2]
@@ -1101,6 +1124,7 @@ def rope_positions(cos_table, sin_table, start_pos, length):
     from (S, D/2) tables; returns a ``(cos, sin)`` pair shaped
     (B, 1, length, D/2) — broadcastable over the head axis."""
 
+    @_scoped("attn.rope")
     def f(ct, st, sp):
         jnp = _jnp()
         pos = sp.astype(jnp.int32)[:, None] \
@@ -1187,6 +1211,7 @@ def gather_positions(data, indices):
     (B,) -> (B, ...). Serving uses it to pick each request's last-real-
     position logits out of a padded prefill block."""
 
+    @_scoped("head")
     def f(x, i):
         jnp = _jnp()
         idx = i.astype(jnp.int32).reshape((-1,) + (1,) * (x.ndim - 1))
@@ -1281,6 +1306,7 @@ def sample_step(logits, temperature, top_k, seeds, positions, key_bits):
 # ---------------------------------------------------------------------------
 
 
+@_scoped("kv.gather")
 def gather_pages(p, t):
     """:func:`paged_kv_gather` on raw arrays."""
     jnp = _jnp()
@@ -1294,6 +1320,7 @@ def gather_pages(p, t):
     return g.reshape(b, kv, n * pg)
 
 
+@_scoped("kv.write")
 def write_pages(p, t, new, sp, ring=False):
     """``new`` (B, KV, T, D) — or (B, KV, T) scale rows — written
     straight into the pool ``p`` (P, KV, page[, D]) at positions
@@ -1443,6 +1470,7 @@ def state_rows_gather(store, lanes):
     if store.shape[0] == lanes.shape[0]:
         return store
 
+    @_scoped("ssm.state")
     def f(st, ln):
         jnp = _jnp()
         return jnp.take(st, jnp.maximum(ln.astype(jnp.int32), 0), axis=0)
@@ -1457,6 +1485,7 @@ def state_rows_scatter(store, lanes, rows):
     if store.shape[0] == lanes.shape[0]:
         return rows
 
+    @_scoped("ssm.state")
     def f(st, ln, new):
         jnp = _jnp()
         ln = ln.astype(jnp.int32)
@@ -1479,6 +1508,7 @@ def carried_tokens(tokens, ids, rows):
     row's carried id: the per-row choice between a token the host sent
     and the one the call before left on the device."""
 
+    @_scoped("embed")
     def f(tok, kept, at):
         jnp = _jnp()
         mine = jnp.take(kept, jnp.maximum(at.astype(jnp.int32), 0))
@@ -1493,6 +1523,7 @@ def keep_greedy_ids(ids, rows, logits):
     ``serve.generate.sample_tokens`` picks from the same row); a batch
     row bound to none is dropped."""
 
+    @_scoped("head")
     def f(kept, at, lg):
         jnp = _jnp()
         at = at.astype(jnp.int32)
@@ -1507,6 +1538,7 @@ def grouped_rms_norm(data, gamma, groups=1, eps=1e-6):
     """RMSNorm over each of ``groups`` equal slices of the last axis
     (Mamba-2's gated norm with ``n_groups`` > 1), then the gain."""
 
+    @_scoped("norm")
     def f(x, g):
         jnp = _jnp()
         xg = x.reshape(x.shape[:-1] + (groups, x.shape[-1] // groups))
@@ -1527,6 +1559,7 @@ def causal_conv1d(data, weight, bias, state=None, start_pos=None,
     K-1 inputs at or before position ``valid_len[b] - 1``.
     """
 
+    @_scoped("ssm.conv")
     def f(x, w, b, st, sp, vl, lv):
         jnp = _jnp()
         t_len, k = x.shape[1], w.shape[1]
@@ -1568,6 +1601,7 @@ def ssd_scan(x, dt, a, b_mat, c_mat, d, state=None, start_pos=None,
     """
     chunk = int(chunk)
 
+    @_scoped("ssm.scan")
     def f(xv, dtv, av, bv, cv, dv, st, sp, vl, lv):
         jnp = _jnp()
         t_len, heads = xv.shape[1], xv.shape[2]
@@ -1682,6 +1716,7 @@ def expert_form(rows, positions, top_k, num_experts):
     return "dense"
 
 
+@_scoped("experts.router")
 def route_top_k(logits, top_k, renormalize=True, score="softmax"):
     """``(weights (N, k), experts (N, k) int32)`` of router ``logits``
     (N, E), on raw arrays: the scores in float32 (``score``: ``"softmax"``
@@ -1726,17 +1761,18 @@ def grouped_expert_products(x, local, gate, up, down, tile=32):
     a = n * k
     tile = int(tile)
     flat = local.reshape(a)
-    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-    edges = jnp.searchsorted(
-        flat[order], jnp.arange(e_held + 1, dtype=jnp.int32),
-        side="left").astype(jnp.int32)                           # (E + 1,)
-    counts = edges[1:] - edges[:-1]
-    # tiles of ``tile`` sorted rows, none across two experts: expert e has
-    # ceil(count / tile), in expert order
-    ends = jnp.cumsum((counts + tile - 1) // tile).astype(jnp.int32)
+    with _device_scope("experts.router"):   # the sort by expert
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        edges = jnp.searchsorted(
+            flat[order], jnp.arange(e_held + 1, dtype=jnp.int32),
+            side="left").astype(jnp.int32)                       # (E + 1,)
+        counts = edges[1:] - edges[:-1]
+        # tiles of ``tile`` sorted rows, none across two experts: expert e
+        # has ceil(count / tile), in expert order
+        ends = jnp.cumsum((counts + tile - 1) // tile).astype(jnp.int32)
+        # slices never clamp: a tile may start on the last row
+        xs = jnp.concatenate([x[order // k], jnp.zeros((tile, h), x.dtype)])
     prec = stored_precision(x, gate)
-    # slices never clamp: a tile may start on the last row
-    xs = jnp.concatenate([x[order // k], jnp.zeros((tile, h), x.dtype)])
     row_in_tile = jnp.arange(tile, dtype=jnp.int32)
     zero = jnp.int32(0)   # jax_enable_x64 is on: a bare 0 would be an i64
 
@@ -1754,9 +1790,10 @@ def grouped_expert_products(x, local, gate, up, down, tile=32):
 
     ys = jax.lax.fori_loop(zero, ends[-1], body,
                            jnp.zeros((a + tile, h), x.dtype))
-    back = jnp.zeros((a,), jnp.int32).at[order].set(
-        jnp.arange(a, dtype=jnp.int32))
-    return ys[back].reshape(n, k, h), counts
+    with _device_scope("experts.router"):   # back to the tokens' order
+        back = jnp.zeros((a,), jnp.int32).at[order].set(
+            jnp.arange(a, dtype=jnp.int32))
+        return ys[back].reshape(n, k, h), counts
 
 
 def dense_expert_products(x, gate, up, down):
@@ -1777,6 +1814,7 @@ def shared_experts(data, gate, up, down):
     the routed ones are (the same three products, every token with every
     expert), so that a trace tells the two branches apart by their
     weights' leading size."""
+    @_scoped("experts.shared")
     def f(x, g, u, d):
         jnp = _jnp()
         b, t, h = x.shape
@@ -1811,6 +1849,7 @@ def routed_experts(data, router_weight, gate, up, down, top_k, held=None,
     if impl not in ("grouped", "dense"):
         raise MXNetError(f"routed_experts impl {impl!r}")
 
+    @_scoped("experts.router")
     def f(x, rw, g, u, d, live):
         jnp = _jnp()
         b, t, h = x.shape
@@ -1825,13 +1864,19 @@ def routed_experts(data, router_weight, gate, up, down, top_k, held=None,
             asked = jnp.sum(live) * idx.shape[1]
         local = jnp.where(here, local, count)
         w = jnp.where(here, w, 0.0)
+        # under this function's scope all is the router's (scores, top-k,
+        # the sort and the combine) but the experts' own products
         if impl == "grouped":
-            prod, counts = grouped_expert_products(xf, local, g, u, d, tile)
+            with _device_scope("experts.routed"):
+                prod, counts = grouped_expert_products(xf, local, g, u, d,
+                                                       tile)
             out = jnp.sum(prod * w[:, :, None].astype(prod.dtype), axis=1)
         else:
             combine = jnp.zeros((b * t, count + 1), w.dtype).at[
                 jnp.arange(b * t, dtype=jnp.int32)[:, None], local].add(w)
-            out = jnp.einsum("enh,ne->nh", dense_expert_products(xf, g, u, d),
+            with _device_scope("experts.routed"):
+                prod = dense_expert_products(xf, g, u, d)
+            out = jnp.einsum("enh,ne->nh", prod,
                              combine[:, :count].astype(xf.dtype),
                              precision=stored_precision(xf))
             counts = jnp.zeros((count + 1,), jnp.int32).at[

@@ -65,6 +65,8 @@ from __future__ import annotations
 
 import math
 
+from ...profiler.core import device_scope as _device_scope
+
 _NEG_INF = -1e30  # finite "minus infinity": keeps fully-masked rows NaN-free
 _BLOCK = 128      # lane width / KV stream block size
 
@@ -165,6 +167,7 @@ def _supports_pallas(q, k):
     return _platform_of(q) == "tpu"
 
 
+@_device_scope("attn.scores")
 def _xla_decode(q, k, v, start_pos, scale, k_scale, v_scale):
     """Fused-einsum fallback: grouped-heads attention over the ring with
     the same ``position <= start_pos + t`` mask as the kernel. Handles any
@@ -275,6 +278,7 @@ def _decode_kernel(quant, kv, g, d, bk, n_k, scale, prec,
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
+@_device_scope("attn.kernel")
 def _pallas_decode(q, k, v, start_pos, scale, k_scale, v_scale):
     import functools
 
@@ -337,6 +341,7 @@ def _pallas_decode(q, k, v, start_pos, scale, k_scale, v_scale):
                                scale, stored_precision(q, k, v))
     out = pl.pallas_call(
         kernel,
+        name="decode_attention",
         grid=(b * kv, n_k),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, gp, dp), head_map),
@@ -462,6 +467,7 @@ def _paged_kernel(quant, kv, bk, n_blk, scale, prec, window,
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
+@_device_scope("attn.kernel")
 def _pallas_paged_decode(q, k_pool, v_pool, page_table, start_pos, scale,
                          k_scale, v_scale, window=None):
     import functools
@@ -530,6 +536,7 @@ def _pallas_paged_decode(q, k_pool, v_pool, page_table, start_pos, scale,
                                stored_precision(q, k_pool, v_pool), window)
     out = pl.pallas_call(
         kernel,
+        name="paged_decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, n_blk),
@@ -547,6 +554,7 @@ def _pallas_paged_decode(q, k_pool, v_pool, page_table, start_pos, scale,
     return out[:, :, :g, :].reshape(b, h, 1, d)
 
 
+@_device_scope("attn.scores")
 def _xla_window(q, k_pool, v_pool, page_table, start_pos, scale, window):
     """The windowed layers' XLA path (a prefill chunk; a decode step the
     kernel does not cover): each row's ring columns gathered in logical

@@ -22,7 +22,7 @@ from collections import OrderedDict
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from ..base import MXNetError
-from ..profiler.core import host_span
+from ..profiler.core import device_scope, host_span
 
 
 def _jax():
@@ -783,7 +783,8 @@ class ShardedTrainer:
                 lambda x: x if isinstance(x, NDArray) else NDArray(x), out,
                 is_leaf=lambda x: isinstance(x, NDArray))
             lbl_nd = jax.tree_util.tree_map(NDArray, labels)
-            loss = loss_fn(out_nd, lbl_nd)
+            with device_scope("loss"):
+                loss = loss_fn(out_nd, lbl_nd)
             ldata = loss._data if isinstance(loss, NDArray) else loss
             aux = _collect_aux_losses(self.block)
             if aux is not None:
@@ -796,9 +797,10 @@ class ShardedTrainer:
 
         def step(train_params, state_params, opt_states, batch, labels,
                  key, lrs, wds, t):
-            (loss, new_state), grads = jax.value_and_grad(
-                local_loss, has_aux=True)(train_params, state_params,
-                                          batch, labels, key)
+            with device_scope("train_step.grad"):
+                (loss, new_state), grads = jax.value_and_grad(
+                    local_loss, has_aux=True)(train_params, state_params,
+                                              batch, labels, key)
             new_train = {}
             new_opt = {}
             frozen = self._frozen_names
@@ -818,11 +820,12 @@ class ShardedTrainer:
                 # layouts (sharded axes already collapse in the backward,
                 # missing axes in the psum above)
                 g = g / float(mesh_n)
-                g = opt._prep_grad(g)
-                p_new, s_new = opt._update_raw(
-                    train_params[n], g, opt_states[n],
-                    _scalar_like(lrs[i], train_params[n]),
-                    _scalar_like(wds[i], train_params[n]), t)
+                with device_scope("train_step.optimizer"):
+                    g = opt._prep_grad(g)
+                    p_new, s_new = opt._update_raw(
+                        train_params[n], g, opt_states[n],
+                        _scalar_like(lrs[i], train_params[n]),
+                        _scalar_like(wds[i], train_params[n]), t)
                 new_train[n] = p_new
                 new_opt[n] = tuple(s_new) \
                     if isinstance(s_new, (list, tuple)) else (s_new,)
@@ -973,7 +976,8 @@ class ShardedTrainer:
                 lambda x: x if isinstance(x, NDArray) else NDArray(x), out,
                 is_leaf=lambda x: isinstance(x, NDArray))
             lbl_nd = jax.tree_util.tree_map(NDArray, labels)
-            loss = loss_fn(out_nd, lbl_nd)
+            with device_scope("loss"):
+                loss = loss_fn(out_nd, lbl_nd)
             ldata = loss._data if isinstance(loss, NDArray) else loss
             aux = _collect_aux_losses(self.block)
             if aux is not None:
@@ -996,9 +1000,10 @@ class ShardedTrainer:
 
         def step(train_params, state_params, opt_states, batch, labels, key,
                  lrs, wds, t):
-            (loss, new_state), grads = jax.value_and_grad(
-                loss_of, has_aux=True)(train_params, state_params, batch,
-                                       labels, key)
+            with device_scope("train_step.grad"):
+                (loss, new_state), grads = jax.value_and_grad(
+                    loss_of, has_aux=True)(train_params, state_params,
+                                           batch, labels, key)
             new_train = {}
             new_opt = {}
             frozen = self._frozen_names
@@ -1017,11 +1022,12 @@ class ShardedTrainer:
                 # 1/N of the state — gather-for-compute (XLA all-gathers
                 # the weight at its use sites) / scatter-for-update.
                 g = jax.lax.with_sharding_constraint(g, train_shard[n])
-                g = opt._prep_grad(g)
-                p_new, s_new = opt._update_raw(
-                    train_params[n], g, opt_states[n],
-                    _scalar_like(lrs[i], train_params[n]),
-                    _scalar_like(wds[i], train_params[n]), t)
+                with device_scope("train_step.optimizer"):
+                    g = opt._prep_grad(g)
+                    p_new, s_new = opt._update_raw(
+                        train_params[n], g, opt_states[n],
+                        _scalar_like(lrs[i], train_params[n]),
+                        _scalar_like(wds[i], train_params[n]), t)
                 new_train[n] = p_new
                 new_opt[n] = tuple(s_new) if isinstance(s_new, (list, tuple)) \
                     else (s_new,)

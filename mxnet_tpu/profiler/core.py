@@ -24,6 +24,7 @@ import os
 import threading
 import time
 
+from jax import named_scope as _named_scope
 from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 # -- hot flags (read by instrumented modules; written by the facade) --------
@@ -53,6 +54,52 @@ def host_span(name, **stats):
     ``mxnet_tpu.<layer>.<phase>``; an engine's or a block's name goes in
     ``stats``."""
     return _TraceAnnotation(name, **stats)
+
+
+# -- device scopes ----------------------------------------------------------
+# The names the compiled programs carry into the device trace. A scope is
+# HLO metadata, written once while a program is traced: there is no switch
+# and nothing to pay at run time. Three kinds, outermost first: a step
+# scope around everything one executable does; the block scopes that
+# ``gluon.Block.__call__`` enters under a trace, each the name its parent
+# registered the child under (``layer3/attention``: no string of any model
+# is kept anywhere); the op scopes below, where a block is too coarse.
+# ``OBSERVABILITY.md`` section 7 says what each covers and where it is
+# entered; ``chipbench/device_scopes.py`` reads them back.
+STEP_SCOPES = (
+    "serve_step.decode",     # _CacheForward, one position a row
+    "serve_step.prefill",    # _CacheForward, more than one
+    "train_step.grad",       # ShardedTrainer: forward, loss and backward
+    "train_step.optimizer",  # ShardedTrainer: the parameters' update
+)
+OP_SCOPES = (
+    "attn.rope",        # a row's rotation table rows (rope_positions)
+    "attn.kernel",      # the Pallas decode kernels and their layout glue
+    "attn.scores",      # attention in XLA: scores, softmax, values
+    "kv.write",         # new K/V rows into the ring or the pages
+    "kv.gather",        # pages gathered into per-row rings
+    "experts.router",   # scores, top-k, the sort and the combine
+    "experts.routed",   # the routed experts' products
+    "experts.shared",   # the shared experts' products
+    "ssm.conv",         # causal_conv1d and its state
+    "ssm.scan",         # ssd_scan and its state
+    "ssm.state",        # a call's rows of the state arrays, taken and put back
+    "head",             # picking the last position and the greedy id
+    "embed",            # the embedding's rows
+    "norm",             # layer_norm, rms_norm, grouped_rms_norm
+    "loss",             # the trainer's loss over the block's outputs
+)
+DEVICE_SCOPES = frozenset(STEP_SCOPES + OP_SCOPES)
+
+
+def device_scope(name):
+    """``jax.named_scope`` for a name of the table above, and for no
+    other: a context manager, or a decorator of a function on raw
+    arrays."""
+    if name not in DEVICE_SCOPES:
+        raise KeyError(f"{name!r} is not a device scope "
+                       "(mxnet_tpu/profiler/core.py keeps the table)")
+    return _named_scope(name)
 
 
 def begin() -> int:

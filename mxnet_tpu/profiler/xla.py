@@ -53,14 +53,28 @@ def is_tracing():
     return _tracing
 
 
+def scope_path(op_name):
+    """An XLA ``op_name`` as a path of scopes: ``jit(...)`` segments (the
+    names of traced functions) and the primitive at its end taken off."""
+    import re
+
+    parts = [p for p in re.sub(r"(?:^|/)p?jit\([^/()]*\)", "",
+                               op_name).split("/") if p]
+    return "/".join(parts[:-1])
+
+
 def device_op_stats(trace_dir_=None):
     """Per-op DEVICE time table from a captured trace.
 
     Parses the chrome-trace the ``jax.profiler`` run wrote (device pid rows
     carry ``device_duration_ps``/``model_flops``/``bytes_accessed`` per XLA
-    op) and aggregates by op name. Returns rows sorted by total device time:
-    ``{"name", "category", "calls", "total_us", "avg_us", "flops",
-    "bytes_accessed", "tflops_s", "gb_s"}``.
+    op) and aggregates by op name and scope. Returns rows sorted by total
+    device time: ``{"name", "scope", "category", "calls", "total_us",
+    "avg_us", "flops", "bytes_accessed", "tflops_s", "gb_s"}``. ``scope`` is
+    the path of the program's own names the op was compiled under (the
+    event's ``tf_op``: ``serve_step.decode/model/layer3/attention/
+    attn.kernel``, see ``core.DEVICE_SCOPES``), without the primitive's
+    name; two executables that reuse an op name are rows of their own.
 
     ``trace_dir_`` defaults to the directory of the last XLA capture. Empty
     list when the backend recorded no device events (pure-CPU runs expose
@@ -94,8 +108,10 @@ def device_op_stats(trace_dir_=None):
         if "device_duration_ps" not in args:
             continue
         name = e.get("name", "?")
-        row = agg.setdefault(name, {
+        scope = scope_path(args.get("tf_op") or args.get("op_name") or "")
+        row = agg.setdefault((name, scope), {
             "name": name,
+            "scope": scope,
             "category": args.get("hlo_category", ""),
             "calls": 0, "total_us": 0.0, "flops": 0, "bytes_accessed": 0})
         row["calls"] += 1
@@ -111,15 +127,17 @@ def device_op_stats(trace_dir_=None):
     return rows
 
 
-def device_op_table(trace_dir_=None, by_category=False, top=30):
-    """Formatted per-op (or per-category) device-time table; the printable
-    analog of ``MXAggregateProfileStatsPrint``."""
+def device_op_table(trace_dir_=None, by_category=False, top=30,
+                    by_scope=False):
+    """Formatted per-op (or per-category, or per-scope: by block) device-time
+    table; the printable analog of ``MXAggregateProfileStatsPrint``."""
     rows = device_op_stats(trace_dir_)
-    if by_category:
+    if by_category or by_scope:
+        key = "scope" if by_scope else "category"
         cats = {}
         for r in rows:
-            c = cats.setdefault(r["category"] or "other", {
-                "name": r["category"] or "other", "calls": 0,
+            c = cats.setdefault(r[key] or "other", {
+                "name": r[key] or "other", "calls": 0,
                 "total_us": 0.0, "flops": 0, "bytes_accessed": 0})
             c["calls"] += r["calls"]
             c["total_us"] += r["total_us"]
@@ -130,10 +148,13 @@ def device_op_table(trace_dir_=None, by_category=False, top=30):
             secs = r["total_us"] / 1e6
             r["tflops_s"] = r["flops"] / secs / 1e12 if secs else 0.0
             r["gb_s"] = r["bytes_accessed"] / secs / 1e9 if secs else 0.0
-    lines = [f"{'Name':<32}{'Calls':>7}{'Total(us)':>12}"
+    width = 64 if by_scope else 32
+    lines = [f"{'Name':<{width}}{'Calls':>7}{'Total(us)':>12}"
              f"{'TFLOP/s':>9}{'GB/s':>8}"]
     for r in rows[:top]:
-        lines.append(f"{r['name'][:31]:<32}{r['calls']:>7}"
+        # a scope's tail says most (the block), an op's name its head
+        shown = r["name"][-(width - 1):] if by_scope else r["name"][:31]
+        lines.append(f"{shown:<{width}}{r['calls']:>7}"
                      f"{r['total_us']:>12.1f}{r['tflops_s']:>9.1f}"
                      f"{r['gb_s']:>8.0f}")
     return "\n".join(lines)

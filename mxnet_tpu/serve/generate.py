@@ -32,6 +32,7 @@ from .. import random as _rng
 from ..base import MXNetError
 from ..profiler import attribution as _attr
 from ..profiler import trace as _trace
+from ..profiler.core import device_scope
 from ..gluon.block import HybridBlock
 from ..ops import nn as _ops
 from ..resilience import faults as _faults
@@ -527,6 +528,13 @@ class _CacheForward(HybridBlock):
                             if self._inplace else ())
 
     def forward(self, tokens, start_pos, last_idx, *rest):
+        # the executable's name in the device trace, by what tells the two
+        # apart already: one position a row, or more
+        with device_scope("serve_step.decode" if tokens.shape[1] == 1
+                          else "serve_step.prefill"):
+            return self._step(tokens, start_pos, last_idx, *rest)
+
+    def _step(self, tokens, start_pos, last_idx, *rest):
         layout = self._layout
         page_table = window_table = lanes = None
         if self._paged:

@@ -310,3 +310,40 @@ def test_profiler_device_op_stats_parses_trace(tmp_path):
     assert r["tflops_s"] > 0 and r["gb_s"] > 0
     table = profiler.device_op_table(str(tmp_path), by_category=True)
     assert "convolution fusion" in table
+
+
+def test_profiler_device_op_stats_rows_carry_the_scope(tmp_path):
+    """Two executables reuse an op name: the rows are kept apart by the
+    scope path of the program's own names, and the table reads by block."""
+    import gzip
+    import json
+
+    from mxnet_tpu import profiler
+
+    d = tmp_path / "plugins" / "profile" / "2026_01_01"
+    d.mkdir(parents=True)
+
+    def op(ts, dur_ps, tf_op):
+        return {"ph": "X", "pid": 3, "tid": 1, "ts": ts, "dur": 1,
+                "name": "fusion.140",
+                "args": {"device_duration_ps": str(dur_ps), "tf_op": tf_op}}
+
+    dec = "jit(fwd)/serve_step.decode/model/layer3/mixer/jit(f)/ssm.scan/mul"
+    pre = "jit(fwd)/serve_step.prefill/model/layer3/attention/o_proj/" \
+        "jit(f)/dot_general"
+    events = [{"ph": "M", "pid": 3, "name": "process_name",
+               "args": {"name": "/device:TPU:0"}},
+              op(0, 2_000_000, dec), op(3, 2_000_000, dec),
+              op(6, 7_000_000, pre),
+              {"ph": "X", "pid": 3, "tid": 1, "ts": 9, "dur": 1,
+               "name": "copy.1", "args": {"device_duration_ps": "1000000"}}]
+    with gzip.open(d / "vm.trace.json.gz", "wt") as f:
+        json.dump({"traceEvents": events}, f)
+    rows = profiler.device_op_stats(str(tmp_path))
+    assert [(r["name"], r["scope"], r["calls"]) for r in rows] == [
+        ("fusion.140", "serve_step.prefill/model/layer3/attention/o_proj", 1),
+        ("fusion.140", "serve_step.decode/model/layer3/mixer/ssm.scan", 2),
+        ("copy.1", "", 1)]
+    table = profiler.device_op_table(str(tmp_path), by_scope=True)
+    assert "serve_step.decode/model/layer3/mixer/ssm.scan" in table
+    assert "other" in table   # the event under no scope

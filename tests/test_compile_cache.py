@@ -171,3 +171,50 @@ class TestEnableDisable:
         assert "compile_cache.enabled" in snap
         assert "compile_cache.disk_hits" in snap
         assert "compile_cache.disk_bytes" in snap
+
+
+_SCOPE_CHILD = r"""
+import os, sys
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import jax, jax.numpy as jnp
+from mxnet_tpu import compile_cache
+compile_cache.enable(sys.argv[1])
+def work(x):
+    return jnp.tanh(x * 2.0 + 1.0).sum()
+def named(x):
+    with jax.named_scope(sys.argv[2]):
+        return work(x)
+fn = jax.jit(named if sys.argv[2] else work)
+x = jnp.ones((8, 8))
+h0, m0 = compile_cache.disk_hits(), compile_cache.disk_misses()
+fn(x).block_until_ready()
+h1, m1 = compile_cache.disk_hits(), compile_cache.disk_misses()
+text = fn.lower(x).compile().as_text()
+print("CC_SCOPE=%d %d %d" % (h1 - h0, m1 - m0,
+                             int(sys.argv[2] != "" and sys.argv[2] in text)))
+"""
+
+
+def _scope_child(cache_dir, scope):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
+        + env.get("PYTHONPATH", "").split(os.pathsep))
+    proc = subprocess.run(
+        [sys.executable, "-c", _SCOPE_CHILD, str(cache_dir), scope],
+        env=env, capture_output=True, text=True, timeout=300)
+    line, = [ln for ln in proc.stdout.splitlines()
+             if ln.startswith("CC_SCOPE=")]
+    return tuple(int(x) for x in line.split("=", 1)[1].split())
+
+
+def test_an_entry_from_before_a_scope_is_a_miss(tmp_path):
+    """The names a program is compiled under are part of the key
+    (``enable`` puts metadata in it): a directory warmed by the same
+    computation without a scope does not hand its executable to the
+    program that has one, whose device events would then carry no name;
+    the same program again is a hit."""
+    assert _scope_child(tmp_path, "") == (0, 1, 0)
+    assert _scope_child(tmp_path, "serve_step.decode") == (0, 1, 1)
+    assert _scope_child(tmp_path, "serve_step.decode") == (1, 0, 1)
