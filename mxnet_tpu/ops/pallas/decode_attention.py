@@ -28,8 +28,13 @@ end repeats the last block index and is not fetched at all. One program
 is one lane with all its KV heads (a page with its heads is one
 contiguous block of the pool); per head the arithmetic and the block
 order are the ring kernel's. What it cannot tile (a page that is neither
-a multiple of the block nor smaller than it; T > 1) reads the pages
-gathered into rings through the ring paths below.
+a multiple of the block nor smaller than it; T > 1: a prefill chunk, a
+verify block) walks, in plain XLA, the key blocks that some query of the
+call can see, a few pages a turn with the kernels' running max and sum
+(``_xla_blocks``): its trip count follows ``start_pos``, T, the window
+and the table's width, and neither the whole table nor the whole scores
+are ever formed. int8 pools alone still read the pages gathered into
+rings through the ring paths below.
 
 A window (``paged_decode_attention(..., window=W)``): the query at
 position ``t`` sees the keys at ``t - W + 1 .. t`` alone, and the page
@@ -42,7 +47,7 @@ kernel it always compiled.
 
 Introspection follows ``flash_attention``'s conventions: ``last_path()``
 reports which implementation the last call traced ("pallas_paged" |
-"pallas" | "xla"),
+"xla_blocks" | "pallas" | "xla"),
 ``force_path()`` overrides routing, ``use_interpret(True)`` runs the kernel
 through the Pallas interpreter on CPU. Decode-shaped calls (T == 1) that
 land on the XLA fallback additionally record a flight-recorder note and
@@ -198,6 +203,28 @@ def _xla_decode(q, k, v, start_pos, scale, k_scale, v_scale):
     return out.reshape(b, h, t, d).astype(q.dtype)
 
 
+def _flash_update(sc, vb, prec, m_prev, l_prev, acc_prev):
+    """Masked scores ``sc`` (..., R, bk) and their values ``vb`` (..., bk,
+    D) into the flash accumulators (max, sum, acc) of the R query rows;
+    the leading dimensions, if any, are batch dimensions of the product.
+    A masked score is ``_NEG_INF``, finite: rows that have seen no key yet
+    weigh such a block evenly, and their first visible key wipes that
+    (``alpha`` is 0 there), so a masked key contributes an exact zero."""
+    import jax
+    import jax.numpy as jnp
+
+    m_cur = jnp.max(sc, axis=-1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    p = jnp.exp(sc - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    lead = tuple(range(sc.ndim - 2))
+    pv = jax.lax.dot_general(
+        p, vb, (((sc.ndim - 1,), (sc.ndim - 2,)), (lead, lead)),
+        precision=prec, preferred_element_type=jnp.float32)
+    return m_new, l_new, acc_prev * alpha + pv
+
+
 def _flash_block(q, k, v, k_scale, v_scale, first, sp, scale, prec,
                  m_prev, l_prev, acc_prev, window=None):
     """One K/V block of one KV head into the flash accumulators: q
@@ -225,16 +252,7 @@ def _flash_block(q, k, v, k_scale, v_scale, first, sp, scale, prec,
     if window is not None:
         seen = seen & (kpos > sp - jnp.int32(window))
     sc = jnp.where(seen, sc, jnp.float32(_NEG_INF))
-
-    m_cur = jnp.max(sc, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(sc - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    pv = jax.lax.dot_general(
-        p, vb, (((1,), (0,)), ((), ())), precision=prec,
-        preferred_element_type=jnp.float32)
-    return m_new, l_new, acc_prev * alpha + pv
+    return _flash_update(sc, vb, prec, m_prev, l_prev, acc_prev)
 
 
 def _decode_kernel(quant, kv, g, d, bk, n_k, scale, prec,
@@ -554,12 +572,50 @@ def _pallas_paged_decode(q, k_pool, v_pool, page_table, start_pos, scale,
     return out[:, :, :g, :].reshape(b, h, 1, d)
 
 
+# keys of one turn of :func:`_xla_blocks`' loop, at most (settled on the
+# chip at the Command A+ cell's widths: exp/chunk_attention_bench.py)
+_BLOCK_KEYS = 512
+
+
+def block_range(start_pos, t, page, n_pages, window=None):
+    """The pages that :func:`_xla_blocks` walks for a call of ``t``
+    positions a row from ``start_pos`` (B,) over a table of ``n_pages``
+    pages of ``page`` keys: ``(first, turns, c)``, ``turns`` turns of
+    ``c`` pages (``c * page`` keys) from logical page ``first`` on, as
+    far as the last page a query sees. Without a window a row's keys start
+    at 0 and end at the table's extent (a last chunk's padding may run
+    past it); with one they start at the first key its first query sees,
+    and the pages are the logical ring's, unbounded. The range is the
+    union over the rows. ``c`` divides what one row can see (the table;
+    the pages a window and ``t`` positions can lie in, which a ring holds
+    no more of) into the fewest even turns of at most ``_BLOCK_KEYS``
+    keys. On numpy arrays (the scheduler's count of the keys a chunk
+    visits) as on traced ones (the loop's bounds)."""
+    hi = start_pos + (t - 1)
+    if window is None:
+        span = n_pages
+        lo, hi = start_pos * 0, hi.clip(None, n_pages * page - 1)
+    else:
+        span = min(n_pages, (window + t + page - 3) // page + 1)
+        lo = (start_pos - (window - 1)).clip(0, None)
+    c = -(-span // -(-span // max(1, _BLOCK_KEYS // page)))
+    first = lo.min() // page
+    return first, (hi.max() // page - first) // c + 1, c
+
+
 @_device_scope("attn.scores")
-def _xla_window(q, k_pool, v_pool, page_table, start_pos, scale, window):
-    """The windowed layers' XLA path (a prefill chunk; a decode step the
-    kernel does not cover): each row's ring columns gathered in logical
-    order, from the page ``N - 1`` before the one its last query lies in,
-    and the keys outside ``t - window + 1 .. t`` masked."""
+def _xla_blocks(q, k_pool, v_pool, page_table, start_pos, scale, window):
+    """Attention over float32 pages in plain XLA (a prefill chunk, a
+    verify block, a decode step the kernel does not cover): a loop over
+    the pages of :func:`block_range`, the ones some query of the call can
+    see, a block of ``c`` a turn, with :func:`_flash_update`'s running
+    max, sum and accumulator. A turn takes its block's pages for every
+    row from the table (with a ``window`` the table is a ring: logical
+    page ``j`` in column ``j mod N``, so the column a chunk has just
+    written over is read as the newest page), multiplies the (B, KV, G*T,
+    D) queries with them and masks by position, per query row: never the
+    whole table, and never the whole scores, unless the table is no more
+    than a block."""
     import jax
     import jax.numpy as jnp
 
@@ -570,23 +626,41 @@ def _xla_window(q, k_pool, v_pool, page_table, start_pos, scale, window):
     n_pages = page_table.shape[1]
     g = h // kv
     sp = start_pos.astype(jnp.int32)
-    first = jnp.maximum((sp + (t - 1)) // page - (n_pages - 1), 0)   # (B,)
-    logical = first[:, None] + jnp.arange(n_pages, dtype=jnp.int32)[None, :]
-    table = jnp.take_along_axis(page_table.astype(jnp.int32),
-                                logical % n_pages, axis=1)
-    k, v = gather_pages(k_pool, table), gather_pages(v_pool, table)
-    prec = stored_precision(q, k, v)
-    qg = q.reshape(b, kv, g, t, d)
-    scores = jnp.einsum("bngtd,bnsd->bngts", qg, k, precision=prec) * scale
-    pos = sp[:, None] + jnp.arange(t, dtype=jnp.int32)              # (B, T)
-    kpos = (first * page)[:, None] \
-        + jnp.arange(n_pages * page, dtype=jnp.int32)[None, :]      # (B, S)
-    seen = (kpos[:, None, :] <= pos[:, :, None]) \
-        & (kpos[:, None, :] > pos[:, :, None] - window)
-    scores = jnp.where(seen[:, None, None, :, :], scores, _NEG_INF)
-    w = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bngts,bnsd->bngtd", w, v, precision=prec)
-    return out.reshape(b, h, t, d).astype(q.dtype)
+    first, turns, c = block_range(sp, t, page, n_pages, window)
+    whole = window is None and c == n_pages     # the table is one block
+    if whole:
+        first = 0
+    table = page_table.astype(jnp.int32)
+    prec = stored_precision(q, k_pool, v_pool)
+    qg = q.reshape(b, kv, g * t, d)
+    # row g * T + t of a KV head's queries is at position sp + t
+    pos = jnp.tile(sp[:, None] + jnp.arange(t, dtype=jnp.int32), (1, g))
+
+    def block(i, carry):
+        at = first + i * c
+        cols = at + jnp.arange(c, dtype=jnp.int32)
+        if window is not None:
+            ids = jnp.take(table, cols % n_pages, axis=1)
+        else:       # a column past the table is the null page
+            ids = jnp.take(table, cols, axis=1, mode="fill", fill_value=0)
+        k, v = gather_pages(k_pool, ids), gather_pages(v_pool, ids)
+        sc = jnp.einsum("bnrd,bnsd->bnrs", qg, k, precision=prec,
+                        preferred_element_type=jnp.float32) * scale
+        kpos = at * page + jnp.arange(c * page, dtype=jnp.int32)
+        seen = kpos <= pos[:, :, None]                  # (B, G*T, c*page)
+        if window is not None:
+            seen = seen & (kpos > pos[:, :, None] - window)
+        sc = jnp.where(seen[:, None], sc, jnp.float32(_NEG_INF))
+        return _flash_update(sc, v, prec, *carry)
+
+    rows = (b, kv, g * t)
+    none = (jnp.full(rows + (1,), _NEG_INF, jnp.float32),
+            jnp.zeros(rows + (1,), jnp.float32),
+            jnp.zeros(rows + (d,), jnp.float32))
+    # one turn needs no loop, and no bounds worked out on the device
+    _, l, acc = block(0, none) if whole \
+        else jax.lax.fori_loop(0, turns, block, none)
+    return (acc / l).reshape(b, h, t, d).astype(q.dtype)
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, start_pos,
@@ -598,9 +672,12 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, start_pos,
     (P, KV, page) scale pools); page_table: (B, N) int32 pool page ids of
     each row's logical pages (0 = the null page); start_pos: (B,) int32.
     The new rows of this call are in the pools already. A decode-shaped
-    call (T == 1) the paged kernel covers reads the pages in place;
-    anything else gathers the rows' pages into rings and goes through
-    :func:`decode_attention` (a decode-shaped one counts as a fallback).
+    call (T == 1) the paged kernel covers reads the pages in place. Every
+    other call on float32 pools walks the key blocks its queries can see
+    (:func:`_xla_blocks`, ``last_path()`` ``"xla_blocks"``; a
+    decode-shaped one counts as a fallback). int8 pools alone, which carry
+    scales, still gather the rows' every page into rings and go through
+    :func:`decode_attention`.
 
     ``window``: position ``s`` attends iff ``start_pos[b] + t - window <
     s <= start_pos[b] + t``, and ``page_table``'s N columns are a ring of
@@ -621,20 +698,28 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, start_pos,
         _LAST_PATH = "pallas_paged"
         return _pallas_paged_decode(q, k_pool, v_pool, page_table,
                                     start_pos, sc, k_scale, v_scale, window)
-    if window is not None:
-        # a ring of pages has no ring-shaped gather: its own XLA path
-        _LAST_PATH = "xla"
-        if decode:
-            _record_fallback("forced_xla" if _FORCE_PATH == "xla"
-                             else "unsupported_shape", q.shape)
-        return _xla_window(q, k_pool, v_pool, page_table, start_pos, sc,
+    if k_scale is None:
+        if _FORCE_PATH == "pallas":
+            raise ValueError(
+                f"force_path('pallas'): unsupported paged shape "
+                f"q={q.shape} pool={k_pool.shape} on {_platform_of(q)}")
+        _LAST_PATH = "xla_blocks"
+        if decode:  # decode-shaped call missed the kernel: diagnose
+            if _FORCE_PATH == "xla":
+                reason = "forced_xla"
+            elif _supports_pallas(q, k_pool):
+                reason = "page_untiled"
+            else:
+                reason = ("interpret_off_cpu" if _platform_of(q) != "tpu"
+                          else "unsupported_shape")
+            _record_fallback(reason, q.shape)
+        return _xla_blocks(q, k_pool, v_pool, page_table, start_pos, sc,
                            window)
     from ..nn import gather_pages
 
     k, v = gather_pages(k_pool, page_table), gather_pages(v_pool, page_table)
-    if k_scale is not None:
-        k_scale = gather_pages(k_scale, page_table)
-        v_scale = gather_pages(v_scale, page_table)
+    k_scale = gather_pages(k_scale, page_table)
+    v_scale = gather_pages(v_scale, page_table)
     if decode and _FORCE_PATH != "xla" and _supports_pallas(q, k):
         # the ring kernel serves it; the xla path records its own
         _record_fallback("page_untiled", q.shape)

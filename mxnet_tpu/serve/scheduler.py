@@ -58,6 +58,7 @@ import time
 import numpy as _onp
 
 from ..base import MXNetError
+from ..ops.pallas.decode_attention import block_range
 from ..profiler import attribution as _attr
 from ..profiler import core as _prof
 from ..profiler import trace as _trace
@@ -249,6 +250,8 @@ class ContinuousEngine:
         self._n_window_layers = sum(lay.window is not None
                                     for lay in layout.layers)
         self._n_full_layers = len(layout.layers) - self._n_window_layers
+        # running sums of the prefill spans' kv_keys_* stats (_chunk_keys)
+        self._prefill_keys = {"visited": 0, "held": 0}
         self.prefix = (PrefixCache(self.pool, name=f"{name}_prefix")
                        if prefix_cache else None)
         # fast rungs fuse the paging brackets into the step executable;
@@ -680,8 +683,33 @@ class ContinuousEngine:
         self._pf_next = (i + 1) % self.num_slots
         s = self._slots[i]
         n = min(self.prefill_chunk, len(s.prompt) - s.consumed)
-        with host_span("mxnet_tpu.serve.prefill", slot=i, n=n):
+        with host_span("mxnet_tpu.serve.prefill", slot=i, n=n,
+                       **self._chunk_keys(s.consumed)):
             self._prefill_chunk(i, s, n)
+
+    def _chunk_keys(self, start):
+        """Keys that the attention of a chunk starting at position
+        ``start`` visits, and keys its tables hold, summed over the
+        layers of each kind: the blocks ``decode_attention._xla_blocks``
+        walks over float32 pages, by the formula its loop takes its
+        bounds from (the host knows the lane's position). Nothing on the
+        rungs that gather a ring."""
+        if not self._fused_paged or self._quant:
+            return {}
+        sp, page = _onp.asarray([start]), self.pool.page_size
+        visited = held = 0
+        for layers, cols, window in (
+                (self._n_full_layers, self.pool.pages_per_slot, None),
+                (self._n_window_layers, self.pool.window_columns,
+                 self.pool.layout.window)):
+            if layers:
+                _, turns, c = block_range(sp, self.prefill_chunk, page, cols,
+                                          window)
+                visited += layers * int(turns) * c * page
+                held += layers * cols * page
+        self._prefill_keys["visited"] += visited
+        self._prefill_keys["held"] += held
+        return {"kv_keys_visited": visited, "kv_keys_held": held}
 
     def _prefill_chunk(self, i, s, n):
         """The next ``n`` prompt tokens of slot ``i`` through the step."""
@@ -1383,6 +1411,9 @@ class ContinuousEngine:
             out["window_pages_recycled"] = self._window_recycled
         if self._moe is not None:
             out["moe"] = dict(self._moe)
+        keys = self._prefill_keys
+        out["prefill_keys"] = {**keys, "visited_share":
+                               keys["visited"] / max(keys["held"], 1)}
         out["slots_live"] = len(self._live())
         out["slots_total"] = self.num_slots
         out["admit_wait_steps_max"] = self._admit_wait_max

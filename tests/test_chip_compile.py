@@ -381,6 +381,8 @@ def test_decode_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell,
         assert f"{kind}[{c['lanes']},{c['kv']},{c['max_seq']}]" not in text
     _no_copy_of(stores, text)
     _expert_form_of(cell, text)
+    # the kernel is all of its attention: the chunk's loop is not here
+    assert "attn.scores" not in text
 
 
 def _expert_form_of(cell, text):
@@ -412,9 +414,25 @@ def _expert_form_of(cell, text):
 def test_prefill_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell):
     """The (1, 128) prefill chunk: no Mosaic call (the benchmark tells the
     two step executables apart by it), every store aliased, no copy of a
-    pool or of the state rows."""
+    pool or of the state rows. Its attention is a loop over blocks of
+    pages (``decode_attention._xla_blocks``, PR 38): a ``while`` under
+    ``attn.scores``, and no array of the chunk's positions by the keys of
+    a whole table or ring, scores or mask."""
+    import re
+
     monkeypatch.setattr(da, "_platform_of", lambda x: "tpu")
     text, stores, pairs = _inplace_step(cell, 1, 128, "pallas", one_chip)
+    assert da.last_path() == "xla_blocks"
     assert "tpu_custom_call" not in text
     assert pairs <= _aliases(text), (pairs, _aliases(text))
     _no_copy_of(stores, text)
+    c = _CELLS[cell]
+    # Falcon-H1's table of 512 keys is one block, which needs no loop
+    assert bool(re.search(r' while\([^\n]*op_name="[^"]*attn\.scores/while"',
+                          text)) == (c["max_seq"] > da._BLOCK_KEYS)
+    held = {c["max_seq"]}
+    if cell in _ROUTED:     # the two models with a layer under a window
+        held.add(c.get("window", _WINDOW) + _PAGE)
+    for keys in held:
+        if keys > da._BLOCK_KEYS:       # Falcon-H1's table is one block
+            assert not re.search(rf"\[[\d,]*128,{keys}\]", text), keys

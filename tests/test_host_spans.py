@@ -176,6 +176,38 @@ def test_engine_spans_nest_cover_and_count(tmp_path, net, multistep):
         assert len(decodes) < sum(n - 1 for _, n in requests)
 
 
+def test_prefill_spans_carry_the_keys_their_chunks_visit(tmp_path, net,
+                                                         monkeypatch):
+    """On a fast rung over float32 pages a chunk's attention walks blocks
+    of pages: at two pages of 16 a block over a table of 8, the chunks at
+    0, 16 and 32 take 1, 1 and 2 turns of 32 keys of the 128 held, a
+    layer; the engine's running sums are the spans'. The baseline rung
+    gathers a ring and says nothing."""
+    from mxnet_tpu.ops.pallas import decode_attention as da
+
+    monkeypatch.setattr(da, "_BLOCK_KEYS", 32)
+    layers = len(net._blocks)
+    for path, want in (("pallas", [32, 32, 64]), ("baseline", None)):
+        eng = ContinuousEngine(
+            net, max_seq=128, num_slots=2, page_size=16, prefill_chunk=16,
+            decode_path=path, name=f"keys_{path}")
+        _, spans = traced(tmp_path / path,
+                          lambda: drive(eng, [(list(range(1, 41)), 2)]))
+        stats = [s["stats"] for s in spans if s["name"] == "serve.prefill"]
+        assert [s["n"] for s in stats] == [16, 16, 8]
+        sums = eng.stats()["prefill_keys"]
+        if want is None:
+            assert not any("kv_keys_visited" in s for s in stats)
+            assert sums == {"visited": 0, "held": 0, "visited_share": 0.0}
+        else:
+            assert [s["kv_keys_visited"] for s in stats] == [
+                n * layers for n in want]
+            assert {s["kv_keys_held"] for s in stats} == {128 * layers}
+            assert sums["visited"] == sum(want) * layers
+            assert sums["held"] == 3 * 128 * layers
+        eng.close()
+
+
 def test_engine_decode_live_is_the_lanes_that_decoded(tmp_path, net):
     eng = ContinuousEngine(net, max_seq=64, num_slots=4, page_size=16,
                            prefill_chunk=16, decode_path="baseline",

@@ -221,6 +221,30 @@ def test_engine_matches_reference_past_the_window(bundle):
     eng.close()
 
 
+def test_the_engine_counts_the_keys_its_chunks_visit(bundle, monkeypatch):
+    """A prompt of 45 in chunks of 8 at a block of two pages: the full
+    layers' table of 16 pages is walked to the chunk's last page (1, 1,
+    2, 2, 3, 3 turns of 16 keys where 128 are held), the window layers'
+    ring of 3 columns from the page of the first visible key (1, 1, 2, 2,
+    2, 2 turns where 24 are held: a turn of two pages over a ring of
+    three visits more than is held). The spans' stats are these numbers
+    a chunk (``tests/test_host_spans.py``)."""
+    monkeypatch.setattr(da, "_BLOCK_KEYS", 2 * PAGE)
+    eng = bundle.engine(slots=1)
+    spy = Spy(eng)
+    prompt = tokens_of(2, 45)[0]
+    res = serve_all(eng, [prompt], 2)[0]
+    want = bundle.reference([prompt + res["tokens"]])[0]
+    assert spy.worst({0: want})[0] <= TOL
+    full, ring = eng._n_full_layers, eng._n_window_layers
+    assert full and ring
+    keys = eng.stats()["prefill_keys"]
+    assert keys["visited"] == (12 * full + 10 * ring) * 2 * PAGE
+    assert keys["held"] == 6 * (16 * full + 3 * ring) * PAGE
+    assert keys["visited_share"] == keys["visited"] / keys["held"]
+    eng.close()
+
+
 def test_two_lanes_at_different_positions(bundle):
     """The second request arrives while the first decodes: its lane is
     dead in the first's decode steps while its chunks are written, then
@@ -268,7 +292,7 @@ def _unchecked(fn):
         import jax.numpy as jnp
 
         if window is not None:
-            return da._xla_window(q, k_pool, v_pool, page_table,
+            return da._xla_blocks(q, k_pool, v_pool, page_table,
                                   start_pos.astype(jnp.int32),
                                   scale or 1.0 / math.sqrt(q.shape[-1]),
                                   window)

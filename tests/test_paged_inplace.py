@@ -123,20 +123,25 @@ def test_paged_kernel_is_the_ring_kernel_block_for_block():
 
 
 def test_what_the_paged_kernel_cannot_tile_falls_back_and_counts():
-    """A page of 192 is neither blocks of 128 nor under one: the pages
-    are gathered, the ring kernel serves, and the fallback is counted.
-    A block of several positions (T > 1) is no fallback."""
+    """A page of 192 is neither blocks of 128 nor under one: float32
+    pages are walked by blocks in XLA, int8 pages are gathered for the
+    ring kernel, and either fallback is counted. A block of several
+    positions (T > 1) is no fallback."""
     rs = np.random.RandomState(5)
     before = prof.get_counter("serve.decode_fallbacks")
     q, k, v, table, _, _ = _pools(rs, 2, 4, 2, 32, 192, 2)
     got, want, path = _both(q, k, v, table, [190, 300], None, None)
-    assert path == "pallas" and da.fallback_count() == 1
+    assert path == "xla_blocks" and da.fallback_count() == 1
     assert prof.get_counter("serve.decode_fallbacks") == before + 1
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     q5 = jnp.asarray(rs.randn(2, 4, 5, 32).astype(np.float32))
     da.paged_decode_attention(q5, jnp.asarray(k), jnp.asarray(v),
                               jnp.asarray(table), jnp.asarray([3, 9]))
-    assert da.last_path() == "xla" and da.fallback_count() == 1
+    assert da.last_path() == "xla_blocks" and da.fallback_count() == 1
+    q, k, v, table, ks, vs = _pools(rs, 2, 4, 2, 32, 192, 2, int8=True)
+    got, want, path = _both(q, k, v, table, [190, 300], ks, vs)
+    assert path == "pallas" and da.fallback_count() == 2
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
 # -- the write -----------------------------------------------------------------
