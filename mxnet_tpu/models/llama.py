@@ -99,9 +99,13 @@ def apply_rope(x, cos, sin):
 # ``window``: a query sees the ``window`` newest keys alone (itself among
 # them), so the layer's K/V is bounded and the pool keeps it in a ring of
 # pages; None for a layer that sees every key.
+# ``latent``: the layer keeps ONE array a position and no pair: ``kv_heads``
+# (1) x ``head_dim`` channels that every query head reads as its key, the
+# first ``latent`` of which are also its value (latent attention in its
+# absorbed form, ``models.ax_k1``); None for a layer that keeps K and V.
 LayerCache = collections.namedtuple(
-    "LayerCache", ["kv_heads", "head_dim", "state", "window"],
-    defaults=(None,))
+    "LayerCache", ["kv_heads", "head_dim", "state", "window", "latent"],
+    defaults=(None, None))
 
 
 def _serving_dense(x, weight, cache):

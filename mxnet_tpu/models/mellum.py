@@ -61,11 +61,16 @@ class RoutedFFN(HybridBlock):
     ``num_shared`` (0: none) adds shared experts of the same size, which
     every token takes and every share of the routed experts computes
     alike: their mean is added to the routed sum. They are stored stacked
-    as the routed ones are, ``shared_*_weight`` (num_shared, ...)."""
+    as the routed ones are, ``shared_*_weight`` (num_shared, ...).
+
+    ``groups`` (``(n_group, topk_group)``) limits a token's experts to
+    its best groups and ``routed_scale`` multiplies the routed sum
+    (``ops.nn.route_top_k``, ``routed_experts``); None, the default,
+    limits and multiplies nothing."""
 
     def __init__(self, units, expert_size, num_experts, top_k,
                  experts_held=None, norm_topk_prob=True, score="softmax",
-                 num_shared=0, **kwargs):
+                 num_shared=0, groups=None, routed_scale=None, **kwargs):
         super().__init__(**kwargs)
         first, count = (0, num_experts) if experts_held is None \
             else (int(experts_held[0]), int(experts_held[1]))
@@ -76,6 +81,9 @@ class RoutedFFN(HybridBlock):
                 f"{count} from {first}")
         self._top_k, self._held = int(top_k), (first, count)
         self._renorm, self._score = bool(norm_topk_prob), score
+        self._groups = None if groups is None else \
+            (int(groups[0]), int(groups[1]))
+        self._scale = None if routed_scale is None else float(routed_scale)
         self.router = nn.Dense(num_experts, flatten=False, use_bias=False,
                                in_units=units)
         self.gate_weight = Parameter("gate_weight",
@@ -110,7 +118,8 @@ class RoutedFFN(HybridBlock):
             x, self.router.weight.data(), self.gate_weight.data(),
             self.up_weight.data(), self.down_weight.data(), self._top_k,
             held=self._held, token_live=live, renormalize=self._renorm,
-            impl=impl, score=self._score)
+            impl=impl, score=self._score, groups=self._groups,
+            scale=self._scale)
         if cache is not None:
             cache.note_route(load)
         if self._shared:

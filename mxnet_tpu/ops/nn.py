@@ -1040,7 +1040,7 @@ def quantized_dense(data, qweight, scale, bias=None):
 
 def cached_attention(query, key, value, start_pos, scale=None,
                      path="baseline", k_scale=None, v_scale=None,
-                     page_table=None, window=None):
+                     page_table=None, window=None, v_width=None):
     """Causal attention of ``query`` (B, H, T, D) — absolute positions
     ``start_pos[b] + t`` — over a KV ring (B, H, S, D).
 
@@ -1064,12 +1064,33 @@ def cached_attention(query, key, value, start_pos, scale=None,
     .. t`` alone and the table's columns are a ring of pages
     (:func:`write_pages`, ``ring``); ``None`` bounds nothing and traces
     what it always traced.
+
+    ``value=None`` with ``v_width`` is the latent form (paged fast rungs,
+    float32 alone): ``key`` is a pool of one array a position, (P, 1,
+    page, D), that all H query heads read, and its first ``v_width``
+    channels are the values; the result is (B, H, T, ``v_width``).
     """
     d = query.shape[-1]
     sc = float(scale) if scale is not None else 1.0 / math.sqrt(d)
     if window is not None and (path == "baseline" or page_table is None):
         raise MXNetError("a window bounds the keys of the paged fast rungs "
                          "alone (the continuous engine's in-place step)")
+    if value is None:
+        if path == "baseline" or page_table is None or k_scale is not None:
+            raise MXNetError(
+                "a latent layer's one array a position is read from float32 "
+                "page pools alone (the continuous engine's in-place step)")
+        from .pallas import decode_attention as da
+
+        routing = (da._FORCE_PATH, da._INTERPRET)
+
+        def latent(q, pool, sp, table):
+            assert routing == (da._FORCE_PATH, da._INTERPRET)
+            return da.paged_decode_attention(q, pool, None, table, sp,
+                                             scale=sc, v_width=v_width)
+
+        return _apply(latent, (query, key, start_pos, page_table),
+                      name="cached_attention_latent")
 
     if path != "baseline":
         from .pallas import decode_attention as da
@@ -1117,6 +1138,28 @@ def cached_attention(query, key, value, start_pos, scale=None,
         return jnp.sum(p[:, :, :, :, None] * v[:, :, None, :, :], axis=-2)
 
     return _apply(f, (query, key, value, start_pos), name="cached_attention")
+
+
+def latent_absorb(data, up_weight, rows, into_latent):
+    """Latent attention's absorbed products (``models.ax_k1``), a head at
+    a time, at :func:`stored_precision`. ``up_weight`` is the latent's
+    up-projection as a ``Dense`` stores it, (H * R, C): head ``h``'s rows
+    ``h * R + rows`` (``rows`` a ``slice``) are its keys' (or its
+    values') part, (n, C). ``into_latent``: ``data`` (B, H, T, n) ->
+    (B, H, T, C), the queries carried into the latent's space (``q W``);
+    else ``data`` (B, H, T, C) -> (B, H, T, n), the attended latent
+    carried out to the head's values (``o W^T``)."""
+    heads = data.shape[1]
+
+    @_scoped("attn.absorb")
+    def f(x, w):
+        jnp = _jnp()
+        w3 = w.reshape(heads, w.shape[0] // heads, w.shape[1])[:, rows]
+        return jnp.einsum("bhtn,hnc->bhtc" if into_latent
+                          else "bhtc,hnc->bhtn", x, w3,
+                          precision=stored_precision(x, w))
+
+    return _apply(f, (data, up_weight), name="latent_absorb")
 
 
 def rope_positions(cos_table, sin_table, start_pos, length):
@@ -1717,12 +1760,20 @@ def expert_form(rows, positions, top_k, num_experts):
 
 
 @_scoped("experts.router")
-def route_top_k(logits, top_k, renormalize=True, score="softmax"):
+def route_top_k(logits, top_k, renormalize=True, score="softmax",
+                groups=None):
     """``(weights (N, k), experts (N, k) int32)`` of router ``logits``
     (N, E), on raw arrays: the scores in float32 (``score``: ``"softmax"``
     over all E, or ``"sigmoid"`` of each expert's own logit), the k
     largest (``lax.top_k``: of equal values the lower index first),
-    renormalised to sum to 1."""
+    renormalised to sum to 1.
+
+    ``groups`` (``(n_group, topk_group)``; None: no limit) keeps a
+    token's experts inside its ``topk_group`` best of ``n_group`` groups
+    of ``E / n_group`` consecutive experts: a group's score is the sum of
+    its two largest scores, of equal groups the lower index first, and
+    the k largest are then taken among the kept groups' experts alone
+    (``k <= topk_group * E / n_group``)."""
     import jax
 
     jnp = _jnp()
@@ -1731,7 +1782,27 @@ def route_top_k(logits, top_k, renormalize=True, score="softmax"):
     z = logits.astype(jnp.float32)
     p = jax.nn.softmax(z, axis=-1) if score == "softmax" \
         else jax.nn.sigmoid(z)
-    w, idx = jax.lax.top_k(p, int(top_k))
+    if groups is None:
+        w, idx = jax.lax.top_k(p, int(top_k))
+    else:
+        n_group, keep = int(groups[0]), int(groups[1])
+        n, e = p.shape
+        per = e // n_group
+        if e % n_group or not 0 < keep <= n_group or per < 2 \
+                or int(top_k) > keep * per:
+            raise MXNetError(
+                f"router groups: the best {keep} of {n_group} groups of "
+                f"{e} experts cannot give a token {top_k}")
+        best2, _ = jax.lax.top_k(p.reshape(n, n_group, per), 2)
+        _, kept = jax.lax.top_k(jnp.sum(best2, axis=-1), keep)  # (N, keep)
+        in_kept = jnp.any(
+            kept[:, :, None] == jnp.arange(n_group, dtype=kept.dtype),
+            axis=1)                                          # (N, n_group)
+        # scores are positive: an expert outside the kept groups, at -1,
+        # comes after every expert inside them
+        _, idx = jax.lax.top_k(
+            jnp.where(jnp.repeat(in_kept, per, axis=1), p, -1.0), int(top_k))
+        w = jnp.take_along_axis(p, idx, axis=1)
     if renormalize:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
     return w, idx.astype(jnp.int32)
@@ -1826,13 +1897,15 @@ def shared_experts(data, gate, up, down):
 
 def routed_experts(data, router_weight, gate, up, down, top_k, held=None,
                    token_live=None, renormalize=True, impl="grouped",
-                   tile=32, score="softmax"):
+                   tile=32, score="softmax", groups=None, scale=None):
     """The routed-expert feed-forward of the section comment: ``data``
     (B, T, H), ``router_weight`` (E_all, H), the held experts' ``gate`` /
     ``up`` (E, H, F) and ``down`` (E, F, H); ``held`` is ``(first, count)``
     of the experts these are among all ``E_all`` (None: all of them).
     ``token_live`` (B, T) bool says which tokens are real (None: all); the
-    others are given to no expert and come back as zeros.
+    others are given to no expert and come back as zeros. ``groups`` is
+    :func:`route_top_k`'s limit; ``scale`` (None: none) multiplies every
+    token's weights after they are renormalised.
 
     Returns ``(out (B, T, H), load (6,) int32)``: the held experts' part
     of every token's sum, and ``[held experts that got a live token, the
@@ -1855,7 +1928,9 @@ def routed_experts(data, router_weight, gate, up, down, top_k, held=None,
         b, t, h = x.shape
         xf = x.reshape(b * t, h)
         logits = jnp.dot(xf, rw.T, precision=stored_precision(xf, rw))
-        w, idx = route_top_k(logits, top_k, renormalize, score)
+        w, idx = route_top_k(logits, top_k, renormalize, score, groups)
+        if scale is not None:
+            w = w * scale
         local = idx - first
         here = (local >= 0) & (local < count)
         asked = b * t * idx.shape[1]

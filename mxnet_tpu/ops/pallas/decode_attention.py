@@ -45,6 +45,14 @@ the block of ``max(0, t - W + 1)``, pages before it are never fetched and
 the first visible block is masked inside. ``window=None`` compiles the
 kernel it always compiled.
 
+Latent form (``paged_decode_attention(q, pool, None, ..., v_width=W)``):
+a layer that keeps one array a position (latent attention in its absorbed
+form: the normalised latent and the shared rotated key side by side) has
+no V pool. Its values are the keys' first ``W`` channels: the kernel and
+the chunk's loop take them as a slice of the key block they have already
+fetched, the output is ``W`` wide, and the key block spans the pool's
+whole last dimension, which need not be a multiple of the lane width.
+
 Introspection follows ``flash_attention``'s conventions: ``last_path()``
 reports which implementation the last call traced ("pallas_paged" |
 "xla_blocks" | "pallas" | "xla"),
@@ -157,13 +165,13 @@ def _platform_of(x):
         return jax.default_backend()
 
 
-def _supports_pallas(q, k):
+def _supports_pallas(q, k, widest=256):
     """Kernel coverage: one query timestep, lane-width-bounded head_dim,
     grouped heads, and a TPU (or interpreter) underneath."""
     if q.ndim != 4 or k.ndim != 4:
         return False
     b, h, t, d = q.shape
-    if t != 1 or d > 256:
+    if t != 1 or d > widest:
         return False
     if h % k.shape[1] != 0:
         return False
@@ -425,28 +433,55 @@ def _paged_block(page):
     return page if page < _BLOCK else None
 
 
-def _supports_paged(q, k_pool):
+def latent_width(width):
+    """The width a latent pool stores a position at: ``width`` rounded up
+    to the lane tile, zeros after the last real channel. XLA:TPU lays an
+    array whose last dimension is no multiple of the tile out with another
+    dimension innermost and copies the whole pool to the kernel's layout
+    and back around every call (576: seen in the compiled step,
+    tests/test_chip_compile.py); stored at a whole number of tiles the
+    pool is read where it lies, for 640 / 576 = 11% more bytes and score
+    products (PERF.md section 6, PR 39)."""
+    return _round_up(int(width), _BLOCK)
+
+
+# the widest key a latent pool may hold a position (VMEM: two buffers of a
+# block of 128 such keys beside the queries and the accumulator)
+_LATENT_WIDEST = 1024
+
+
+def _supports_paged(q, k_pool, v_width=None):
     """Coverage of the paged kernel: the ring kernel's (the pool has its
     KV heads where a ring has them) and a page the stream block tiles. On
     the chip the pool's head_dim is the block's lane extent and cannot be
-    padded without a copy of the pool."""
+    padded without a copy of the pool. A latent pool's block spans its
+    whole last dimension, whatever that is; its values, a slice of the
+    block, must end on a lane tile."""
+    if v_width is not None:
+        return (_supports_pallas(q, k_pool, _LATENT_WIDEST)
+                and _paged_block(k_pool.shape[2]) is not None
+                and (_INTERPRET or v_width % _BLOCK == 0))
     return (_supports_pallas(q, k_pool)
             and _paged_block(k_pool.shape[2]) is not None
             and (_INTERPRET or q.shape[-1] % _BLOCK == 0))
 
 
-def _paged_kernel(quant, kv, bk, n_blk, scale, prec, window,
-                  tbl_ref, sp_ref, q_ref, k_ref, v_ref, *rest):
+def _paged_kernel(quant, kv, bk, n_blk, scale, prec, window, v_width,
+                  tbl_ref, sp_ref, q_ref, k_ref, *rest):
     """One lane: stream its reached pages in ``bk`` blocks, every KV head
     of a block in turn through :func:`_flash_block` (the G grouped query
     heads on the sublane axis, as in ``_decode_kernel``). With a
     ``window`` grid step ``si`` is block ``lo + si`` of the lane, ``lo``
-    the block of its first visible position."""
+    the block of its first visible position. With a ``v_width`` there is
+    no V block: a key block's first ``v_width`` channels are its values."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     del tbl_ref  # the index maps' alone
+    v_ref = None
+    if v_width is None:
+        v_ref, *rest = rest
     if quant:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -470,8 +505,10 @@ def _paged_kernel(quant, kv, bk, n_blk, scale, prec, window,
     @pl.when(blk * bk <= sp)
     def _body():
         for n in range(kv):
+            kb = k_ref[0, n]
             m_ref[n], l_ref[n], acc_ref[n] = _flash_block(
-                q_ref[0, n], k_ref[0, n], v_ref[0, n],
+                q_ref[0, n], kb,
+                kb[:, :v_width] if v_ref is None else v_ref[0, n],
                 ks_ref[0, n] if quant else None,
                 vs_ref[0, n] if quant else None,
                 blk * bk, sp, scale, prec, m_ref[n], l_ref[n], acc_ref[n],
@@ -487,7 +524,7 @@ def _paged_kernel(quant, kv, bk, n_blk, scale, prec, window,
 
 @_device_scope("attn.kernel")
 def _pallas_paged_decode(q, k_pool, v_pool, page_table, start_pos, scale,
-                         k_scale, v_scale, window=None):
+                         k_scale, v_scale, window=None, v_width=None):
     import functools
 
     import jax
@@ -502,6 +539,8 @@ def _pallas_paged_decode(q, k_pool, v_pool, page_table, start_pos, scale,
     n_pages = page_table.shape[1]
     g = h // kv
     quant = k_scale is not None
+    dv = d if v_width is None else int(v_width)      # the values' width
+    pools = [k_pool] if v_pool is None else [k_pool, v_pool]
     bk = _paged_block(page)
     sub = page // bk                 # stream blocks a page
     n_blk = n_pages * sub
@@ -539,19 +578,16 @@ def _pallas_paged_decode(q, k_pool, v_pool, page_table, start_pos, scale,
         pid, blk = _at(i, j, tbl, sp)
         return (pid, jnp.int32(0), blk)
 
-    in_specs = [
-        pl.BlockSpec((1, kv, gp, d), lane_map),
-        pl.BlockSpec((1, kv, bk, d), page_map),
-        pl.BlockSpec((1, kv, bk, d), page_map),
-    ]
-    args = [q4, k_pool, v_pool]
+    in_specs = [pl.BlockSpec((1, kv, gp, d), lane_map)] \
+        + [pl.BlockSpec((1, kv, bk, d), page_map)] * len(pools)
+    args = [q4] + pools
     if quant:
         in_specs += [pl.BlockSpec((1, kv, bk), scale_map),
                      pl.BlockSpec((1, kv, bk), scale_map)]
         args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
 
     kernel = functools.partial(_paged_kernel, quant, kv, bk, n_blk, scale,
-                               stored_precision(q, k_pool, v_pool), window)
+                               stored_precision(q, *pools), window, v_width)
     out = pl.pallas_call(
         kernel,
         name="paged_decode_attention",
@@ -559,17 +595,17 @@ def _pallas_paged_decode(q, k_pool, v_pool, page_table, start_pos, scale,
             num_scalar_prefetch=2,
             grid=(b, n_blk),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, kv, gp, d), lane_map),
+            out_specs=pl.BlockSpec((1, kv, gp, dv), lane_map),
             scratch_shapes=[pltpu.VMEM((kv, gp, 1), jnp.float32),
                             pltpu.VMEM((kv, gp, 1), jnp.float32),
-                            pltpu.VMEM((kv, gp, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((b, kv, gp, d), q.dtype),
+                            pltpu.VMEM((kv, gp, dv), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, kv, gp, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_INTERPRET,
     )(page_table.astype(jnp.int32).reshape(-1), start_pos.astype(jnp.int32),
       *args)
-    return out[:, :, :g, :].reshape(b, h, 1, d)
+    return out[:, :, :g, :].reshape(b, h, 1, dv)
 
 
 # keys of one turn of :func:`_xla_blocks`' loop, at most (settled on the
@@ -604,7 +640,8 @@ def block_range(start_pos, t, page, n_pages, window=None):
 
 
 @_device_scope("attn.scores")
-def _xla_blocks(q, k_pool, v_pool, page_table, start_pos, scale, window):
+def _xla_blocks(q, k_pool, v_pool, page_table, start_pos, scale, window,
+                v_width=None):
     """Attention over float32 pages in plain XLA (a prefill chunk, a
     verify block, a decode step the kernel does not cover): a loop over
     the pages of :func:`block_range`, the ones some query of the call can
@@ -615,13 +652,15 @@ def _xla_blocks(q, k_pool, v_pool, page_table, start_pos, scale, window):
     written over is read as the newest page), multiplies the (B, KV, G*T,
     D) queries with them and masks by position, per query row: never the
     whole table, and never the whole scores, unless the table is no more
-    than a block."""
+    than a block. Without a ``v_pool`` a block's values are its keys'
+    first ``v_width`` channels (the latent form)."""
     import jax
     import jax.numpy as jnp
 
     from ..nn import gather_pages, stored_precision
 
     b, h, t, d = q.shape
+    dv = int(v_width) if v_pool is None else v_pool.shape[-1]
     kv, page = k_pool.shape[1], k_pool.shape[2]
     n_pages = page_table.shape[1]
     g = h // kv
@@ -631,7 +670,8 @@ def _xla_blocks(q, k_pool, v_pool, page_table, start_pos, scale, window):
     if whole:
         first = 0
     table = page_table.astype(jnp.int32)
-    prec = stored_precision(q, k_pool, v_pool)
+    prec = stored_precision(q, k_pool) if v_pool is None \
+        else stored_precision(q, k_pool, v_pool)
     qg = q.reshape(b, kv, g * t, d)
     # row g * T + t of a KV head's queries is at position sp + t
     pos = jnp.tile(sp[:, None] + jnp.arange(t, dtype=jnp.int32), (1, g))
@@ -643,7 +683,8 @@ def _xla_blocks(q, k_pool, v_pool, page_table, start_pos, scale, window):
             ids = jnp.take(table, cols % n_pages, axis=1)
         else:       # a column past the table is the null page
             ids = jnp.take(table, cols, axis=1, mode="fill", fill_value=0)
-        k, v = gather_pages(k_pool, ids), gather_pages(v_pool, ids)
+        k = gather_pages(k_pool, ids)
+        v = k[..., :dv] if v_pool is None else gather_pages(v_pool, ids)
         sc = jnp.einsum("bnrd,bnsd->bnrs", qg, k, precision=prec,
                         preferred_element_type=jnp.float32) * scale
         kpos = at * page + jnp.arange(c * page, dtype=jnp.int32)
@@ -656,16 +697,16 @@ def _xla_blocks(q, k_pool, v_pool, page_table, start_pos, scale, window):
     rows = (b, kv, g * t)
     none = (jnp.full(rows + (1,), _NEG_INF, jnp.float32),
             jnp.zeros(rows + (1,), jnp.float32),
-            jnp.zeros(rows + (d,), jnp.float32))
+            jnp.zeros(rows + (dv,), jnp.float32))
     # one turn needs no loop, and no bounds worked out on the device
     _, l, acc = block(0, none) if whole \
         else jax.lax.fori_loop(0, turns, block, none)
-    return (acc / l).reshape(b, h, t, d).astype(q.dtype)
+    return (acc / l).reshape(b, h, t, dv).astype(q.dtype)
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, start_pos,
                            scale=None, k_scale=None, v_scale=None,
-                           window=None):
+                           window=None, v_width=None):
     """:func:`decode_attention` over K/V that stay in their page pools.
 
     q: (B, H, T, D); k_pool/v_pool: (P, KV, page, D) (f32, or int8 with
@@ -684,20 +725,35 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, start_pos,
     pages (logical page ``j`` in column ``j mod N``; ``N`` pages must
     hold a window and a page, ``N >= ceil(window / page) + 1``). float32
     pools alone.
+
+    ``v_pool=None`` with ``v_width``: the latent form. ``k_pool`` is
+    (P, 1, page, D), one array a position for all H query heads, whose
+    first ``v_width`` channels are the values: the result is (B, H, T,
+    ``v_width``). float32 pools alone, no window.
     """
     global _LAST_PATH
     sc = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     decode = q.ndim == 4 and q.shape[2] == 1
+    latent = v_pool is None
+    if latent != (v_width is not None) or (latent and not (
+            k_scale is None and window is None
+            and 0 < v_width <= k_pool.shape[-1])):
+        raise ValueError(
+            "the latent form takes one float32 pool and the width of its "
+            f"values (v_pool None with v_width), no scales and no window; "
+            f"got v_width={v_width}, pool {k_pool.shape}")
     if window is not None:
         page, n = k_pool.shape[2], page_table.shape[1]
         if k_scale is not None or n * page < window + page:
             raise ValueError(
                 f"a window of {window} needs float32 pools and a ring of "
                 f"ceil(window / page) + 1 pages of {page}; got {n}")
-    if decode and _FORCE_PATH != "xla" and _supports_paged(q, k_pool):
+    if decode and _FORCE_PATH != "xla" \
+            and _supports_paged(q, k_pool, v_width):
         _LAST_PATH = "pallas_paged"
         return _pallas_paged_decode(q, k_pool, v_pool, page_table,
-                                    start_pos, sc, k_scale, v_scale, window)
+                                    start_pos, sc, k_scale, v_scale, window,
+                                    v_width)
     if k_scale is None:
         if _FORCE_PATH == "pallas":
             raise ValueError(
@@ -707,14 +763,15 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, start_pos,
         if decode:  # decode-shaped call missed the kernel: diagnose
             if _FORCE_PATH == "xla":
                 reason = "forced_xla"
-            elif _supports_pallas(q, k_pool):
+            elif _supports_pallas(q, k_pool,
+                                  _LATENT_WIDEST if latent else 256):
                 reason = "page_untiled"
             else:
                 reason = ("interpret_off_cpu" if _platform_of(q) != "tpu"
                           else "unsupported_shape")
             _record_fallback(reason, q.shape)
         return _xla_blocks(q, k_pool, v_pool, page_table, start_pos, sc,
-                           window)
+                           window, v_width)
     from ..nn import gather_pages
 
     k, v = gather_pages(k_pool, page_table), gather_pages(v_pool, page_table)

@@ -76,6 +76,8 @@ OP_SCOPES = (
     "attn.rope",        # a row's rotation table rows (rope_positions)
     "attn.kernel",      # the Pallas decode kernels and their layout glue
     "attn.scores",      # attention in XLA: scores, softmax, values
+    "attn.absorb",      # latent attention's absorbed products, a head each:
+                        # queries into the latent, the latent out to values
     "kv.write",         # new K/V rows into the ring or the pages
     "kv.gather",        # pages gathered into per-row rings
     "experts.router",   # scores, top-k, the sort and the combine

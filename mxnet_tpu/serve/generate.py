@@ -119,6 +119,7 @@ class _LayerKV:
         self._cache._state[self._idx] = tuple(new_state)
 
     def update(self, new_k, new_v, new_k_scale=None, new_v_scale=None):
+        # a latent layer's one array is ``k``; its ``v`` is and stays None
         self._cache._k[self._idx] = new_k
         self._cache._v[self._idx] = new_v
         if new_k_scale is not None:
@@ -137,13 +138,15 @@ class CacheLayout:
     says so there and nowhere else.
 
     The flat order is a layer at a time: ``k, v`` (``k, k_scale, v,
-    v_scale`` when quantized), then the layer's state arrays. ``kinds``
-    names each flat position ``"kv"`` (indexed by position: rings or
-    pages) or ``"state"`` (one row a sequence, never paged). ``windows``
-    gives, for each flat position, the window that bounds its layer's
-    keys (None: unbounded, and for state): a bounded layer's K/V are pages
-    of a second kind, a ring of :meth:`window_columns` a sequence under a
-    table of their own.
+    v_scale`` when quantized; the one array of a latent layer), then the
+    layer's state arrays. ``kinds`` names each flat position ``"kv"``
+    (indexed by position: rings or pages), ``"latent"`` (indexed by
+    position too, the one array of a layer that keeps no pair:
+    ``LayerCache.latent``; pages alone) or ``"state"`` (one row a
+    sequence, never paged). ``windows`` gives, for each flat position,
+    the window that bounds its layer's keys (None: unbounded, and for
+    state): a bounded layer's K/V are pages of a second kind, a ring of
+    :meth:`window_columns` a sequence under a table of their own.
     """
 
     def __init__(self, model, quant=None):
@@ -159,11 +162,20 @@ class CacheLayout:
         self.quant = quant
         self.kinds, self.windows = [], []
         for lay in self.layers:
-            n_kv = 4 if quant else 2
-            self.kinds += ["kv"] * n_kv
+            n_kv = 1 if lay.latent else 4 if quant else 2
+            self.kinds += ["latent" if lay.latent else "kv"] * n_kv
             self.kinds += ["state"] * len(lay.state)
             self.windows += [lay.window] * n_kv + [None] * len(lay.state)
         self.has_state = "state" in self.kinds
+        self.has_latent = "latent" in self.kinds
+        if self.has_latent and (quant or any(
+                lay.latent and (lay.window is not None or lay.kv_heads != 1
+                                or not 0 < lay.latent <= lay.head_dim)
+                for lay in self.layers)):
+            raise MXNetError(
+                "a latent layer keeps one float32 array a position on one "
+                "head, unbounded, its values inside its keys: no int8 "
+                f"pools (quant {quant!r}), no window, latent <= head_dim")
         bounds = {lay.window for lay in self.layers} - {None}
         if len(bounds) > 1:
             raise MXNetError(
@@ -197,7 +209,9 @@ class CacheLayout:
                     "a layer bounded by a window keeps its K/V in page "
                     "pools alone (serve.PagedKVPool)")
             shape = (int(lead), lay.kv_heads, int(kv_seq), lay.head_dim)
-            if self.quant:
+            if lay.latent:
+                out.append(zeros(shape, dtype=dtype))
+            elif self.quant:
                 # four arrays, not two listed twice: a step that consumes
                 # its stores cannot be handed one buffer at two positions
                 out += [zeros(sh, dtype=dt) for _ in range(2)
@@ -210,8 +224,12 @@ class CacheLayout:
 
     def state_nbytes(self, arrays):
         """Bytes of the state arrays among the flat ``arrays``."""
+        return self.kind_nbytes(arrays, "state")
+
+    def kind_nbytes(self, arrays, kind):
+        """Bytes of the flat ``arrays`` of one of :attr:`kinds`."""
         return sum(_nbytes(a) for a, k in zip(arrays, self.kinds)
-                   if k == "state")
+                   if k == kind)
 
 
 def _nbytes(a):
@@ -235,6 +253,23 @@ def require_unbounded(model, what, missing):
         raise MXNetError(
             f"{what} cannot serve {type(model).__name__}: a layer's keys "
             f"are bounded by a window, and {missing}")
+
+
+def require_kv_pairs(model, what, missing):
+    """``what`` cannot serve a model with a latent layer (one array a
+    position and no K/V pair): refuse loudly, naming what is ``missing``."""
+    if CacheLayout(model).has_latent:
+        raise MXNetError(
+            f"{what} cannot serve {type(model).__name__}: a layer keeps "
+            f"one latent array a position and no K/V pair, and {missing}")
+
+
+# missing of :func:`require_kv_pairs` for what reads a layer's cache as
+# rings
+LATENT_PAGES_ALONE = (
+    "its attention reads that array where it lies in the float32 page "
+    "pool, through the page table (the continuous engine's in-place step, "
+    "decode_path 'pallas'); no ring form of it exists")
 
 
 # what and missing of :func:`require_unbounded` for what shares or replays
@@ -374,6 +409,8 @@ class KVCache:
         for i, (k, v) in enumerate(zip(self._k, self._v)):
             if self.quant is not None:
                 out.extend((k, self._ks[i], v, self._vs[i]))
+            elif v is None:       # a latent layer's one array
+                out.append(k)
             else:
                 out.extend((k, v))
             out.extend(self._state[i])
@@ -392,18 +429,20 @@ class KVCache:
                     "flat quantized KVCache needs 4 arrays per layer"
                     if quant is not None else
                     "flat KVCache needs an even array count")
-            counts = [0] * (len(arrays) // per)
+            counts = [(per, 0)] * (len(arrays) // per)
         else:
-            counts = [len(lay.state) for lay in layout.layers]
+            counts = [(1 if lay.latent else per, len(lay.state))
+                      for lay in layout.layers]
             if len(arrays) != len(layout):
                 raise MXNetError(
                     f"flat KVCache: got {len(arrays)} arrays, the model's "
                     f"cache description has {len(layout)}")
         rings, states, at = [], [], 0
-        for n in counts:
-            rings.append(arrays[at:at + per])
-            states.append(tuple(arrays[at + per:at + per + n]))
-            at += per + n
+        for per_layer, n in counts:
+            # a latent layer's one array stands as its ``k``, beside no ``v``
+            rings.append(arrays[at:at + per_layer] + [None] * (per - per_layer))
+            states.append(tuple(arrays[at + per_layer:at + per_layer + n]))
+            at += per_layer + n
         if quant is not None:
             return cls([r[0] for r in rings], [r[2] for r in rings], max_seq,
                        [r[1] for r in rings], [r[3] for r in rings], quant,
@@ -474,6 +513,10 @@ class _CacheForward(HybridBlock):
     prefill chunk leaves the state alone. A model with K/V alone keeps
     the convention, and the traced program, it always had.
 
+    A model with a latent layer (``LayerCache.latent``; in-place only)
+    passes that layer's one pool where another passes two, and nothing
+    else of the convention moves.
+
     A model with a layer bounded by a window (in-place only) adds a
     ``window_table`` (B, C) arg right after ``page_table``: the ring of
     the bounded layers' pages, ``C`` columns a row
@@ -519,6 +562,10 @@ class _CacheForward(HybridBlock):
                 "by a window: its K/V lives in a ring of pages, which the "
                 "continuous engine's in-place step (decode_path 'pallas') "
                 "alone serves; ring caches and the strict rung do not")
+        if not self._inplace:
+            require_kv_pairs(model, "a step over ring caches (serve."
+                             "Generator, the strict 'baseline' rung)",
+                             LATENT_PAGES_ALONE)
         # the call's positions that the step consumes and returns (read
         # by CachedOp): the cache stores, after tokens, start_pos,
         # last_idx, the page table(s), the lanes, and keep and ids
@@ -712,6 +759,9 @@ class _MultiStepForward(HybridBlock):
         self._paged = bool(paged)
         require_unbounded(model, "multi-step decode (multistep=True)",
                           WINDOW_PAGES_GO)
+        require_kv_pairs(model, "multi-step decode (multistep=True)",
+                         LATENT_PAGES_ALONE + " for the compiled loop's "
+                         "gather and scatter brackets")
         require_kv_only(
             model, "multi-step decode (multistep=True)",
             "a lane that finished inside the compiled loop would have to "
@@ -970,6 +1020,7 @@ class Generator:
             "its ring caches keep every position of a sequence: the "
             "continuous engine (serve.ContinuousEngine, decode_path "
             "'pallas') serves such a model from rings of pages")
+        require_kv_pairs(model, "serve.Generator", LATENT_PAGES_ALONE)
         if self._prefix_on:
             require_kv_only(model, *PREFIX_CACHE_NEEDS)
         if self._prefix_on and paged is False:
@@ -1691,6 +1742,9 @@ class SpeculativeGenerator:
             require_unbounded(
                 m, "speculative decoding (SpeculativeGenerator)",
                 WINDOW_PAGES_GO)
+            require_kv_pairs(
+                m, "speculative decoding (SpeculativeGenerator)",
+                LATENT_PAGES_ALONE)
             require_kv_only(
                 m, "speculative decoding (SpeculativeGenerator)",
                 "a rejected proposal rolls a row's position back, which a "

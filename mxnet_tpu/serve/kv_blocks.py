@@ -50,6 +50,13 @@ read-only by construction (``paged_kv_scatter`` only writes rows at
 position starts past the shared boundary), so the first divergent
 token always lands in a slot-private page and no copy is ever needed.
 
+**Latent pages.** A layer that keeps one array a position and no K/V
+pair (``LayerCache.latent``: latent attention's normalised latent and
+shared rotated key side by side) has one pool, ``(P, 1, page, D)``, where
+another layer has two. It is indexed by the same table and the same page
+ids as the K/V pools, so the allocator, the reference counts and the
+prefix cache's shared pages know nothing of the kind; float32 alone.
+
 **Two kinds of pages.** A layer whose keys are bounded by a window
 (``LayerCache.window``) never needs more than a window of positions and
 the page being written, so its K/V does not grow with the sequence. Such
@@ -442,6 +449,17 @@ class PagedKVPool:
                    for a, w in zip(self._arrays, self.layout.windows)
                    if w is not None)
 
+    def latent_nbytes(self):
+        """The latent pools' part of :meth:`nbytes`: the one array a
+        position of the layers that keep no K/V pair (0 for a model that
+        has none)."""
+        return self.layout.kind_nbytes(self._arrays, "latent")
+
+    def latent_bytes_per_position(self):
+        """Bytes one position takes in the latent pools, all such layers
+        together."""
+        return self.latent_nbytes() // (self.num_pages * self.page_size)
+
     def stats(self):
         with self._lock:
             free = len(self._free)
@@ -458,6 +476,8 @@ class PagedKVPool:
                 "nbytes": self.nbytes(),
                 "state_nbytes": self.state_nbytes(),
                 "window_nbytes": self.window_nbytes(),
+                "latent_nbytes": self.latent_nbytes(),
+                "latent_bytes_per_position": self.latent_bytes_per_position(),
                 "window_columns": self.window_columns,
                 "window_pages_used": self.window_pages - 1
                 - len(self._wfree)}

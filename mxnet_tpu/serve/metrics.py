@@ -83,6 +83,7 @@ class ServeMetrics:
         # rings and which decode path this generator traced
         self.kv_cache_bytes = 0
         self.state_pool_bytes = 0
+        self.latent_pool_bytes = 0
         self.decode_path = None
         # continuous-batching telemetry (tentpole PR 12): streaming SLOs
         # (time-to-first-token, inter-token latency) plus the paged-KV and
@@ -321,13 +322,16 @@ class ServeMetrics:
             _prof.set_counter(f"serve.queue_depth({self.name})", int(depth),
                               cat="serve")
 
-    def set_kv_cache_bytes(self, nbytes, state=0):
+    def set_kv_cache_bytes(self, nbytes, state=0, latent=0):
         """Gauges: total bytes of the cache a server holds on the device
         (``KVCache.nbytes()`` summed over the warm batch buckets, or the
-        page pool's), and ``state``, the part of it that is recurrent
-        state (one row a slot; 0 for a model that keeps K/V alone)."""
+        page pool's), ``state``, the part of it that is recurrent state
+        (one row a slot; 0 for a model that keeps K/V alone), and
+        ``latent``, the part that is latent pages (one array a position;
+        0 for a model whose layers all keep K and V)."""
         self.kv_cache_bytes = int(nbytes)
         self.state_pool_bytes = int(state)
+        self.latent_pool_bytes = int(latent)
         if _prof.ENABLED:
             _prof.set_counter(f"serve.kv_cache_bytes({self.name})",
                               int(nbytes), cat="serve")
@@ -405,6 +409,7 @@ class ServeMetrics:
                 "swaps": self.swaps,
                 "kv_cache_bytes": self.kv_cache_bytes,
                 "state_pool_bytes": self.state_pool_bytes,
+                "latent_pool_bytes": self.latent_pool_bytes,
                 "decode_path": self.decode_path,
                 "kv_pages_used": self.kv_pages_used,
                 "kv_pages_free": self.kv_pages_free,

@@ -179,7 +179,12 @@ class ContinuousEngine:
         beside the first; ``prefix_cache``, ``multistep`` and the strict
         rung raise for such a model (a ring page is written over while
         its request lives), and the prefill chunk must lie inside one
-        page. Routed-expert layers have their load read back once a
+        page. A latent layer (``LayerCache.latent``) keeps one array a
+        position in a pool of its own kind under the same page table:
+        the fast float32 rung (``decode_path="pallas"``) alone serves
+        it, with or without ``prefix_cache`` (its pages are shared like
+        any other); ``multistep``, the strict rung and int8 raise.
+        Routed-expert layers have their load read back once a
         decode step (``stats()["moe"]``).
     max_seq : per-request logical ring length (prompt + generated tokens
         must fit); must be a whole number of KV pages.
@@ -250,6 +255,8 @@ class ContinuousEngine:
         self._n_window_layers = sum(lay.window is not None
                                     for lay in layout.layers)
         self._n_full_layers = len(layout.layers) - self._n_window_layers
+        self._n_latent_layers = sum(bool(lay.latent)
+                                    for lay in layout.layers)
         # running sums of the prefill spans' kv_keys_* stats (_chunk_keys)
         self._prefill_keys = {"visited": 0, "held": 0}
         self.prefix = (PrefixCache(self.pool, name=f"{name}_prefix")
@@ -290,6 +297,9 @@ class ContinuousEngine:
             for kind, n in self._kv_pool_bytes().items():
                 _prof.set_counter(f"serve.kv_pool_bytes_{kind}", n,
                                   cat="serve")
+        if self._n_latent_layers:
+            _prof.set_counter("serve.kv_pool_bytes_latent",
+                              self.pool.latent_nbytes(), cat="serve")
         # exactly two live signatures: (1, chunk) chunked prefill and
         # (num_slots, 1) decode — the whole point of the design
         self.session = InferenceSession(
@@ -302,7 +312,8 @@ class ContinuousEngine:
         self.metrics = self.session.metrics
         self.metrics.set_decode_path(self.decode_path)
         self.metrics.set_kv_cache_bytes(self.pool.nbytes(),
-                                        state=self.pool.state_nbytes())
+                                        state=self.pool.state_nbytes(),
+                                        latent=self.pool.latent_nbytes())
         # the admission queue: PR-6 semantics intact, flusher OFF — the
         # scheduler consumes via take()/settle_one() between decode steps
         self._batcher = DynamicBatcher(
@@ -574,7 +585,8 @@ class ContinuousEngine:
         """Bytes of the K/V page pools by kind of layer: unbounded
         (``full``) and bounded by a window (``window``)."""
         win = self.pool.window_nbytes()
-        return {"full": self.pool.nbytes() - win - self.pool.state_nbytes(),
+        return {"full": self.pool.nbytes() - win - self.pool.state_nbytes()
+                - self.pool.latent_nbytes(),
                 "window": win}
 
     def _window_rows(self, lanes):
@@ -684,16 +696,20 @@ class ContinuousEngine:
         s = self._slots[i]
         n = min(self.prefill_chunk, len(s.prompt) - s.consumed)
         with host_span("mxnet_tpu.serve.prefill", slot=i, n=n,
-                       **self._chunk_keys(s.consumed)):
+                       **self._chunk_keys(s.consumed, n)):
             self._prefill_chunk(i, s, n)
 
-    def _chunk_keys(self, start):
-        """Keys that the attention of a chunk starting at position
-        ``start`` visits, and keys its tables hold, summed over the
-        layers of each kind: the blocks ``decode_attention._xla_blocks``
-        walks over float32 pages, by the formula its loop takes its
-        bounds from (the host knows the lane's position). Nothing on the
-        rungs that gather a ring."""
+    def _chunk_keys(self, start, n):
+        """Keys that the attention of a chunk of ``n`` real positions
+        starting at position ``start`` visits, and keys its tables hold,
+        summed over the layers of each kind (a latent layer is a full
+        layer whose keys are its one array a position): the blocks
+        ``decode_attention._xla_blocks`` walks over float32 pages, by
+        the formula its loop takes its bounds from (the host knows the
+        lane's position). With latent layers also the (query, key)
+        pairs their attention needs, ``kv_pairs_latent``: query ``j`` of
+        the chunk sees ``start + j + 1`` keys. Nothing on the rungs that
+        gather a ring."""
         if not self._fused_paged or self._quant:
             return {}
         sp, page = _onp.asarray([start]), self.pool.page_size
@@ -709,7 +725,11 @@ class ContinuousEngine:
                 held += layers * cols * page
         self._prefill_keys["visited"] += visited
         self._prefill_keys["held"] += held
-        return {"kv_keys_visited": visited, "kv_keys_held": held}
+        out = {"kv_keys_visited": visited, "kv_keys_held": held}
+        if self._n_latent_layers:
+            out["kv_pairs_latent"] = self._n_latent_layers * (
+                n * start + n * (n + 1) // 2)
+        return out
 
     def _prefill_chunk(self, i, s, n):
         """The next ``n`` prompt tokens of slot ``i`` through the step."""
@@ -931,6 +951,10 @@ class ContinuousEngine:
             stats = {"kv_positions_full": sum(at) * self._n_full_layers,
                      "kv_positions_window": sum(min(t, w) for t in at)
                      * self._n_window_layers}
+        if self._n_latent_layers:
+            # latent positions it reads: every one of every live lane
+            stats["kv_positions_latent"] = self._n_latent_layers * sum(
+                s.pos + 1 for _, s in riders)
         with host_span("mxnet_tpu.serve.decode", live=len(riders),
                        **stats):
             self._decode_step(riders)
@@ -1409,6 +1433,10 @@ class ContinuousEngine:
             for kind, n in self._kv_pool_bytes().items():
                 out[f"kv_pool_bytes_{kind}"] = n
             out["window_pages_recycled"] = self._window_recycled
+        if self._n_latent_layers:
+            out["kv_pool_bytes_latent"] = self.pool.latent_nbytes()
+            out["latent_bytes_per_position"] = \
+                self.pool.latent_bytes_per_position()
         if self._moe is not None:
             out["moe"] = dict(self._moe)
         keys = self._prefill_keys

@@ -196,10 +196,14 @@ _CELLS = {"mistral": dict(lanes=8, heads=32, kv=8, max_seq=2048),
           "falcon": dict(lanes=32, heads=20, kv=4, max_seq=512),
           "mellum": dict(lanes=16, heads=32, kv=4, max_seq=3584),
           "command_a": dict(lanes=8, heads=128, kv=8, max_seq=7168,
-                            window=4096)}
+                            window=4096),
+          # ax_k1 (PR 39): 8 lanes of 64 heads on ONE latent array a
+          # position, 576 wide, rings of 10,880; a dense layer and a routed
+          "ax_k1": dict(lanes=8, heads=64, kv=1, max_seq=10880)}
 _WINDOW = 1024
 # the cells whose model hands back its routed layers' load after the logits
-_ROUTED = ("mellum", "command_a")
+_ROUTED = ("mellum", "command_a", "ax_k1")
+_LATENT, _LATENT_V = 640, 512     # 576 stored at whole lane tiles
 
 
 def _cell_model(cell, layers=1):
@@ -207,6 +211,18 @@ def _cell_model(cell, layers=1):
     from mxnet_tpu.models.llama import LlamaModel
     from mxnet_tpu.models.mellum import MellumModel
 
+    if cell == "ax_k1":
+        from mxnet_tpu.models.ax_k1 import AxK1Model
+
+        # one dense layer and one routed, the two kinds the cell holds
+        return AxK1Model(
+            vocab_size=20480, units=7168, num_layers=layers + 1,
+            num_heads=64, q_rank=1536, kv_rank=_LATENT_V, nope_dim=128,
+            rope_dim=64, v_dim=128, hidden_size=18432,
+            expert_size=2048, num_experts=192, num_experts_per_tok=8,
+            num_shared_experts=1, first_dense=1, groups=(8, 4),
+            routed_scale=2.5, experts_held=(0, 8),
+            rope_scaling=("yarn", 32, 4096, 32, 1, 1.0), mscale_all_dim=1)
     if cell == "command_a":
         from mxnet_tpu.models.command_a_plus import CommandAPlusModel
 
@@ -286,6 +302,28 @@ def test_windowed_paged_decode_kernel_compiles_for_v5e(one_chip, cell):
     assert "tpu_custom_call" in text
 
 
+def test_latent_paged_decode_kernel_compiles_for_v5e(one_chip):
+    """The paged kernel in its latent form at the A.X-K1 cell's widths: 64
+    query heads on one array a position, 576 channels stored as 640,
+    values its first 512."""
+    c = _CELLS["ax_k1"]
+    n_pages = c["max_seq"] // _PAGE
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q, pool, table, sp):
+        return da._pallas_paged_decode(q, pool, None, table, sp, 0.13, None,
+                                       None, None, _LATENT_V)
+
+    text = _compile(
+        fn, sds((c["lanes"], c["heads"], 1, _LATENT), jnp.float32),
+        sds((c["lanes"] * n_pages + 1, 1, _PAGE, _LATENT), jnp.float32),
+        sds((c["lanes"], n_pages), jnp.int32), sds((c["lanes"],), jnp.int32))
+    assert "tpu_custom_call" in text
+    assert f"f32[{c['lanes']},1,{c['heads']},{_LATENT_V}]" in text
+
+
 def _inplace_step(cell, rows, t_len, path, one_chip):
     """The engine's in-place step over ``cell``'s model (one layer, every
     width real, parameters never materialized), compiled for the
@@ -359,7 +397,7 @@ def _no_copy_of(stores, text):
 
 @pytest.mark.parametrize("cell,path", [
     ("mistral", "pallas"), ("mistral", "int8"), ("falcon", "pallas"),
-    ("mellum", "pallas"), ("command_a", "pallas")])
+    ("mellum", "pallas"), ("command_a", "pallas"), ("ax_k1", "pallas")])
 def test_decode_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell,
                                                path):
     """The (lanes, 1) decode step: every cache store is an input-output
@@ -398,7 +436,11 @@ def _expert_form_of(cell, text):
     import re
 
     loops = re.findall(r" (while|conditional)\(", text)
-    if cell == "command_a":
+    if cell == "ax_k1":
+        # 8 lanes can hit 0.29 of its 8 held experts of 192: the tiles
+        assert "while" in loops
+        assert text.count("dynamic_slice_sizes={1,7168,2048}") >= 2
+    elif cell == "command_a":
         assert "while" in loops
         assert set(re.findall(r"f32\[(?:\d+,)*4096,4096\]", text)) <= {
             "f32[8,4096,4096]", "f32[1,4096,4096]", "f32[4096,4096]",
@@ -410,7 +452,7 @@ def _expert_form_of(cell, text):
 
 
 @pytest.mark.parametrize("cell", ["mistral", "falcon", "mellum",
-                                  "command_a"])
+                                  "command_a", "ax_k1"])
 def test_prefill_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell):
     """The (1, 128) prefill chunk: no Mosaic call (the benchmark tells the
     two step executables apart by it), every store aliased, no copy of a
@@ -431,7 +473,7 @@ def test_prefill_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell):
     assert bool(re.search(r' while\([^\n]*op_name="[^"]*attn\.scores/while"',
                           text)) == (c["max_seq"] > da._BLOCK_KEYS)
     held = {c["max_seq"]}
-    if cell in _ROUTED:     # the two models with a layer under a window
+    if cell in ("mellum", "command_a"):   # a layer under a window
         held.add(c.get("window", _WINDOW) + _PAGE)
     for keys in held:
         if keys > da._BLOCK_KEYS:       # Falcon-H1's table is one block
