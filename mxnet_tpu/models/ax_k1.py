@@ -75,11 +75,13 @@ def yarn_mscale(factor, mscale):
 class LatentAttention(HybridBlock):
     """Causal latent attention (the module's docstring). ``rope_scaling``
     is what :func:`~.llama._rope_tables` takes; ``scale_factor``
-    multiplies the softmax scale ``(nope + rope) ** -0.5``."""
+    multiplies the softmax scale ``(nope + rope) ** -0.5``. ``gated``
+    multiplies the heads' output by ``sigmoid(x W_g)``, one gate a
+    channel of a head, before ``W_o`` (GigaChat 3.5's gated attention)."""
 
     def __init__(self, units, num_heads, q_rank, kv_rank, nope_dim, rope_dim,
                  v_dim, theta=10000.0, rope_scaling=None, scale_factor=1.0,
-                 norm_eps=1e-6, **kwargs):
+                 norm_eps=1e-6, gated=False, **kwargs):
         super().__init__(**kwargs)
         self._heads, self._kv_rank = int(num_heads), int(kv_rank)
         self._nope, self._rope, self._v = int(nope_dim), int(rope_dim), \
@@ -105,6 +107,16 @@ class LatentAttention(HybridBlock):
         self.kv_norm = nn.RMSNorm(epsilon=norm_eps, in_channels=kv_rank)
         self.kv_b_proj = dense(h * (self._nope + self._v), kv_rank)
         self.o_proj = dense(units, h * self._v)
+        self._gated = bool(gated)
+        if self._gated:
+            self.gate_proj = dense(h * self._v, units)
+
+    def _out(self, x, out, dense):
+        """``W_o`` of the heads' output (B, T, H * v), gated by the
+        layer's input ``x`` where the layer has a gate."""
+        if self._gated:
+            out = out * _ops.activation(dense(x, self.gate_proj), "sigmoid")
+        return dense(out, self.o_proj)
 
     def cache_geometry(self):
         """What a position of this layer's cache holds: one array of
@@ -157,7 +169,8 @@ class LatentAttention(HybridBlock):
                        ((0, 0), (0, 0), (0, 0), (0, n + self._rope - v)))
         out = _ops.attention(q, k, vals, causal=True,
                              scale=self._scale)[..., :v]
-        return self.o_proj(out.transpose(0, 2, 1, 3).reshape(b, t, h * v))
+        return self._out(x, out.transpose(0, 2, 1, 3).reshape(b, t, h * v),
+                         lambda y, layer: layer(y))
 
     def _forward_cached(self, x, cache, start_pos):
         """The absorbed form over the layer's latent pages."""
@@ -195,7 +208,7 @@ class LatentAttention(HybridBlock):
         o_lat = attend_by_segment(cache, start_pos, attend, q, row)
         out = _ops.latent_absorb(o_lat, w_kvb, slice(n, n + v), False)
         out = out.transpose(0, 2, 1, 3).reshape(b, t, self._heads * v)
-        return _dense_on(cache)(out, self.o_proj)
+        return self._out(x, out, _dense_on(cache))
 
 
 class AxK1Block(HybridBlock):

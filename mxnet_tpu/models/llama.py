@@ -372,13 +372,15 @@ class LlamaAttention(HybridBlock):
 class LlamaFFN(HybridBlock):
     """SwiGLU: down(silu(gate(x)) * up(x)). ``gate_multiplier`` scales
     the gate's pre-activation and ``down_multiplier`` the output; None
-    (Llama) multiplies nothing."""
+    (Llama) multiplies nothing. ``swiglu_limit`` clamps the gate from
+    above and ``up`` on both sides before they meet (``ops.nn.swiglu``)."""
 
     def __init__(self, units, hidden_size, gate_multiplier=None,
-                 down_multiplier=None, **kwargs):
+                 down_multiplier=None, swiglu_limit=None, **kwargs):
         super().__init__(**kwargs)
         self._gate_mult = gate_multiplier
         self._down_mult = down_multiplier
+        self._limit = swiglu_limit
         self.gate_proj = nn.Dense(hidden_size, flatten=False,
                                   use_bias=False, in_units=units)
         self.up_proj = nn.Dense(hidden_size, flatten=False, use_bias=False,
@@ -387,12 +389,19 @@ class LlamaFFN(HybridBlock):
                                   in_units=hidden_size)
 
     def forward(self, x, cache=None):
+        from .. import numpy as mnp
+
         dense = _dense_on(cache)
         gate = dense(x, self.gate_proj)
         if self._gate_mult is not None:
             gate = gate * self._gate_mult
-        out = dense(_ops.activation(gate, "silu") * dense(x, self.up_proj),
-                    self.down_proj)
+        if self._limit is not None:
+            gate = mnp.minimum(gate, self._limit)
+        gate = _ops.activation(gate, "silu")
+        up = dense(x, self.up_proj)
+        if self._limit is not None:
+            up = mnp.clip(up, -self._limit, self._limit)
+        out = dense(gate * up, self.down_proj)
         return out if self._down_mult is None else out * self._down_mult
 
 

@@ -66,11 +66,16 @@ class RoutedFFN(HybridBlock):
     ``groups`` (``(n_group, topk_group)``) limits a token's experts to
     its best groups and ``routed_scale`` multiplies the routed sum
     (``ops.nn.route_top_k``, ``routed_experts``); None, the default,
-    limits and multiplies nothing."""
+    limits and multiplies nothing. ``score_bias`` adds a score-correction
+    bias a routed expert, ``e_score_correction_bias`` (all the experts':
+    the router's width), which moves the choice and not the weights;
+    ``swiglu_limit`` clamps every expert's gate and up products
+    (``ops.nn.swiglu``)."""
 
     def __init__(self, units, expert_size, num_experts, top_k,
                  experts_held=None, norm_topk_prob=True, score="softmax",
-                 num_shared=0, groups=None, routed_scale=None, **kwargs):
+                 num_shared=0, groups=None, routed_scale=None,
+                 score_bias=False, swiglu_limit=None, **kwargs):
         super().__init__(**kwargs)
         first, count = (0, num_experts) if experts_held is None \
             else (int(experts_held[0]), int(experts_held[1]))
@@ -84,8 +89,14 @@ class RoutedFFN(HybridBlock):
         self._groups = None if groups is None else \
             (int(groups[0]), int(groups[1]))
         self._scale = None if routed_scale is None else float(routed_scale)
+        self._limit = None if swiglu_limit is None else float(swiglu_limit)
         self.router = nn.Dense(num_experts, flatten=False, use_bias=False,
                                in_units=units)
+        self._biased = bool(score_bias)
+        if self._biased:
+            self.e_score_correction_bias = Parameter(
+                "e_score_correction_bias", shape=(num_experts,),
+                init="zeros")
         self.gate_weight = Parameter("gate_weight",
                                      shape=(count, units, expert_size))
         self.up_weight = Parameter("up_weight",
@@ -119,13 +130,16 @@ class RoutedFFN(HybridBlock):
             self.up_weight.data(), self.down_weight.data(), self._top_k,
             held=self._held, token_live=live, renormalize=self._renorm,
             impl=impl, score=self._score, groups=self._groups,
-            scale=self._scale)
+            scale=self._scale, limit=self._limit,
+            bias=self.e_score_correction_bias.data() if self._biased
+            else None)
         if cache is not None:
             cache.note_route(load)
         if self._shared:
             out = out + _ops.shared_experts(
                 x, self.shared_gate_weight.data(),
-                self.shared_up_weight.data(), self.shared_down_weight.data())
+                self.shared_up_weight.data(), self.shared_down_weight.data(),
+                limit=self._limit)
         return out
 
 

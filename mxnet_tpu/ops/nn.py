@@ -1467,11 +1467,13 @@ def paged_kv_scatter(pool, page_table, ring, start_pos, length):
 
 
 # ---------------------------------------------------------------------------
-# Recurrent-state serving ops (the Mamba-2 mixer of models/falcon_h1.py)
+# Recurrent-state serving ops (the Mamba-2 mixer of models/falcon_h1.py,
+# the Gated DeltaNet mixer of models/gigachat35.py)
 #
 # A state-space layer keeps, for every sequence, a fixed-size state that
 # every position advances: the last K-1 inputs of its causal convolution
-# and the (H, P, N) matrix of the selective scan. Unlike a KV ring there
+# and the (H, P, N) matrix of the selective scan, or the (H, K, V) matrix
+# of the gated delta rule. Unlike a KV ring there
 # is no position mask to hide stale or padded rows behind, so the two ops
 # below take the step's lane contract as arguments and keep it themselves:
 #
@@ -1596,8 +1598,8 @@ def causal_conv1d(data, weight, bias, state=None, start_pos=None,
     """Depthwise causal convolution along time with carried context.
 
     ``data`` (B, T, C), ``weight`` (C, K) with ``weight[:, K-1]`` on the
-    current position, ``bias`` (C,), ``state`` (B, K-1, C): the K-1 inputs
-    before ``data[:, 0]``. Returns ``(out (B, T, C), new_state)`` under the
+    current position, ``bias`` (C,) or None, ``state`` (B, K-1, C): the
+    K-1 inputs before ``data[:, 0]``. Returns ``(out (B, T, C), new_state)`` under the
     lane contract of the section comment: ``new_state`` holds the last
     K-1 inputs at or before position ``valid_len[b] - 1``.
     """
@@ -1612,12 +1614,19 @@ def causal_conv1d(data, weight, bias, state=None, start_pos=None,
         win = jnp.concatenate([jnp.where(fresh, 0.0, st), x], axis=1)
         out = b
         for j in range(k):
-            out = out + win[:, j:j + t_len, :] * w[:, j]
+            term = win[:, j:j + t_len, :] * w[:, j]
+            out = term if out is None else out + term
         # window row i holds input i - (K-1): the last K-1 valid inputs
         # are rows valid_len .. valid_len + K - 2
-        rows = vl.astype(jnp.int32)[:, None] \
-            + jnp.arange(k - 1, dtype=jnp.int32)[None, :]
-        new = jnp.take_along_axis(win, rows[:, :, None], axis=1)
+        if t_len == 1:
+            # valid_len is 0 or 1: a slice, where a gather would lay the
+            # state out anew (a copy of it a step on a TPU)
+            new = jnp.where((vl.astype(jnp.int32) > 0)[:, None, None],
+                            win[:, 1:], win[:, :-1])
+        else:
+            rows = vl.astype(jnp.int32)[:, None] \
+                + jnp.arange(k - 1, dtype=jnp.int32)[None, :]
+            new = jnp.take_along_axis(win, rows[:, :, None], axis=1)
         return out, jnp.where(lv.astype(bool)[:, None, None], new, st)
 
     return _apply(f, (data, weight, bias, state, start_pos, valid_len, live),
@@ -1697,6 +1706,113 @@ def ssd_scan(x, dt, a, b_mat, c_mat, d, state=None, start_pos=None,
                       valid_len, live), name="ssd_scan")
 
 
+def gated_delta_rule(q, k, v, g, beta, state=None, start_pos=None,
+                     valid_len=None, live=None, chunk=64):
+    """The gated delta rule (Gated DeltaNet) with carried state.
+
+    For every value head ``h`` and its key head ``h // (H / Hk)``, with q
+    and k L2-normalised a head and q scaled by ``K ** -0.5``::
+
+        S_t = exp(g_t) (I - beta_t k_t k_t^T) S_{t-1} + beta_t k_t v_t^T
+        o_t = S_t^T q_t
+
+    ``q``, ``k`` (B, T, Hk, K), ``v`` (B, T, H, V), ``g`` (B, T, H) the
+    log decay (at most 0), ``beta`` (B, T, H) in (0, 1), ``state`` (B, H,
+    K, V). Returns ``(o (B, T, H, V), new_state)`` under the lane contract
+    of the section comment.
+
+    T = 1 is the recurrence itself: one pass reads the state for ``S^T q``
+    and ``S^T k``, a second writes the new state, and the output is put
+    together from the two products without reading it again. T > 1 runs
+    in chunks of ``chunk`` positions in the WY form of Yang et al. (Gated
+    Delta Networks, arXiv:2412.06464): inside a chunk the updates are one
+    unit lower triangular solve and masked (T, T) products, and the state
+    enters and leaves once. The two are the same mathematics
+    (tests/test_gigachat35.py holds them to the plain recurrence across a
+    chunk edge, a start state, a padded tail and dead lanes).
+    """
+    chunk = int(chunk)
+
+    @_scoped("delta.rule")
+    def f(qv, kv, vv, gv, bv, st, sp, vl, lv):
+        import jax
+
+        jnp = _jnp()
+        b, t_len, heads, dv = vv.shape
+        dk = qv.shape[-1]
+        rep = heads // qv.shape[2]
+        st, sp, vl, lv = _lane_defaults(b, t_len, (heads, dk, dv), st, sp,
+                                        vl, lv)
+        prec = stored_precision(vv, st)
+
+        def unit(x):
+            return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
+                                     + 1e-6)
+
+        qh = jnp.repeat(unit(qv) * dk ** -0.5, rep, axis=2)     # (B,T,H,K)
+        kh = jnp.repeat(unit(kv), rep, axis=2)
+        # a position past the row's valid length neither decays the state
+        # nor writes to it
+        valid = (jnp.arange(t_len, dtype=jnp.int32)[None, :]
+                 < vl.astype(jnp.int32)[:, None])[:, :, None]  # (B, T, 1)
+        gv = jnp.where(valid, gv, 0.0)
+        bv = jnp.where(valid, bv, 0.0)
+        alive = lv.astype(bool)[:, None, None, None]
+        fresh = (sp.astype(jnp.int32) == 0)[:, None, None, None]
+        s0 = jnp.where(fresh, 0.0, st)
+        if t_len == 1:
+            q0, k0, v0 = qh[:, 0], kh[:, 0], vv[:, 0]           # (B, H, .)
+            decay = jnp.exp(gv[:, 0])[..., None]                 # (B, H, 1)
+            be = bv[:, 0][..., None]
+            sk = jnp.sum(k0[..., None] * s0, axis=2)             # S^T k
+            sq = jnp.sum(q0[..., None] * s0, axis=2)             # S^T q
+            kq = jnp.sum(k0 * q0, axis=-1, keepdims=True)
+            new = be * (v0 - decay * sk)                         # (B, H, V)
+            s1 = decay[..., None] * s0 + k0[..., None] * new[:, :, None, :]
+            o = decay * sq + kq * new
+            return o[:, None], jnp.where(alive, s1, st)
+        heads_first = (0, 2, 1, 3)
+        qh, kh = qh.transpose(heads_first), kh.transpose(heads_first)
+        vh = vv.transpose(heads_first)                           # (B,H,T,V)
+        gh, bh = gv.transpose(0, 2, 1), bv.transpose(0, 2, 1)    # (B, H, T)
+        c = min(chunk, t_len)
+        os, s = [], s0
+        for lo in range(0, t_len, c):
+            hi = min(lo + c, t_len)
+            qc, kc, vc = qh[:, :, lo:hi], kh[:, :, lo:hi], vh[:, :, lo:hi]
+            bc = bh[:, :, lo:hi]
+            cum = jnp.cumsum(gh[:, :, lo:hi], axis=-1)           # (B,H,n)
+            n = hi - lo
+            inside = jnp.tril(jnp.ones((n, n), bool))
+            # exp(G_t - G_s) for s <= t, 0 above
+            ratio = jnp.exp(jnp.where(inside, cum[..., :, None]
+                                      - cum[..., None, :], -jnp.inf))
+            kk = jnp.einsum("bhtd,bhsd->bhts", kc, kc, precision=prec)
+            a = jnp.where(jnp.tril(inside, -1),
+                          bc[..., :, None] * ratio * kk, 0.0)
+            # (I + A) [U | W_k] = [beta v | beta exp(G) k]
+            rhs = jnp.concatenate(
+                [bc[..., None] * vc,
+                 (bc * jnp.exp(cum))[..., None] * kc], axis=-1)
+            sol = jax.lax.linalg.triangular_solve(
+                a, rhs, left_side=True, lower=True, unit_diagonal=True)
+            w = sol[..., :dv] - jnp.einsum("bhtk,bhkv->bhtv",
+                                           sol[..., dv:], s, precision=prec)
+            qk = jnp.einsum("bhtd,bhsd->bhts", qc, kc, precision=prec) \
+                * ratio
+            os.append(jnp.exp(cum)[..., None] * jnp.einsum(
+                "bhtk,bhkv->bhtv", qc, s, precision=prec)
+                + jnp.einsum("bhts,bhsv->bhtv", qk, w, precision=prec))
+            to_end = jnp.exp(cum[..., -1:] - cum)                # (B,H,n)
+            s = jnp.exp(cum[..., -1])[..., None, None] * s + jnp.einsum(
+                "bhsk,bhsv->bhkv", kc * to_end[..., None], w, precision=prec)
+        o = jnp.concatenate(os, axis=2).transpose(heads_first)
+        return o, jnp.where(alive, s, st)
+
+    return _apply(f, (q, k, v, g, beta, state, start_pos, valid_len, live),
+                  name="gated_delta_rule")
+
+
 # ---------------------------------------------------------------------------
 # Routed experts (the feed-forward of models/mellum.py and
 # models/command_a_plus.py)
@@ -1706,7 +1822,10 @@ def ssd_scan(x, dt, a, b_mat, c_mat, d, state=None, start_pos=None,
 # (a tie goes to the lower index) share their scores' sum, renormalised
 # to 1, and the layer's output is the weighted sum of those experts'
 # SwiGLU products. There is no capacity: a token gets all k of its
-# experts whatever the load.
+# experts whatever the load. A score-correction bias (DeepSeek-V3's
+# ``e_score_correction_bias``) moves which experts are chosen and not
+# their weights; a SwiGLU limit clamps the gate from above and the up
+# product on both sides before they meet.
 #
 # The layer may hold a range of the experts (``held``: expert parallelism,
 # or the chip's share of a deployment). The router keeps its full width
@@ -1761,7 +1880,7 @@ def expert_form(rows, positions, top_k, num_experts):
 
 @_scoped("experts.router")
 def route_top_k(logits, top_k, renormalize=True, score="softmax",
-                groups=None):
+                groups=None, bias=None):
     """``(weights (N, k), experts (N, k) int32)`` of router ``logits``
     (N, E), on raw arrays: the scores in float32 (``score``: ``"softmax"``
     over all E, or ``"sigmoid"`` of each expert's own logit), the k
@@ -1773,7 +1892,11 @@ def route_top_k(logits, top_k, renormalize=True, score="softmax",
     of ``E / n_group`` consecutive experts: a group's score is the sum of
     its two largest scores, of equal groups the lower index first, and
     the k largest are then taken among the kept groups' experts alone
-    (``k <= topk_group * E / n_group``)."""
+    (``k <= topk_group * E / n_group``).
+
+    ``bias`` (E,) (None: none) is added to the scores for the choice
+    alone, of the groups and of the experts: the weights are the chosen
+    experts' own scores."""
     import jax
 
     jnp = _jnp()
@@ -1782,48 +1905,77 @@ def route_top_k(logits, top_k, renormalize=True, score="softmax",
     z = logits.astype(jnp.float32)
     p = jax.nn.softmax(z, axis=-1) if score == "softmax" \
         else jax.nn.sigmoid(z)
-    if groups is None:
+    if groups is None and bias is None:
         w, idx = jax.lax.top_k(p, int(top_k))
     else:
-        n_group, keep = int(groups[0]), int(groups[1])
-        n, e = p.shape
-        per = e // n_group
-        if e % n_group or not 0 < keep <= n_group or per < 2 \
-                or int(top_k) > keep * per:
-            raise MXNetError(
-                f"router groups: the best {keep} of {n_group} groups of "
-                f"{e} experts cannot give a token {top_k}")
-        best2, _ = jax.lax.top_k(p.reshape(n, n_group, per), 2)
-        _, kept = jax.lax.top_k(jnp.sum(best2, axis=-1), keep)  # (N, keep)
-        in_kept = jnp.any(
-            kept[:, :, None] == jnp.arange(n_group, dtype=kept.dtype),
-            axis=1)                                          # (N, n_group)
+        sel = p if bias is None else p + bias.astype(jnp.float32)
         # scores are positive: an expert outside the kept groups, at -1,
-        # comes after every expert inside them
-        _, idx = jax.lax.top_k(
-            jnp.where(jnp.repeat(in_kept, per, axis=1), p, -1.0), int(top_k))
+        # comes after every expert inside them; a bias may take a chosen
+        # score under 0, so there it is -inf
+        if groups is None:
+            _, idx = jax.lax.top_k(sel, int(top_k))
+        else:
+            idx = _top_k_in_groups(sel, int(top_k), groups,
+                                   -1.0 if bias is None else -jnp.inf)
         w = jnp.take_along_axis(p, idx, axis=1)
     if renormalize:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
     return w, idx.astype(jnp.int32)
 
 
-def _swiglu_rows(rows, g, u, d, prec):
+def _top_k_in_groups(s, top_k, groups, outside):
+    """The experts (N, k) of :func:`route_top_k`'s group limit over the
+    scores ``s`` (N, E); an expert outside the kept groups counts as
+    ``outside``."""
     import jax
 
     jnp = _jnp()
-    h = jax.nn.silu(jnp.dot(rows, g, precision=prec)) \
-        * jnp.dot(rows, u, precision=prec)
+    n_group, keep = int(groups[0]), int(groups[1])
+    n, e = s.shape
+    per = e // n_group
+    if e % n_group or not 0 < keep <= n_group or per < 2 \
+            or top_k > keep * per:
+        raise MXNetError(
+            f"router groups: the best {keep} of {n_group} groups of "
+            f"{e} experts cannot give a token {top_k}")
+    best2, _ = jax.lax.top_k(s.reshape(n, n_group, per), 2)
+    _, kept = jax.lax.top_k(jnp.sum(best2, axis=-1), keep)  # (N, keep)
+    in_kept = jnp.any(
+        kept[:, :, None] == jnp.arange(n_group, dtype=kept.dtype),
+        axis=1)                                              # (N, n_group)
+    _, idx = jax.lax.top_k(
+        jnp.where(jnp.repeat(in_kept, per, axis=1), s, outside), top_k)
+    return idx
+
+
+def swiglu(gate, up, limit=None):
+    """``silu(gate) * up`` on raw arrays; with ``limit`` (the
+    configuration's ``swiglu_limit``) ``silu(min(gate, limit)) *
+    clip(up, -limit, limit)``."""
+    import jax
+
+    jnp = _jnp()
+    if limit is not None:
+        gate = jnp.minimum(gate, limit)
+        up = jnp.clip(up, -limit, limit)
+    return jax.nn.silu(gate) * up
+
+
+def _swiglu_rows(rows, g, u, d, prec, limit=None):
+    jnp = _jnp()
+    h = swiglu(jnp.dot(rows, g, precision=prec),
+               jnp.dot(rows, u, precision=prec), limit)
     return jnp.dot(h, d, precision=prec)
 
 
-def grouped_expert_products(x, local, gate, up, down, tile=32):
+def grouped_expert_products(x, local, gate, up, down, tile=32, limit=None):
     """``E_e(x_n)`` for every assignment, on raw arrays: ``x`` (N, H),
     ``local`` (N, k) int32 expert of each assignment among the ``E`` held
     (``E`` itself for one that is not computed: an expert held elsewhere,
     a token that is not live), weights ``gate``/``up`` (E, H, F) and
-    ``down`` (E, F, H). Returns ``((N, k, H) products, (E,) int32 rows of
-    each expert)``; an assignment that is not computed gives zeros."""
+    ``down`` (E, F, H), ``limit`` :func:`swiglu`'s. Returns ``((N, k, H)
+    products, (E,) int32 rows of each expert)``; an assignment that is not
+    computed gives zeros."""
     import jax
 
     jnp = _jnp()
@@ -1853,7 +2005,7 @@ def grouped_expert_products(x, local, gate, up, down, tile=32):
             * tile
         rows = jax.lax.dynamic_slice(xs, (first, zero), (tile, h))
         at = lambda w: jax.lax.dynamic_index_in_dim(w, e, 0, keepdims=False)  # noqa: E731
-        y = _swiglu_rows(rows, at(gate), at(up), at(down), prec)
+        y = _swiglu_rows(rows, at(gate), at(up), at(down), prec, limit)
         mine = (first + row_in_tile < edges[e + 1])[:, None]
         old = jax.lax.dynamic_slice(ys, (first, zero), (tile, h))
         return jax.lax.dynamic_update_slice(
@@ -1867,29 +2019,27 @@ def grouped_expert_products(x, local, gate, up, down, tile=32):
         return ys[back].reshape(n, k, h), counts
 
 
-def dense_expert_products(x, gate, up, down):
+def dense_expert_products(x, gate, up, down, limit=None):
     """``E_e(x_n)`` for every token and every held expert, (E, N, H), on
     raw arrays."""
-    import jax
-
     jnp = _jnp()
     prec = stored_precision(x, gate)
-    hid = jax.nn.silu(jnp.einsum("nh,ehf->enf", x, gate, precision=prec)) \
-        * jnp.einsum("nh,ehf->enf", x, up, precision=prec)
+    hid = swiglu(jnp.einsum("nh,ehf->enf", x, gate, precision=prec),
+                 jnp.einsum("nh,ehf->enf", x, up, precision=prec), limit)
     return jnp.einsum("enf,efh->enh", hid, down, precision=prec)
 
 
-def shared_experts(data, gate, up, down):
+def shared_experts(data, gate, up, down, limit=None):
     """The mean of ``S`` SwiGLU experts that every token takes: ``data``
     (B, T, H), ``gate`` / ``up`` (S, H, F), ``down`` (S, F, H), stacked as
     the routed ones are (the same three products, every token with every
     expert), so that a trace tells the two branches apart by their
-    weights' leading size."""
+    weights' leading size. ``limit`` is :func:`swiglu`'s."""
     @_scoped("experts.shared")
     def f(x, g, u, d):
         jnp = _jnp()
         b, t, h = x.shape
-        y = dense_expert_products(x.reshape(b * t, h), g, u, d)
+        y = dense_expert_products(x.reshape(b * t, h), g, u, d, limit)
         return jnp.mean(y, axis=0).reshape(b, t, h)
 
     return _apply(f, (data, gate, up, down), name="shared_experts")
@@ -1897,15 +2047,17 @@ def shared_experts(data, gate, up, down):
 
 def routed_experts(data, router_weight, gate, up, down, top_k, held=None,
                    token_live=None, renormalize=True, impl="grouped",
-                   tile=32, score="softmax", groups=None, scale=None):
+                   tile=32, score="softmax", groups=None, scale=None,
+                   bias=None, limit=None):
     """The routed-expert feed-forward of the section comment: ``data``
     (B, T, H), ``router_weight`` (E_all, H), the held experts' ``gate`` /
     ``up`` (E, H, F) and ``down`` (E, F, H); ``held`` is ``(first, count)``
     of the experts these are among all ``E_all`` (None: all of them).
     ``token_live`` (B, T) bool says which tokens are real (None: all); the
-    others are given to no expert and come back as zeros. ``groups`` is
-    :func:`route_top_k`'s limit; ``scale`` (None: none) multiplies every
-    token's weights after they are renormalised.
+    others are given to no expert and come back as zeros. ``groups`` and
+    ``bias`` (E_all,) are :func:`route_top_k`'s; ``scale`` (None: none)
+    multiplies every token's weights after they are renormalised;
+    ``limit`` is :func:`swiglu`'s.
 
     Returns ``(out (B, T, H), load (6,) int32)``: the held experts' part
     of every token's sum, and ``[held experts that got a live token, the
@@ -1923,12 +2075,12 @@ def routed_experts(data, router_weight, gate, up, down, top_k, held=None,
         raise MXNetError(f"routed_experts impl {impl!r}")
 
     @_scoped("experts.router")
-    def f(x, rw, g, u, d, live):
+    def f(x, rw, g, u, d, live, eb):
         jnp = _jnp()
         b, t, h = x.shape
         xf = x.reshape(b * t, h)
         logits = jnp.dot(xf, rw.T, precision=stored_precision(xf, rw))
-        w, idx = route_top_k(logits, top_k, renormalize, score, groups)
+        w, idx = route_top_k(logits, top_k, renormalize, score, groups, eb)
         if scale is not None:
             w = w * scale
         local = idx - first
@@ -1944,13 +2096,13 @@ def routed_experts(data, router_weight, gate, up, down, top_k, held=None,
         if impl == "grouped":
             with _device_scope("experts.routed"):
                 prod, counts = grouped_expert_products(xf, local, g, u, d,
-                                                       tile)
+                                                       tile, limit)
             out = jnp.sum(prod * w[:, :, None].astype(prod.dtype), axis=1)
         else:
             combine = jnp.zeros((b * t, count + 1), w.dtype).at[
                 jnp.arange(b * t, dtype=jnp.int32)[:, None], local].add(w)
             with _device_scope("experts.routed"):
-                prod = dense_expert_products(xf, g, u, d)
+                prod = dense_expert_products(xf, g, u, d, limit)
             out = jnp.einsum("enh,ne->nh", prod,
                              combine[:, :count].astype(xf.dtype),
                              precision=stored_precision(xf))
@@ -1962,7 +2114,7 @@ def routed_experts(data, router_weight, gate, up, down, top_k, held=None,
                           jnp.asarray(count) if impl == "dense" else hit])
         return out.reshape(b, t, h), load.astype(jnp.int32)
 
-    return _apply(f, (data, router_weight, gate, up, down, token_live),
+    return _apply(f, (data, router_weight, gate, up, down, token_live, bias),
                   name="routed_experts")
 
 

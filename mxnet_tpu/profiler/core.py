@@ -86,6 +86,7 @@ OP_SCOPES = (
     "ssm.conv",         # causal_conv1d and its state
     "ssm.scan",         # ssd_scan and its state
     "ssm.state",        # a call's rows of the state arrays, taken and put back
+    "delta.rule",       # gated_delta_rule and its state
     "head",             # picking the last position and the greedy id
     "embed",            # the embedding's rows
     "norm",             # layer_norm, rms_norm, grouped_rms_norm

@@ -164,8 +164,10 @@ class CacheLayout:
     says so there and nowhere else.
 
     The flat order is a layer at a time: ``k, v`` (``k, k_scale, v,
-    v_scale`` when quantized; the one array of a latent layer), then the
-    layer's state arrays. ``kinds`` names each flat position ``"kv"``
+    v_scale`` when quantized; the one array of a latent layer; nothing
+    for a layer that keeps no positions, ``LayerCache.kv_heads`` 0: a
+    linear-attention layer's state alone), then the layer's state
+    arrays. ``kinds`` names each flat position ``"kv"``
     (indexed by position: rings or pages), ``"latent"`` (indexed by
     position too, the one array of a layer that keeps no pair:
     ``LayerCache.latent``; pages alone) or ``"state"`` (one row a
@@ -188,7 +190,7 @@ class CacheLayout:
         self.quant = quant
         self.kinds, self.windows = [], []
         for lay in self.layers:
-            n_kv = 1 if lay.latent else 4 if quant else 2
+            n_kv = _position_arrays(lay, quant)
             self.kinds += ["latent" if lay.latent else "kv"] * n_kv
             self.kinds += ["state"] * len(lay.state)
             self.windows += [lay.window] * n_kv + [None] * len(lay.state)
@@ -229,24 +231,29 @@ class CacheLayout:
         arrays with ``state_rows`` leading rows."""
         out = []
         for lay in self.layers:
-            lead = kv_lead if lay.window is None else window_lead
-            if lead is None:
-                raise MXNetError(
-                    "a layer bounded by a window keeps its K/V in page "
-                    "pools alone (serve.PagedKVPool)")
-            shape = (int(lead), lay.kv_heads, int(kv_seq), lay.head_dim)
-            if lay.latent:
-                out.append(zeros(shape, dtype=dtype))
-            elif self.quant:
-                # four arrays, not two listed twice: a step that consumes
-                # its stores cannot be handed one buffer at two positions
-                out += [zeros(sh, dtype=dt) for _ in range(2)
-                        for sh, dt in ((shape, "int8"), (shape[:3], "float32"))]
-            else:
-                out += [zeros(shape, dtype=dtype), zeros(shape, dtype=dtype)]
+            if keeps_positions(lay):
+                out += self._position_arrays(zeros, lay, kv_lead, kv_seq,
+                                             dtype, window_lead)
             out += [zeros((int(state_rows),) + tuple(s), dtype="float32")
                     for s in lay.state]
         return out
+
+    def _position_arrays(self, zeros, lay, kv_lead, kv_seq, dtype,
+                         window_lead):
+        lead = kv_lead if lay.window is None else window_lead
+        if lead is None:
+            raise MXNetError(
+                "a layer bounded by a window keeps its K/V in page "
+                "pools alone (serve.PagedKVPool)")
+        shape = (int(lead), lay.kv_heads, int(kv_seq), lay.head_dim)
+        if lay.latent:
+            return [zeros(shape, dtype=dtype)]
+        if self.quant:
+            # four arrays, not two listed twice: a step that consumes its
+            # stores cannot be handed one buffer at two positions
+            return [zeros(sh, dtype=dt) for _ in range(2)
+                    for sh, dt in ((shape, "int8"), (shape[:3], "float32"))]
+        return [zeros(shape, dtype=dtype), zeros(shape, dtype=dtype)]
 
     def state_nbytes(self, arrays):
         """Bytes of the state arrays among the flat ``arrays``."""
@@ -256,6 +263,21 @@ class CacheLayout:
         """Bytes of the flat ``arrays`` of one of :attr:`kinds`."""
         return sum(_nbytes(a) for a, k in zip(arrays, self.kinds)
                    if k == kind)
+
+
+def keeps_positions(lay):
+    """Whether a layer (``models.llama.LayerCache``) keeps anything a
+    position: K/V rows or a latent array. A layer with ``kv_heads`` 0
+    keeps its recurrent state alone: no page, no pool array."""
+    return bool(lay.kv_heads)
+
+
+def _position_arrays(lay, quant):
+    """The flat arrays a layer keeps by position: K and V (with their
+    scales when quantized), a latent layer's one, or none."""
+    if not keeps_positions(lay):
+        return 0
+    return 1 if lay.latent else 4 if quant else 2
 
 
 def _nbytes(a):
@@ -443,7 +465,9 @@ class KVCache:
         ([k0, ks0, v0, vs0, *state0, ...])."""
         out = []
         for i, (k, v) in enumerate(zip(self._k, self._v)):
-            if self.quant is not None:
+            if k is None:         # a layer that keeps no positions
+                pass
+            elif self.quant is not None:
                 out.extend((k, self._ks[i], v, self._vs[i]))
             elif v is None:       # a latent layer's one array
                 out.append(k)
@@ -467,7 +491,7 @@ class KVCache:
                     "flat KVCache needs an even array count")
             counts = [(per, 0)] * (len(arrays) // per)
         else:
-            counts = [(1 if lay.latent else per, len(lay.state))
+            counts = [(_position_arrays(lay, quant), len(lay.state))
                       for lay in layout.layers]
             if len(arrays) != len(layout):
                 raise MXNetError(
@@ -475,7 +499,8 @@ class KVCache:
                     f"cache description has {len(layout)}")
         rings, states, at = [], [], 0
         for per_layer, n in counts:
-            # a latent layer's one array stands as its ``k``, beside no ``v``
+            # a latent layer's one array stands as its ``k``, beside no
+            # ``v``; a layer that keeps no positions has neither
             rings.append(arrays[at:at + per_layer] + [None] * (per - per_layer))
             states.append(tuple(arrays[at + per_layer:at + per_layer + n]))
             at += per_layer + n

@@ -72,8 +72,9 @@ from .engine import DeadlineExceeded, InferenceSession, PoolExhausted, \
     ServeError, ServiceUnavailable, on_block_context
 from .generate import PREFIX_CACHE_NEEDS, WINDOW_PAGES_GO, _CacheForward, \
     _MultiStepForward, _STOP_WIDTH, _fresh_key_bits, _int8_weights_enabled, \
-    _quantize_serving_weights, _stop_matrix, gather_rings, require_kv_only, \
-    require_unbounded, resolve_decode_path, sample_tokens, scatter_rings
+    _quantize_serving_weights, _stop_matrix, gather_rings, keeps_positions, \
+    require_kv_only, require_unbounded, resolve_decode_path, sample_tokens, \
+    scatter_rings
 from .kv_blocks import PagedKVPool
 from .prefix_cache import PrefixCache
 
@@ -257,11 +258,16 @@ class ContinuousEngine:
                 "bounded by a window: a chunk writes inside one column of "
                 "the ring of pages and reads the columns before it")
         # layers of each kind, for the decode span's kv_positions_* stats
+        # and the state_positions of both spans (a layer that keeps its
+        # state alone holds no position and counts in none of the first)
         self._n_window_layers = sum(lay.window is not None
                                     for lay in layout.layers)
-        self._n_full_layers = len(layout.layers) - self._n_window_layers
+        self._n_full_layers = sum(keeps_positions(lay)
+                                  for lay in layout.layers) \
+            - self._n_window_layers
         self._n_latent_layers = sum(bool(lay.latent)
                                     for lay in layout.layers)
+        self._n_state_layers = sum(bool(lay.state) for lay in layout.layers)
         # running sums of the prefill spans' kv_keys_* stats (_chunk_keys)
         self._prefill_keys = {"visited": 0, "held": 0}
         self.prefix = (PrefixCache(self.pool, name=f"{name}_prefix")
@@ -606,8 +612,14 @@ class ContinuousEngine:
             # the pages' and the state rows' swap alike
             self.pool.update_from_flat(flat)
         if stateful:
-            live = int((_onp.asarray(lanes) >= 0).sum())
-            _prof.incr_counter("serve.state_lane_steps", live, cat="serve")
+            live = _onp.asarray(lanes) >= 0
+            _prof.incr_counter("serve.state_lane_steps", int(live.sum()),
+                               cat="serve")
+            # positions the state layers advance: a live row's real ones
+            # (last_idx + 1), in every such layer
+            _prof.incr_counter(
+                "serve.state_positions", self._n_state_layers
+                * int((_onp.asarray(last_idx)[live] + 1).sum()), cat="serve")
         return out[0]
 
     def _kv_pool_bytes(self):
@@ -809,6 +821,8 @@ class ContinuousEngine:
         if self._n_latent_layers:
             out["kv_pairs_latent"] = self._n_latent_layers * (
                 n * start + n * (n + 1) // 2)
+        if self._n_state_layers:
+            out["state_positions"] = self._n_state_layers * n
         return out
 
     def _chunk_inputs(self, i, s, n):
@@ -1072,6 +1086,9 @@ class ContinuousEngine:
             # latent positions it reads: every one of every live lane
             stats["kv_positions_latent"] = self._n_latent_layers * sum(
                 s.pos + 1 for _, s in riders)
+        if self._n_state_layers:
+            # one position a live lane in every layer that keeps state
+            stats["state_positions"] = self._n_state_layers * len(riders)
         return stats
 
     def _decode_step(self, riders, chunk=None):

@@ -199,10 +199,14 @@ _CELLS = {"mistral": dict(lanes=8, heads=32, kv=8, max_seq=2048),
                             window=4096),
           # ax_k1 (PR 39): 8 lanes of 64 heads on ONE latent array a
           # position, 576 wide, rings of 10,880; a dense layer and a routed
-          "ax_k1": dict(lanes=8, heads=64, kv=1, max_seq=10880)}
+          "ax_k1": dict(lanes=8, heads=64, kv=1, max_seq=10880),
+          # gigachat3_5_432b_a28b: 32 lanes; a Gated DeltaNet layer that
+          # keeps its state alone (64 value heads of 128 x 128), a gated
+          # latent layer as A.X-K1's, rings of 5,248
+          "gigachat": dict(lanes=32, heads=64, kv=1, max_seq=5248)}
 _WINDOW = 1024
 # the cells whose model hands back its routed layers' load after the logits
-_ROUTED = ("mellum", "command_a", "ax_k1")
+_ROUTED = ("mellum", "command_a", "ax_k1", "gigachat")
 _LATENT, _LATENT_V = 640, 512     # 576 stored at whole lane tiles
 
 
@@ -211,6 +215,24 @@ def _cell_model(cell, layers=1):
     from mxnet_tpu.models.llama import LlamaModel
     from mxnet_tpu.models.mellum import MellumModel
 
+    if cell == "gigachat":
+        from mxnet_tpu.models.gigachat35 import GigaChat35Model
+
+        # a linear layer with the dense feed-forward and a latent one
+        # with the routed, the two kinds of mixer and of feed-forward
+        return GigaChat35Model(
+            vocab_size=16032, units=7168, num_layers=layers + 1,
+            full_attention=(layers,), first_dense=1, num_heads=64,
+            q_rank=1536, kv_rank=_LATENT_V, nope_dim=128, rope_dim=64,
+            v_dim=128,
+            linear=dict(num_k_heads=32, num_v_heads=64, k_dim=128,
+                        v_dim=128),
+            hidden_size=18432,
+            moe=dict(expert_size=2048, num_experts=256, top_k=8,
+                     experts_held=(0, 8), score="sigmoid", num_shared=1,
+                     routed_scale=2.5, score_bias=True),
+            swiglu_limit=10.0, rope_theta=100000.0,
+            rope_scaling=("yarn", 8, 32768, 32, 1, 1.0), mscale_all_dim=1)
     if cell == "ax_k1":
         from mxnet_tpu.models.ax_k1 import AxK1Model
 
@@ -402,7 +424,8 @@ def _no_copy_of(stores, text):
 
 @pytest.mark.parametrize("cell,path", [
     ("mistral", "pallas"), ("mistral", "int8"), ("falcon", "pallas"),
-    ("mellum", "pallas"), ("command_a", "pallas"), ("ax_k1", "pallas")])
+    ("mellum", "pallas"), ("command_a", "pallas"), ("ax_k1", "pallas"),
+    ("gigachat", "pallas")])
 def test_decode_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell,
                                                path):
     """The (lanes, 1) decode step: every cache store is an input-output
@@ -441,7 +464,11 @@ def _expert_form_of(cell, text):
     import re
 
     loops = re.findall(r" (while|conditional)\(", text)
-    if cell == "ax_k1":
+    if cell == "gigachat":
+        # 32 lanes can hit 0.64 of its 8 held experts of 256: the tiles
+        assert "while" in loops
+        assert text.count("dynamic_slice_sizes={1,7168,2048}") >= 2
+    elif cell == "ax_k1":
         # 8 lanes can hit 0.29 of its 8 held experts of 192: the tiles
         assert "while" in loops
         assert text.count("dynamic_slice_sizes={1,7168,2048}") >= 2
@@ -457,7 +484,7 @@ def _expert_form_of(cell, text):
 
 
 @pytest.mark.parametrize("cell", ["mistral", "falcon", "mellum",
-                                  "command_a", "ax_k1"])
+                                  "command_a", "ax_k1", "gigachat"])
 def test_prefill_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell):
     """The (1, 128) prefill chunk's call, every store aliased, no copy of a
     pool or of the state rows. Its attention is a loop over blocks of
@@ -466,12 +493,13 @@ def test_prefill_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell):
     a whole table or ring, scores or mask. Where the layers keep K/V
     alone the call carries the cell's decode lanes (the mixed call, PR
     40): the paged kernel is in the same program as the chunk's loop;
-    Falcon-H1's chunk call has no Mosaic call."""
+    Falcon-H1's and GigaChat 3.5's chunk calls (layers with state) have no
+    Mosaic call."""
     import re
 
     monkeypatch.setattr(da, "_platform_of", lambda x: "tpu")
     c = _CELLS[cell]
-    mixed = cell != "falcon"
+    mixed = cell not in ("falcon", "gigachat")
     text, stores, pairs = _inplace_step(cell, 1, 128, "pallas", one_chip,
                                         riders=c["lanes"] * mixed)
     # (the riders' kernel may come from an earlier trace of its shape, so
