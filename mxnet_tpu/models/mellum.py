@@ -116,17 +116,18 @@ class RoutedFFN(HybridBlock):
     def forward(self, x, cache=None):
         # the normal path multiplies every token with every held expert
         # (differentiable); a cached call takes the form its shapes ask
-        # for (``ops.nn.expert_form``): a chunk walks tiles of its sorted
-        # assignments, and so does a decode step whose few rows can pick
-        # a minority of the held experts (one nobody picked is not read);
-        # a decode step whose rows pick nearly all of them multiplies with
-        # the whole stack at once (PERF.md, PR 33 and PR 36)
+        # for (``ops.nn.expert_form``): a chunk multiplies each hit expert
+        # with its sorted rows, and so does a decode step whose rows can
+        # leave some of the held experts unpicked (one nobody picked is
+        # not read); a decode step whose rows pick nearly all of them
+        # multiplies with the whole stack at once (PERF.md)
         live = None if cache is None else cache.token_live(x.shape[1])
+        gate = self.gate_weight.data()
         impl = "dense" if cache is None else _ops.expert_form(
             x.shape[0] * x.shape[1], x.shape[1], self._top_k,
-            self.router.weight.shape[0])
+            self.router.weight.shape[0], _ops.grouped_in_kernel(x, gate))
         out, load = _ops.routed_experts(
-            x, self.router.weight.data(), self.gate_weight.data(),
+            x, self.router.weight.data(), gate,
             self.up_weight.data(), self.down_weight.data(), self._top_k,
             held=self._held, token_live=live, renormalize=self._renorm,
             impl=impl, score=self._score, groups=self._groups,

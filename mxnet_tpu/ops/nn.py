@@ -1834,28 +1834,35 @@ def gated_delta_rule(q, k, v, g, beta, state=None, start_pos=None,
 #
 # Two formulations of the expert products, the same mathematics; which
 # one a cached call takes is ``expert_form``'s to say, from the call's
-# shapes. ``grouped`` sorts the (token, expert) assignments by expert and
-# walks tiles of ``tile`` sorted rows, each against the one expert it
-# belongs to (a while loop whose trip count is the tiles in use: an expert
-# nobody picked is never read, N x k rows of work whatever E is; 32 rows a
-# tile take half the time of 8 on a v5e at 1,024 assignments: PERF.md,
-# PR 33): a call of many positions, and a decode step whose few rows can
-# hit a minority of the held experts. ``dense`` multiplies every token
-# with every held expert in one product over the stack and weights the
-# products (differentiable, E / k times the work): the normal path, and a
-# decode step whose rows hit nearly all the held experts.
+# shapes and whether the kernel covers it (``grouped_in_kernel``).
+# ``grouped`` sorts the (token, expert) assignments by expert and
+# multiplies each expert hit with its own rows (an expert nobody picked is
+# never read, N x k rows of work whatever E is): on a TPU in one Pallas
+# kernel that reads each hit expert once a call (ops/pallas/
+# expert_products.py, where its rows fit), elsewhere in a while loop over
+# tiles of
+# ``tile`` sorted rows, each against the one expert it belongs to (an
+# expert read once a tile); a call of many positions, and a decode step
+# whose rows can hit fewer than nearly all the held experts. ``dense``
+# multiplies every token with every held expert in one product over the
+# stack and weights the products (differentiable, E / k times the work):
+# the normal path, and a decode step whose rows hit nearly all the held
+# experts.
 # ---------------------------------------------------------------------------
 
 # The share of the held experts a decode step can expect to hit under
-# which it walks the tiles. A property of the two formulations on a v5e,
-# from the row sweep of exp/moe_products_bench.py (PERF.md, PR 36): an
-# expert read costs the tiles 1.5 times what it costs the one product over
-# the stack at 64 experts of 2304 x 896 (0.054 against 0.035 ms: a tie at
-# 40 of 64 hit, 8 rows, 0.66 reckoned) and 1.09 times at 8 experts of
-# 4096 x 4096 (0.30 against 0.277 ms: the tiles ahead up to 0.9). The
-# lower crossover stands for both; the cells sit far to either side of it
-# (8 rows, 8 of 128: 0.40; 16 rows, 8 of 64: 0.88).
-GROUPED_UNDER_SHARE = 0.7
+# which it takes the grouped form, with the kernel and with the XLA loop.
+# Properties of the formulations on a v5e, from the row sweeps of
+# exp/moe_products_bench.py (PERF.md): the kernel reads each hit expert
+# once at the bandwidth the one product over the stack reaches, so it
+# wins at 16 rows of 8 of 64 experts of 2304 x 896 (0.88 reckoned, 57
+# hit) and ties at 32 (0.986, all 64 hit), and at 8 of 128 experts of
+# 4096 x 4096 wins at every row count the sweep ran; the loop's tiles
+# read an expert at 1.5 times the one product's cost at 64 experts of
+# 2304 x 896 (0.054 against 0.035 ms: a tie at 0.66) and 1.09 times at 8
+# of 4096 x 4096, and the lower crossover stands for both.
+GROUPED_UNDER_SHARE = 0.95
+TILED_UNDER_SHARE = 0.7
 
 
 def expected_hit_share(rows, top_k, num_experts):
@@ -1865,17 +1872,31 @@ def expected_hit_share(rows, top_k, num_experts):
     return 1.0 - (1.0 - top_k / num_experts) ** rows
 
 
-def expert_form(rows, positions, top_k, num_experts):
+def expert_form(rows, positions, top_k, num_experts, kernel):
     """Which formulation of the expert products (the section comment) a
     cached call of ``rows`` tokens (lanes x positions) takes, from its
-    static shapes alone. ``"grouped"`` for a call of more than one
-    position (N x k rows of work against N x E), and for a decode step
-    whose ``expected_hit_share`` is under ``GROUPED_UNDER_SHARE``; else
-    ``"dense"``."""
+    static shapes alone and ``kernel``, whether its grouped form would run
+    in the kernel (:func:`grouped_in_kernel`). ``"grouped"`` for a call
+    of more than one position (N x k rows of work against N x E), and for
+    a decode step whose ``expected_hit_share`` is under
+    ``GROUPED_UNDER_SHARE`` (the kernel) or ``TILED_UNDER_SHARE`` (the
+    loop); else ``"dense"``."""
+    under = GROUPED_UNDER_SHARE if kernel else TILED_UNDER_SHARE
     if positions > 1 or \
-            expected_hit_share(rows, top_k, num_experts) < GROUPED_UNDER_SHARE:
+            expected_hit_share(rows, top_k, num_experts) < under:
         return "grouped"
     return "dense"
+
+
+def grouped_in_kernel(x, gate):
+    """Whether the grouped form of a call over ``x`` (..., H) with the
+    stack ``gate`` (E, H, F) runs in the kernel of
+    ``ops.pallas.expert_products`` (its ``supports``: a TPU or the
+    interpreter, widths it tiles, rows that fit VMEM) rather than the XLA
+    loop; arrays or NDArrays."""
+    from .pallas import expert_products as kern
+
+    return kern.supports(getattr(x, "_data", x), getattr(gate, "_data", gate))
 
 
 @_scoped("experts.router")
@@ -1974,15 +1995,18 @@ def grouped_expert_products(x, local, gate, up, down, tile=32, limit=None):
     (``E`` itself for one that is not computed: an expert held elsewhere,
     a token that is not live), weights ``gate``/``up`` (E, H, F) and
     ``down`` (E, F, H), ``limit`` :func:`swiglu`'s. Returns ``((N, k, H)
-    products, (E,) int32 rows of each expert)``; an assignment that is not
-    computed gives zeros."""
-    import jax
+    products, (E,) int32 rows of each expert, the weight reads of the
+    stack)``; an assignment that is not computed gives zeros.
+
+    Where the kernel covers the call (:func:`grouped_in_kernel`) it reads
+    each hit expert once; elsewhere a loop walks tiles of ``tile`` sorted
+    rows and reads an expert once a tile."""
+    from .pallas import expert_products as kern
 
     jnp = _jnp()
     n, k = local.shape
-    e_held, h = gate.shape[0], x.shape[1]
+    e_held = gate.shape[0]
     a = n * k
-    tile = int(tile)
     flat = local.reshape(a)
     with _device_scope("experts.router"):   # the sort by expert
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)
@@ -1990,6 +2014,49 @@ def grouped_expert_products(x, local, gate, up, down, tile=32, limit=None):
             flat[order], jnp.arange(e_held + 1, dtype=jnp.int32),
             side="left").astype(jnp.int32)                       # (E + 1,)
         counts = edges[1:] - edges[:-1]
+    if not grouped_in_kernel(x, gate):
+        ys, reads = _tiled_expert_products(x, order, edges, counts, k, gate,
+                                           up, down, int(tile), limit)
+        with _device_scope("experts.router"):   # back to the tokens' order
+            back = jnp.zeros((a,), jnp.int32).at[order].set(
+                jnp.arange(a, dtype=jnp.int32))
+            return ys[back].reshape(n, k, -1), counts, reads
+    with _device_scope("experts.router"):   # the padded layout, the hit list
+        sub = kern.sublane_rows(x.dtype)
+        starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(
+            (counts + sub - 1) // sub * sub).astype(jnp.int32)])  # (E + 1,)
+        # sorted row i of expert e lands at starts[e] + i - edges[e]; the
+        # rows that are not computed (e = E) land past every expert's
+        sorted_pos = jnp.arange(a, dtype=jnp.int32) \
+            + (starts - edges)[flat[order]]
+        pos = jnp.zeros((a,), jnp.int32).at[order].set(sorted_pos)
+        p = kern.padded_rows(a, e_held, sub)
+        xs = jnp.zeros((p, x.shape[1]), x.dtype).at[pos].set(
+            x[jnp.arange(a, dtype=jnp.int32) // k], mode="drop")
+        nhit = jnp.sum(counts > 0).astype(jnp.int32)
+        hit = jnp.sort(jnp.where(counts > 0,
+                                 jnp.arange(e_held, dtype=jnp.int32),
+                                 jnp.int32(e_held)))
+        hit = jnp.where(hit < e_held, hit,
+                        hit[jnp.maximum(nhit - 1, 0)] % e_held)
+    ys = kern.expert_products(xs, starts, counts, hit, nhit.reshape(1), gate,
+                              up, down, n, limit, stored_precision(x, gate))
+    with _device_scope("experts.router"):   # back to the tokens' order
+        mine = (flat < e_held)[:, None]
+        ys = jnp.where(mine, ys[jnp.minimum(pos, p - 1)], 0)
+        return ys.reshape(n, k, -1), counts, nhit
+
+
+def _tiled_expert_products(x, order, edges, counts, k, gate, up, down, tile,
+                           limit):
+    """The sorted rows' products by tiles of ``tile`` rows, none across two
+    experts, in a while loop that slices each tile's expert out of the
+    stacks: ``((A + tile, H) products in sorted order, tiles walked)``."""
+    import jax
+
+    jnp = _jnp()
+    a, h = order.shape[0], x.shape[1]
+    with _device_scope("experts.router"):
         # tiles of ``tile`` sorted rows, none across two experts: expert e
         # has ceil(count / tile), in expert order
         ends = jnp.cumsum((counts + tile - 1) // tile).astype(jnp.int32)
@@ -2013,10 +2080,7 @@ def grouped_expert_products(x, local, gate, up, down, tile=32, limit=None):
 
     ys = jax.lax.fori_loop(zero, ends[-1], body,
                            jnp.zeros((a + tile, h), x.dtype))
-    with _device_scope("experts.router"):   # back to the tokens' order
-        back = jnp.zeros((a,), jnp.int32).at[order].set(
-            jnp.arange(a, dtype=jnp.int32))
-        return ys[back].reshape(n, k, h), counts
+    return ys, ends[-1]
 
 
 def dense_expert_products(x, gate, up, down, limit=None):
@@ -2059,12 +2123,15 @@ def routed_experts(data, router_weight, gate, up, down, top_k, held=None,
     multiplies every token's weights after they are renormalised;
     ``limit`` is :func:`swiglu`'s.
 
-    Returns ``(out (B, T, H), load (6,) int32)``: the held experts' part
+    Returns ``(out (B, T, H), load (7,) int32)``: the held experts' part
     of every token's sum, and ``[held experts that got a live token, the
     most tokens one of them got, assignments computed (the live tokens'
     that fell on a held expert), the live tokens' assignments (k each,
     held here or not), experts held, held experts whose weights the call
-    read (all of them for ``dense``, those hit for ``grouped``)]``.
+    read (all of them for ``dense``, those hit for ``grouped``), the
+    reads of an expert's weights the call made (``E`` for ``dense``; for
+    ``grouped`` one a hit expert in the kernel, one a tile in the loop:
+    :func:`grouped_expert_products`)]``.
     """
     first, count = (0, gate.shape[0]) if held is None else \
         (int(held[0]), int(held[1]))
@@ -2073,9 +2140,14 @@ def routed_experts(data, router_weight, gate, up, down, top_k, held=None,
                          f"hold {gate.shape[0]}")
     if impl not in ("grouped", "dense"):
         raise MXNetError(f"routed_experts impl {impl!r}")
+    from .pallas import expert_products as kern
+
+    # routing globals must live in f's closure (see attention())
+    routing = kern._INTERPRET
 
     @_scoped("experts.router")
     def f(x, rw, g, u, d, live, eb):
+        assert routing == kern._INTERPRET
         jnp = _jnp()
         b, t, h = x.shape
         xf = x.reshape(b * t, h)
@@ -2095,8 +2167,8 @@ def routed_experts(data, router_weight, gate, up, down, top_k, held=None,
         # the sort and the combine) but the experts' own products
         if impl == "grouped":
             with _device_scope("experts.routed"):
-                prod, counts = grouped_expert_products(xf, local, g, u, d,
-                                                       tile, limit)
+                prod, counts, reads = grouped_expert_products(
+                    xf, local, g, u, d, tile, limit)
             out = jnp.sum(prod * w[:, :, None].astype(prod.dtype), axis=1)
         else:
             combine = jnp.zeros((b * t, count + 1), w.dtype).at[
@@ -2108,10 +2180,12 @@ def routed_experts(data, router_weight, gate, up, down, top_k, held=None,
                              precision=stored_precision(xf))
             counts = jnp.zeros((count + 1,), jnp.int32).at[
                 local.reshape(-1)].add(1)[:count]
+            reads = count
         hit = jnp.sum(counts > 0)
         load = jnp.stack([hit, jnp.max(counts), jnp.sum(counts),
                           jnp.asarray(asked), jnp.asarray(count),
-                          jnp.asarray(count) if impl == "dense" else hit])
+                          jnp.asarray(count) if impl == "dense" else hit,
+                          jnp.asarray(reads)])
         return out.reshape(b, t, h), load.astype(jnp.int32)
 
     return _apply(f, (data, router_weight, gate, up, down, token_live, bias),

@@ -661,7 +661,8 @@ class ContinuousEngine:
         with host_span("mxnet_tpu.serve.route") as span:
             # (calls, layers, [held experts hit, most on one, assignments
             # that fell on a held expert, the live tokens' assignments,
-            # experts held, held experts whose weights the call read])
+            # experts held, held experts whose weights the call read, the
+            # reads of an expert's weights it made])
             load = _onp.stack([_onp.asarray(a.asnumpy(), _onp.int64)
                                for a in pending])
             hit = int(load[..., 0].sum())
@@ -670,13 +671,14 @@ class ContinuousEngine:
             assignments = int(load[..., 3].sum())
             held = int(load[..., 4].sum())
             read = int(load[..., 5].sum())
+            reads = int(load[..., 6].sum())
             # the most loaded expert over the mean of those hit, at worst
             skew = float((load[..., 1] * load[..., 0]
                           / _onp.maximum(load[..., 2], 1)).max())
             span.set_metadata(experts_hit=hit, assignments=assignments,
                               max_load=top, calls=len(pending),
                               experts_held=held, assignments_held=on_held,
-                              experts_read=read)
+                              experts_read=read, expert_reads=reads)
         m = self._moe
         if m is None:
             m = self._moe = {"reads": 0, "calls": 0, "assignments": 0,
@@ -684,7 +686,8 @@ class ContinuousEngine:
                              "load_max_over_mean": 0.0, "experts_held": 0,
                              "assignments_held": 0,
                              "assignments_held_share": 0.0,
-                             "experts_read": 0, "picked_share": 0.0}
+                             "experts_read": 0, "picked_share": 0.0,
+                             "expert_reads": 0}
         m["reads"] += 1
         m["calls"] += len(pending)
         m["assignments"] += assignments
@@ -695,6 +698,7 @@ class ContinuousEngine:
             / max(m["assignments"], 1)
         m["experts_read"] += read
         m["picked_share"] = m["experts_hit"] / max(m["experts_read"], 1)
+        m["expert_reads"] += reads
         m["max_load"] = max(m["max_load"], top)
         m["load_max_over_mean"] = skew
         _prof.incr_counter("serve.moe_assignments", assignments, cat="serve")

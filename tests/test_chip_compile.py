@@ -346,6 +346,52 @@ def test_latent_paged_decode_kernel_compiles_for_v5e(one_chip):
     assert f"f32[{c['lanes']},1,{c['heads']},{_LATENT_V}]" in text
 
 
+@pytest.mark.parametrize("experts,hidden,width,rows,fits", [
+    (64, 2304, 896, 144, True), (64, 2304, 896, 16, True),
+    (8, 4096, 4096, 136, True), (8, 7168, 2048, 136, True),
+    (8, 7168, 2048, 32, True), (8, 7168, 2048, 912, True),
+    (8, 7168, 2048, 1024, False)],
+    ids=["mellum-mixed", "mellum-decode", "command_a-mixed", "ax_k1-mixed",
+         "gigachat-decode", "ax_k1-most-rows-that-fit",
+         "ax_k1-chunk-of-1024"])
+def test_grouped_expert_kernel_compiles_for_v5e(one_chip, monkeypatch,
+                                                experts, hidden, width, rows,
+                                                fits):
+    """The routed experts' kernel at the cells' widths: Mellum-2's 64
+    experts of 2304 x 896 under a chunk of 128 with 16 decode lanes and
+    under a decode step, Command A+'s 8 held of 4096 x 4096 and A.X-K1's
+    and GigaChat 3.5's 8 held of 7168 x 2048, with top 8. The stacks are
+    the kernel's operands in their own types, which the benchmark's
+    expert rooflines match. The rows' scratch grows with the call's rows:
+    the most that ``supports`` lets into the kernel at 7168 x 2048
+    compile, and a chunk of 1,024 rows there takes the XLA loop."""
+    from mxnet_tpu.ops.pallas import expert_products as kern
+
+    monkeypatch.setattr(kern, "_platform_of", lambda x: "tpu")  # the chip
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(x, local, gate, up, down):
+        return ops.grouped_expert_products(x, local, gate, up, down)[0]
+
+    stack = sds((experts, hidden, width), jnp.float32)
+    x = sds((rows, hidden), jnp.float32)
+    assert kern.supports(x, stack) == fits
+    text = _compile(fn, x, sds((rows, 8), jnp.int32), stack, stack,
+                    sds((experts, width, hidden), jnp.float32))
+    call = [line for line in text.splitlines()
+            if "custom-call(" in line and "grouped_expert_products" in line]
+    if not fits:
+        assert not call and " while(" in text
+        return
+    assert len(call) == 1 and "tpu_custom_call" in call[0]
+    for t in (f"f32[{experts},{hidden},{width}]",
+              f"f32[{experts},{width},{hidden}]"):
+        assert t in call[0].partition("custom-call(")[2]
+    assert kern.block_width(hidden, width, 4) is not None
+
+
 def _inplace_step(cell, rows, t_len, path, one_chip, riders=0):
     """The engine's in-place step over ``cell``'s model (one layer, every
     width real, parameters never materialized), compiled for the
@@ -433,7 +479,10 @@ def test_decode_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell,
     shape is ever made, and no copy of a pool stands beside the writes
     (XLA:TPU transposes the whole operand of a scatter whose indexed
     dimensions are not its outermost: ``ops.nn.write_pages``)."""
-    monkeypatch.setattr(da, "_platform_of", lambda x: "tpu")  # the described chip
+    from mxnet_tpu.ops.pallas import expert_products as kern
+
+    for mod in (da, kern):   # the described chip
+        monkeypatch.setattr(mod, "_platform_of", lambda x: "tpu")
     c = _CELLS[cell]
     text, stores, pairs = _inplace_step(cell, c["lanes"], 1, path, one_chip)
     assert da.last_path() == "pallas_paged"
@@ -453,34 +502,35 @@ def test_decode_step_in_place_compiles_for_v5e(one_chip, monkeypatch, cell,
 
 def _expert_form_of(cell, text):
     """Which form of the expert products the decode step took
-    (``ops.nn.expert_form``). Command A+'s 8 lanes can hit 0.40 of its 8
-    held experts of 128: the tiles' loop is in the step, and every array
-    of an expert's width has one of the types the benchmark's
-    ``held_expert_roofline`` (the routed stack, one expert of it) and
-    ``shared_expert_roofline`` (the shared stack) tell the branches by: a
-    reshape of the stack to another type would take its products out of
-    those readings. Mellum-2's 16 lanes can hit 0.88 of its 64: one
-    product over the stack, no loop and no condition."""
+    (``ops.nn.expert_form``). Every routed cell's lanes can hit under 0.95
+    of their held experts (Command A+'s 8 lanes 0.40 of 8 of 128,
+    Mellum-2's 16 lanes 0.88 of 64, A.X-K1's 8 lanes 0.29, GigaChat 3.5's
+    32 lanes 0.64), so each step takes the grouped form, which on the chip
+    is the kernel: its operands are the stacks themselves, in the types
+    the benchmark's ``moe_expert_roofline`` and ``held_expert_roofline``
+    match, and no expert is sliced out of a stack. The models without
+    routed experts have no loop and no condition."""
     import re
 
-    loops = re.findall(r" (while|conditional)\(", text)
-    if cell == "gigachat":
-        # 32 lanes can hit 0.64 of its 8 held experts of 256: the tiles
-        assert "while" in loops
-        assert text.count("dynamic_slice_sizes={1,7168,2048}") >= 2
-    elif cell == "ax_k1":
-        # 8 lanes can hit 0.29 of its 8 held experts of 192: the tiles
-        assert "while" in loops
-        assert text.count("dynamic_slice_sizes={1,7168,2048}") >= 2
-    elif cell == "command_a":
-        assert "while" in loops
+    stacks = {"mellum": (64, 2304, 896), "command_a": (8, 4096, 4096),
+              "ax_k1": (8, 7168, 2048), "gigachat": (8, 7168, 2048)}
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "grouped_expert_products" in line]
+    if cell not in stacks:
+        assert not calls
+        assert not re.findall(r" (while|conditional)\(", text)
+        return
+    e, h, f = stacks[cell]
+    assert calls
+    for line in calls:
+        operands = line.partition("custom-call(")[2]
+        assert f"f32[{e},{h},{f}]" in operands
+        assert f"f32[{e},{f},{h}]" in operands
+    assert f"dynamic_slice_sizes={{1,{h},{f}}}" not in text
+    if cell == "command_a":
+        # the routed stack and the shared one, never another type
         assert set(re.findall(r"f32\[(?:\d+,)*4096,4096\]", text)) <= {
-            "f32[8,4096,4096]", "f32[1,4096,4096]", "f32[4096,4096]",
-            "f32[4,4096,4096]"}
-        # a tile's expert, sliced out of each of the three stacks
-        assert text.count("dynamic_slice_sizes={1,4096,4096}") >= 3
-    else:
-        assert not loops
+            "f32[8,4096,4096]", "f32[4,4096,4096]"}
 
 
 @pytest.mark.parametrize("cell", ["mistral", "falcon", "mellum",

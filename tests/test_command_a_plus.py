@@ -379,8 +379,8 @@ def _command_like():
 
 
 def _mellum_like():
-    """All 8 experts held at top 2, six lanes: 0.82."""
-    return _mellum()[0], 6
+    """All 8 experts held at top 2, eleven lanes: 0.96."""
+    return _mellum()[0], 11
 
 
 @pytest.mark.parametrize("build,form", [(_command_like, "grouped"),
@@ -397,13 +397,14 @@ def test_a_served_model_lands_on_its_side_of_the_rule(monkeypatch, build,
     try:
         net, slots = build()
         net.initialize(mx.init.Normal(0.1))
-        prompts = tokens_of(21, 11, 19, 9, 14, 17, 12)[:slots]
+        prompts = tokens_of(21, 11, 19, 9, 14, 17, 12, 10, 16, 13, 18,
+                            15)[:slots]
 
         def served(force=None):
             seen, real = set(), ops.expert_form
 
-            def rule(rows, positions, k, e_all):
-                got = real(rows, positions, k, e_all)
+            def rule(rows, positions, k, e_all, kernel):
+                got = real(rows, positions, k, e_all, kernel)
                 if positions == 1:
                     seen.add(got)
                     return force or got
@@ -525,7 +526,7 @@ def test_the_other_models_step_arguments_and_results(build, routed):
             want.add((n_args + 3 + int(windowed), n_out))
         assert set(seen) == want
         # one row a layer (the tiny Mellum-2 has four)
-        assert set(loads) == ({(4, 6)} if routed else set())
+        assert set(loads) == ({(4, 7)} if routed else set())
         assert ("moe" in eng.stats()) == routed
         if routed:
             moe = eng.stats()["moe"]

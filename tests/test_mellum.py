@@ -34,6 +34,7 @@ from mxnet_tpu.models import llama
 from mxnet_tpu.models.mellum import MellumModel, RoutedFFN
 from mxnet_tpu.ops import nn as ops
 from mxnet_tpu.ops.pallas import decode_attention as da
+from mxnet_tpu.ops.pallas import expert_products as kern
 from mxnet_tpu.profiler import core as prof
 from mxnet_tpu.serve.generate import CacheLayout
 
@@ -409,13 +410,36 @@ def _experts(seed, e=8, h=16, f=12):
                 down=a(e, f, h))
 
 
+# the forms of the expert products: the grouped form's XLA loop over
+# tiles (what a platform without the kernel runs), the grouped form's
+# kernel (through the Pallas interpreter) and the one product over the
+# stack
+FORMS = ["grouped", "kernel", "dense"]
+
+
 def _routed(x, p, k, impl, held=None, **kw):
     first, count = held or (0, p["gate"].shape[0])
     sl = slice(first, first + count)
-    out, load = ops.routed_experts(
-        x, p["router"], p["gate"][sl], p["up"][sl], p["down"][sl], k,
-        held=held, impl=impl, tile=4, **kw)
+    kern.use_interpret(impl == "kernel")
+    try:
+        out, load = ops.routed_experts(
+            x, p["router"], p["gate"][sl], p["up"][sl], p["down"][sl], k,
+            held=held, impl="dense" if impl == "dense" else "grouped",
+            tile=4, **kw)
+    finally:
+        kern.use_interpret(False)
     return out.asnumpy(), load.asnumpy()
+
+
+def _reads(impl, load, counts=None, tile=4):
+    """The load's seventh number for ``impl``: one read an expert hit
+    under the kernel, one a tile of ``tile`` rows under the loop (the
+    held experts' ``counts`` of rows), every held expert for ``dense``."""
+    if impl == "kernel":
+        return int(load[0])
+    if impl == "dense":
+        return int(load[4])
+    return sum(-(-int(c) // tile) for c in counts)
 
 
 def _by_hand(x, p, k, held=None):
@@ -440,7 +464,7 @@ def _by_hand(x, p, k, held=None):
     return out
 
 
-@pytest.mark.parametrize("impl", ["grouped", "dense"])
+@pytest.mark.parametrize("impl", FORMS)
 def test_every_token_to_the_same_experts_loses_none(impl):
     """No capacity: 40 tokens that all pick experts 2 and 5 get both
     (40 rows on each of two experts, none on the other six), and the
@@ -453,12 +477,14 @@ def test_every_token_to_the_same_experts_loses_none(impl):
     x[..., 0] = 1.0 + np.abs(x[..., 0])         # every token: 2, then 5
     x = mx.np.array(x)
     out, load = _routed(x, p, 2, impl)
-    # the tiles read the two experts hit, the one product all eight
-    assert load.tolist() == [2, 40, 80, 80, 8, 2 if impl == "grouped" else 8]
+    # the tiles read the two experts hit, the one product all eight; the
+    # loop reads each of the two once a tile of 4 of its 40 rows
+    assert load.tolist() == [2, 40, 80, 80, 8, 8 if impl == "dense" else 2,
+                             {"grouped": 20, "kernel": 2, "dense": 8}[impl]]
     close(out.reshape(-1, 16), _by_hand(x, p, 2), 1e-5)
 
 
-@pytest.mark.parametrize("impl", ["grouped", "dense"])
+@pytest.mark.parametrize("impl", FORMS)
 def test_tokens_that_are_not_live_go_to_no_expert(impl):
     p = _experts(3)
     x = mx.np.array(np.random.RandomState(4).randn(2, 6, 16)
@@ -472,7 +498,7 @@ def test_tokens_that_are_not_live_go_to_no_expert(impl):
 
 # -- (g) the held experts' share -------------------------------------------------
 
-@pytest.mark.parametrize("impl", ["grouped", "dense"])
+@pytest.mark.parametrize("impl", FORMS)
 def test_the_four_quarters_add_up_to_the_whole_layer(impl):
     """``experts_held`` set to each quarter of the experts in turn: the
     router keeps its width, each share computes its own experts' part,
@@ -515,22 +541,24 @@ def _lanes(seed, n=8):
     return mx.np.array(x)
 
 
-@pytest.mark.parametrize("impl", ["grouped", "dense"])
+@pytest.mark.parametrize("impl", FORMS)
 @pytest.mark.parametrize("case", ["nobody_picked_a_held_expert",
                                   "dead_lanes", "one_expert_takes_every_row"])
 def test_a_decode_steps_rows(impl, case):
-    """(8, 1) calls, the shape the tiles had never run at: both forms
+    """(8, 1) calls, the shape the tiles had never run at: every form
     against the sum computed a token and an expert at a time, and the
     load's sixth number: the held experts whose weights the call read
-    (those hit for the tiles, all of them for the one product)."""
+    (those hit for the tiles and the kernel, all of them for the one
+    product)."""
     held, k = (4, 4), 1
-    read = (lambda hit: hit) if impl == "grouped" else (lambda hit: held[1])
+    read = (lambda hit: held[1]) if impl == "dense" else (lambda hit: hit)
     x = _lanes(11)
     if case == "nobody_picked_a_held_expert":
         # every row's one expert is held elsewhere: no tile, zeros
         out, load = _routed(x, _steered(12, 9), k, impl, held=held)
         assert not out.any()
-        assert load.tolist() == [0, 0, 0, 8, 4, read(0)]
+        assert load.tolist() == [0, 0, 0, 8, 4, read(0),
+                                 4 if impl == "dense" else 0]
     elif case == "dead_lanes":
         p = _experts(13, e=16)
         live = np.array([1, 0, 1, 0, 0, 1, 0, 0], bool).reshape(8, 1)
@@ -542,63 +570,213 @@ def test_a_decode_steps_rows(impl, case):
         on_held = int(load[2])
         assert 0 < on_held <= 12 and load[3] == 12 and load[4] == 4
         assert 0 < load[0] <= min(4, on_held) and load[5] == read(load[0])
+        if impl != "grouped":
+            assert load[6] == read(load[0])
     else:
         p = _steered(14, 6)
         out, load = _routed(x, p, k, impl, held=held)
-        assert load.tolist() == [1, 8, 8, 8, 4, read(1)]
+        assert load.tolist() == [1, 8, 8, 8, 4, read(1),
+                                 _reads(impl, load, [8])]
         close(out[:, 0], _by_hand(x, p, k, held=held), 1e-5)
 
 
-@pytest.mark.parametrize("impl", ["grouped", "dense"])
+@pytest.mark.parametrize("impl", FORMS)
 def test_a_decode_steps_forms_agree(impl):
     """Eight rows over 4 held of 16 experts at top 4 (about eight
     assignments fall on a held expert): the form against the plain loop,
-    and the two forms' loads alike but for the sixth number."""
+    and the forms' loads alike but for the sixth and seventh numbers."""
     p, x, held = _experts(15, e=16), _lanes(16), (8, 4)
     out, load = _routed(x, p, 4, impl, held=held)
     close(out[:, 0], _by_hand(x, p, 4, held=held), 1e-5)
-    other, theirs = _routed(x, p, 4, "dense" if impl == "grouped"
-                            else "grouped", held=held)
-    close(out, other, 1e-5)
-    assert load[:5].tolist() == theirs[:5].tolist()
-    assert load[5] == (load[0] if impl == "grouped" else 4)
+    for form in FORMS:
+        other, theirs = _routed(x, p, 4, form, held=held)
+        close(out, other, 1e-5)
+        assert load[:5].tolist() == theirs[:5].tolist()
+    assert load[5] == (4 if impl == "dense" else load[0])
+    if impl == "dense":
+        assert load[6] == 4
+    else:   # at most 8 rows an expert: one tile of 4 or two, for the loop
+        assert load[0] <= load[6] <= (2 if impl == "grouped" else 1) * load[0]
+
+
+# -- (g''') the kernel of the grouped form -----------------------------------------
+
+@pytest.mark.parametrize("case", [
+    "one_expert_takes_every_row", "experts_with_no_rows",
+    "counts_not_a_multiple_of_eight", "held_elsewhere_and_dead_lanes",
+    "swiglu_limit", "one_row", "a_chunk_and_its_riders",
+    "two_blocks_of_the_expert_width", "widest_tiles_of_sixteen_rows"])
+def test_the_kernel_against_the_one_product(monkeypatch, case):
+    """``grouped_expert_products`` through the kernel (the Pallas
+    interpreter) against ``dense_expert_products`` picked by the route:
+    every assignment's product, zeros where it is not computed, the rows
+    of each expert, one weight read a hit expert, and the sum weighted by
+    the route."""
+    import jax.numpy as jnp
+
+    e, h, f, n, k, limit = 8, 16, 12, 21, 2, None
+    rs = np.random.RandomState(sum(map(ord, case)))
+    if case == "experts_with_no_rows":
+        e, n = 16, 3
+    elif case == "counts_not_a_multiple_of_eight":
+        e, n, k = 4, 21, 3
+    elif case == "swiglu_limit":
+        limit = 0.3
+    elif case == "one_row":
+        n = 1
+    elif case == "a_chunk_and_its_riders":
+        n = 128 + 16
+    elif case == "two_blocks_of_the_expert_width":
+        h, f = 128, 256
+        monkeypatch.setattr(kern, "_WEIGHT_VMEM", 2 * 3 * h * 128 * 4)
+        assert kern.block_width(h, f, 4) == 128
+    elif case == "widest_tiles_of_sixteen_rows":
+        monkeypatch.setattr(kern, "_ROW_TILE", 16)
+    w = lambda *s: jnp.asarray((0.4 * rs.randn(*s)).astype("float32"))  # noqa: E731
+    gate, up, down, x = w(e, h, f), w(e, h, f), w(e, f, h), w(n, h)
+    logits = w(n, e)
+    if case == "one_expert_takes_every_row":
+        logits = logits.at[:, 3].add(100.0)
+    elif case == "a_chunk_and_its_riders":   # the chunk's rows lean to one
+        logits = logits.at[:128, 3].add(100.0)
+    weights, local = ops.route_top_k(logits, k)
+    if case == "held_elsewhere_and_dead_lanes":
+        held = (local >= 2) & (local < 6)               # 4 of 8 here
+        held &= jnp.asarray(rs.rand(n, 1) < 0.7)        # lanes not live
+        local = jnp.where(held, local - 2, 4)
+        gate, up, down = gate[2:6], up[2:6], down[2:6]
+        e = 4
+    kern.use_interpret(True)
+    try:
+        prod, counts, reads = ops.grouped_expert_products(
+            x, local, gate, up, down, limit=limit)
+    finally:
+        kern.use_interpret(False)
+    loc = np.asarray(local)
+    every = np.asarray(ops.dense_expert_products(x, gate, up, down, limit))
+    want = np.zeros((n, k, h), np.float32)
+    for i, j in zip(*np.nonzero(loc < e)):
+        want[i, j] = every[loc[i, j], i]
+    close(np.asarray(prod), want, 1e-5)
+    assert not np.asarray(prod)[loc == e].any()
+    assert np.asarray(counts).tolist() \
+        == np.bincount(loc[loc < e], minlength=e).tolist()
+    assert int(reads) == int((np.asarray(counts) > 0).sum())
+    if case == "one_expert_takes_every_row":
+        assert int(reads) == k and np.asarray(counts)[3] == n
+    elif case == "a_chunk_and_its_riders":   # whole widest tiles, and more
+        assert np.asarray(counts)[3] > 4 * kern.tile_plan(n, 8)[0]
+    w_here = np.where(loc < e, np.asarray(weights), 0.0)
+    close(np.einsum("nkh,nk->nh", np.asarray(prod), w_here),
+          np.einsum("nkh,nk->nh", want, w_here), 1e-5)
+
+
+@pytest.mark.parametrize("experts,hidden,width,rows,fits", [
+    (64, 2304, 896, 144, True), (8, 4096, 4096, 136, True),
+    (8, 7168, 2048, 160, True), (8, 7168, 2048, 912, True),
+    (8, 7168, 2048, 913, False), (8, 7168, 2048, 10880, False)],
+    ids=["mellum2_mixed", "command_a_mixed", "gigachat_mixed",
+         "ax_k1_most_rows", "ax_k1_a_row_more", "ax_k1_whole_context"])
+def test_the_kernel_takes_the_rows_that_fit_vmem(experts, hidden, width, rows,
+                                                 fits):
+    """``supports`` holds a call's weight blocks, rows' scratch and stack
+    room to a v5e's 128 MiB of VMEM: the cells' mixed calls fit with room
+    to spare, and at 7168 x 2048 float32 (84 KiB of scratch a row) 912
+    rows fit and one more does not."""
+    import jax
+    import jax.numpy as jnp
+
+    x = jax.ShapeDtypeStruct((rows, hidden), jnp.float32)
+    gate = jax.ShapeDtypeStruct((experts, hidden, width), jnp.float32)
+    kern.use_interpret(True)
+    try:
+        assert kern.supports(x, gate) == fits
+    finally:
+        kern.use_interpret(False)
+    assert (kern.vmem_bytes(rows, hidden, width, x.dtype, gate.dtype)
+            <= kern._VMEM) == fits
+
+
+def test_a_call_whose_rows_do_not_fit_takes_the_loop(monkeypatch):
+    """A call whose rows would pass ``_VMEM`` gets the same products from
+    the XLA loop, which reads an expert once a tile of rows."""
+    import jax.numpy as jnp
+
+    e, h, f, n, k = 8, 16, 12, 40, 2
+    rs = np.random.RandomState(41)
+    w = lambda *s: jnp.asarray((0.4 * rs.randn(*s)).astype("float32"))  # noqa: E731
+    gate, up, down, x = w(e, h, f), w(e, h, f), w(e, f, h), w(n, h)
+    _, local = ops.route_top_k(w(n, e), k)
+    kern.use_interpret(True)
+    try:
+        fit, counts, hit = ops.grouped_expert_products(x, local, gate, up,
+                                                       down, tile=4)
+        monkeypatch.setattr(kern, "_VMEM", kern.vmem_bytes(
+            n - kern.sublane_rows(x.dtype), h, f, x.dtype, gate.dtype))
+        assert not kern.supports(x, gate)
+        loop, counts2, tiles = ops.grouped_expert_products(x, local, gate, up,
+                                                           down, tile=4)
+    finally:
+        kern.use_interpret(False)
+    close(np.asarray(loop), np.asarray(fit), 1e-5)
+    assert np.asarray(counts2).tolist() == np.asarray(counts).tolist()
+    assert int(hit) == int((np.asarray(counts) > 0).sum())
+    assert int(tiles) == sum(-(-int(c) // 4) for c in np.asarray(counts))
+    assert int(tiles) > int(hit)
 
 
 # -- (g'') which form a cached call takes ------------------------------------------
 
-@pytest.mark.parametrize("rows,positions,k,e_all,form", [
-    (8, 1, 8, 128, "grouped"),       # Command A+'s decode step: 0.40 hit
-    (16, 1, 8, 64, "dense"),         # Mellum-2's: 0.88
-    (128, 128, 8, 128, "grouped"),   # both cells' prefill chunks
-    (128, 128, 8, 64, "grouped"),
-    (16, 2, 8, 8, "grouped"),        # more than one position, whatever else
-    (1, 1, 8, 8, "dense"),           # one row that takes every expert
-    (1, 1, 8, 64, "grouped"),        # one row that takes an eighth
-], ids=["command_decode", "mellum2_decode", "command_chunk", "mellum2_chunk",
-        "two_positions", "one_row_every_expert", "one_row_an_eighth"])
-def test_the_form_a_cached_call_takes(rows, positions, k, e_all, form):
-    assert ops.expert_form(rows, positions, k, e_all) == form
+@pytest.mark.parametrize("rows,positions,k,e_all,kernel,form", [
+    (8, 1, 8, 128, True, "grouped"),     # Command A+'s decode step: 0.40
+    (16, 1, 8, 64, True, "grouped"),     # Mellum-2's: 0.88, under 0.95
+    (32, 1, 8, 64, True, "dense"),       # twice its lanes: 0.986
+    (128, 128, 8, 128, True, "grouped"),  # both cells' prefill chunks
+    (128, 128, 8, 64, True, "grouped"),
+    (16, 2, 8, 8, True, "grouped"),      # more than one position
+    (1, 1, 8, 8, True, "dense"),         # one row that takes every expert
+    (1, 1, 8, 64, True, "grouped"),      # one row that takes an eighth
+    (8, 1, 8, 128, False, "grouped"),    # the loop: 0.40, under 0.7
+    (16, 1, 8, 64, False, "dense"),      # the loop: 0.88, over 0.7
+    (128, 128, 8, 64, False, "grouped"),  # a chunk, whatever the form
+], ids=["command_decode", "mellum2_decode", "mellum2_32_lanes",
+        "command_chunk", "mellum2_chunk", "two_positions",
+        "one_row_every_expert", "one_row_an_eighth", "command_decode_loop",
+        "mellum2_decode_loop", "mellum2_chunk_loop"])
+def test_the_form_a_cached_call_takes(rows, positions, k, e_all, kernel,
+                                      form):
+    assert ops.expert_form(rows, positions, k, e_all, kernel) == form
 
 
 @pytest.mark.parametrize("k,e_all", [(8, 128), (8, 64), (2, 8), (4, 16),
                                      (1, 256)])
 def test_more_rows_never_turn_a_decode_step_back_to_the_tiles(k, e_all):
-    forms = [ops.expert_form(n, 1, k, e_all) for n in range(1, 513)]
-    turn = forms.index("dense")
-    assert turn > 0 and set(forms[:turn]) == {"grouped"}
-    assert set(forms[turn:]) == {"dense"}
+    turns = []
+    for kernel in (False, True):
+        forms = [ops.expert_form(n, 1, k, e_all, kernel)
+                 for n in range(1, 1025)]
+        turn = forms.index("dense")
+        assert turn > 0 and set(forms[:turn]) == {"grouped"}
+        assert set(forms[turn:]) == {"dense"}
+        turns.append(turn)
+    assert turns[0] <= turns[1]   # the kernel keeps more steps grouped
 
 
-@pytest.mark.parametrize("shape,num,cached,form", [
-    ((8, 1), 128, True, "grouped"), ((16, 1), 64, True, "dense"),
-    ((1, 5), 64, True, "grouped"), ((16, 1), 128, False, "dense"),
-    ((1, 5), 128, False, "dense")],
+@pytest.mark.parametrize("shape,num,cached,kernel,form", [
+    ((8, 1), 128, True, False, "grouped"), ((32, 1), 64, True, False, "dense"),
+    ((1, 5), 64, True, False, "grouped"), ((16, 1), 128, False, False, "dense"),
+    ((1, 5), 128, False, False, "dense"), ((16, 1), 64, True, True, "grouped"),
+    ((16, 1), 64, True, False, "dense")],
     ids=["few_rows_of_many_experts", "many_rows_of_few_experts", "a_chunk",
-         "no_cache_one_position", "no_cache_a_sequence"])
-def test_the_block_asks_the_rule(monkeypatch, shape, num, cached, form):
+         "no_cache_one_position", "no_cache_a_sequence",
+         "sixteen_rows_of_64_in_the_kernel", "sixteen_rows_of_64_in_the_loop"])
+def test_the_block_asks_the_rule(monkeypatch, shape, num, cached, kernel,
+                                 form):
     """``RoutedFFN.forward`` hands ``routed_experts`` the form that
-    ``expert_form`` names for the call's shapes, and ``dense`` (the
-    differentiable one) on the normal path whatever the shapes."""
+    ``expert_form`` names for the call's shapes and for the grouped form's
+    kernel or loop (16 rows of 8 of 64 hit 0.88: under the kernel's 0.95,
+    over the loop's 0.7), and ``dense`` (the differentiable one) on the
+    normal path whatever the shapes."""
     seen, real = [], ops.routed_experts
 
     def spy(*args, **kw):
@@ -611,15 +789,19 @@ def test_the_block_asks_the_rule(monkeypatch, shape, num, cached, form):
             return None
 
         def note_route(self, load):
-            assert load.shape == (6,)
+            assert load.shape == (7,)
 
     monkeypatch.setattr(ops, "routed_experts", spy)
     moe = RoutedFFN(16, 12, num, 8, experts_held=(0, 8))
     moe.initialize(mx.init.Normal(0.1))
     x = mx.np.array(np.random.RandomState(3).randn(*shape, 16)
                     .astype("float32"))
-    with mx.autograd.predict_mode():
-        moe(x, cache=View()) if cached else moe(x)
+    kern.use_interpret(kernel)
+    try:
+        with mx.autograd.predict_mode():
+            moe(x, cache=View()) if cached else moe(x)
+    finally:
+        kern.use_interpret(False)
     assert seen == [form]
 
 
@@ -733,7 +915,62 @@ def test_route_loads_are_read_back_and_counted(bundle):
     assert prof.get_counter("serve.moe_experts_read") \
         == moe["experts_read"] == moe["experts_hit"]
     assert moe["picked_share"] == 1.0
+    # and no expert has more than one tile's rows in a call: the loop too
+    # reads each hit expert once
+    assert moe["expert_reads"] == moe["experts_hit"]
     assert prof.get_counter("serve.moe_load_max_over_mean") \
         == pytest.approx(moe["load_max_over_mean"])
     assert not eng._route_pending
     eng.close()
+
+
+def test_the_kernel_reads_each_hit_expert_once_a_call(bundle, monkeypatch):
+    """Through ``ContinuousEngine`` with the grouped form's kernel (the
+    Pallas interpreter): every ``serve.route`` span's ``expert_reads`` is
+    its ``experts_hit`` (one read a hit expert a call, chunk or decode
+    step), the stats add the spans up, and the served tokens are those of
+    the XLA loop over tiles."""
+    import mxnet_tpu.serve.scheduler as sched
+
+    spans = []
+
+    class Span:
+        """Every host span of the scheduler, keeping ``serve.route``'s
+        stats."""
+
+        def __init__(self, name, **kw):
+            self.route = name == "mxnet_tpu.serve.route"
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        def set_metadata(self, **kw):
+            if self.route:
+                spans.append(kw)
+
+    monkeypatch.setattr(sched, "host_span", Span)
+    prompts = tokens_of(9, 21, 30)
+
+    def served():
+        eng = bundle.engine(slots=2)
+        eng.warmup()
+        res = serve_all(eng, prompts, 9)
+        moe = eng.stats()["moe"]
+        eng.close()
+        return [r["tokens"] for r in res], moe
+
+    tiles, _ = served()
+    spans.clear()
+    kern.use_interpret(True)
+    try:
+        tokens, moe = served()
+    finally:
+        kern.use_interpret(False)
+    assert tokens == tiles
+    assert spans and all(s["expert_reads"] == s["experts_hit"] > 0
+                         for s in spans)
+    assert sum(s["expert_reads"] for s in spans) == moe["expert_reads"] \
+        == moe["experts_hit"]
